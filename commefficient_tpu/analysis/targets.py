@@ -741,7 +741,7 @@ def attention_target(bwd: bool = True) -> AuditTarget:
     else:
         fn = fwd
         name = "attention/flash-fwd"
-        desc = "flash attention forward (custom_vjp_call_jaxpr descent)"
+        desc = "flash attention forward (custom_vjp_call descent)"
 
     def trace():
         return jax.make_jaxpr(fn)(q, k, v)

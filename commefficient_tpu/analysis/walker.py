@@ -6,7 +6,7 @@ it happened to find by scanning ``eqn.params`` for ``Jaxpr`` /
 ``ClosedJaxpr`` values in lists and tuples.  That covers ``scan`` and
 ``pjit`` but is blind to the call-like primitives whose bodies hide
 behind other param names or wrapper objects — most importantly
-``custom_vjp_call_jaxpr`` (param ``fun_jaxpr``) and ``remat2`` (an *open*
+``custom_vjp_call`` (param ``call_jaxpr``) and ``remat2`` (an *open*
 ``Jaxpr`` under param ``jaxpr``), which is exactly where the flash
 attention kernels of PR 3 live.
 
@@ -16,7 +16,7 @@ including
 
 - ``scan`` / ``while`` / ``cond``            (ClosedJaxpr params, lists)
 - ``pjit`` / ``xla_call`` / ``core_call``    (ClosedJaxpr ``jaxpr``)
-- ``custom_vjp_call_jaxpr`` / ``custom_jvp_call_jaxpr`` (``fun_jaxpr``;
+- ``custom_vjp_call`` / ``custom_jvp_call`` (``call_jaxpr``;
   the fwd/bwd thunks are Python callables, not jaxprs, and are *not*
   invoked — tracing arbitrary user thunks from an auditor is fragile.
   The bwd body is auditable by tracing ``jax.grad`` of the target, which

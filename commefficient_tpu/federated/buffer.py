@@ -146,6 +146,7 @@ def build_buffer_programs(apply_loss: Callable, unflatten: Callable,
     if trainable_mask is not None:
         trainable_mask = jnp.asarray(trainable_mask, jnp.float32)
 
+    from commefficient_tpu.parallel.mesh import on_each_replica
     if mesh is not None:
         from commefficient_tpu.parallel.mesh import (
             batch_shardings, buffer_state_shardings,
@@ -361,7 +362,8 @@ def build_buffer_programs(apply_loss: Callable, unflatten: Callable,
             # bitwise-identical to the unbatched call — and identical to
             # round.py's sync-path call site, which keeps the buffered
             # lockstep trajectory pinned bit-equal to sync
-            agg = sketch.sketch_vec_batched(agg, use_kernel=True)
+            agg = on_each_replica(
+                mesh, lambda a: sketch.sketch_vec_batched(a, True))(agg)
 
         breach = jnp.logical_or(~jnp.isfinite(loss_mean),
                                 loss_mean > cfg.nan_threshold)
@@ -371,7 +373,8 @@ def build_buffer_programs(apply_loss: Callable, unflatten: Callable,
         server_lr = 1.0 if is_fedavg else lr
         noise_rng = jax.random.fold_in(rng, 0x5e77e7)
         update, new_opt = server_update(agg, state.opt, cfg, server_lr,
-                                        sketch=sketch, noise_rng=noise_rng)
+                                        sketch=sketch, noise_rng=noise_rng,
+                                        mesh=mesh)
         if trainable_mask is not None:
             update = update * trainable_mask
         # select, not multiply: NaN * 0 = NaN (mirrors round.round_core)

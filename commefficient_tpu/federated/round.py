@@ -248,6 +248,10 @@ def build_round_step(apply_loss: Callable, unflatten: Callable,
     if bucketed and sum(buckets.sizes) != cfg.grad_dim:
         raise ValueError(f"GradBuckets plan covers {sum(buckets.sizes)} "
                          f"coordinates, round has {cfg.grad_dim}")
+    # the aggregate-side sketch is replicated compute that holds a Pallas
+    # kernel: on a mesh each chip runs it on its own replica, outside the
+    # auto-partitioner (server_update(mesh=) does the same for its kernels)
+    from commefficient_tpu.parallel.mesh import on_each_replica
 
     def bucketed_compress(chunk_of):
         """Assemble the round's aggregate from K independent per-bucket
@@ -264,11 +268,13 @@ def build_round_step(apply_loss: Callable, unflatten: Callable,
         chunks = [chunk_of(o, s)
                   for o, s in zip(buckets.offsets, buckets.sizes)]
         if sketch_after_aggregate:
-            table = sketch.sketch_range(chunks[0], buckets.offsets[0],
-                                        use_kernel=True)
+            def sketch_bucket(off, ch):
+                return on_each_replica(
+                    mesh, lambda c: sketch.sketch_range(c, off, True))(ch)
+
+            table = sketch_bucket(buckets.offsets[0], chunks[0])
             for off, ch in zip(buckets.offsets[1:], chunks[1:]):
-                table = table + sketch.sketch_range(ch, off,
-                                                    use_kernel=True)
+                table = table + sketch_bucket(off, ch)
             return table
         return jnp.concatenate(chunks)
 
@@ -434,7 +440,8 @@ def build_round_step(apply_loss: Callable, unflatten: Callable,
             # 2-D grid kernel as the per-worker vmapped paths, and the
             # same call buffer.py makes — buffered lockstep stays bitwise
             # equal to sync
-            agg = sketch.sketch_vec_batched(agg, use_kernel=True)
+            agg = on_each_replica(
+                mesh, lambda a: sketch.sketch_vec_batched(a, True))(agg)
 
         # --- in-round NaN guard (ref cv_train.py:221-229: the reference
         # checks the round's loss BEFORE opt.step, so a breaching round's
@@ -461,7 +468,8 @@ def build_round_step(apply_loss: Callable, unflatten: Callable,
         # identical to the lax.top_k chain, audited by
         # `graft-audit --target server_update_fused`
         update, new_opt = server_update(agg, state.opt, cfg, server_lr,
-                                        sketch=sketch, noise_rng=noise_rng)
+                                        sketch=sketch, noise_rng=noise_rng,
+                                        mesh=mesh)
         if trainable_mask is not None:
             update = update * trainable_mask
         # aborted => no weight/last_changed movement. A select, not a

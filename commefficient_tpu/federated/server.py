@@ -154,11 +154,21 @@ def server_update(
     lr,
     sketch: Optional[CountSketch] = None,
     noise_rng: Optional[jax.Array] = None,
+    mesh=None,
 ) -> Tuple[jax.Array, ServerOptState]:
     """Dispatch to the mode's update rule (ref get_server_update :469-481).
 
     Pure and jit-safe: ``cfg``/``sketch`` are static, everything else traced.
+    Inside a program partitioned over ``mesh`` the rule — replicated compute
+    on replicated state, and the home of the top-k/unsketch kernels — runs
+    on each chip's own replica (``parallel.mesh.on_each_replica``).
     """
+    if mesh is not None:
+        from commefficient_tpu.parallel.mesh import on_each_replica
+        return on_each_replica(
+            mesh, lambda g, st, lr_, key: server_update(
+                g, st, cfg, lr_, sketch=sketch, noise_rng=key)
+        )(gradient, state, lr, noise_rng)
     if cfg.mode == "fedavg":
         return _fedavg(gradient, state, cfg, lr)
     if cfg.mode == "uncompressed":

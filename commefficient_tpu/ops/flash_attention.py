@@ -285,7 +285,7 @@ def _fwd(q3, k3, v3, seeds, scale, block_q, block_k, t_k, dropout_rate,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(seeds, q3, k3, v3)
@@ -438,7 +438,7 @@ def _bwd(q3, k3, v3, do3, lse, delta, seeds, scale, block_q, block_k, t_k,
         out_specs=pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, Tq, D), q3.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(seeds, q3, k3, v3, do3, lse, delta)
@@ -467,7 +467,7 @@ def _bwd(q3, k3, v3, do3, lse, delta, seeds, scale, block_q, block_k, t_k,
             pltpu.VMEM((block_k, D), jnp.float32),
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(seeds, q3, k3, v3, do3, lse, delta)

@@ -86,8 +86,7 @@ from jax.experimental.pallas import tpu as pltpu
 # sketch kernels use — imported, not copied, so the bit-identity
 # contract between the est-mode stream and CountSketch.estimates is
 # drift-proof by construction
-from commefficient_tpu.ops.sketch_kernels import (LANES, TILE_BLOCKS,
-                                                  TPU_BACKENDS, _U,
+from commefficient_tpu.ops.sketch_kernels import (LANES, TILE_BLOCKS, _U,
                                                   _block_hash,
                                                   _butterfly_xor,
                                                   _interpret, _signs,
@@ -122,7 +121,7 @@ def topk_kernel_ok(approx_recall=None) -> bool:
         return False
     if forced == "kernel":
         return True
-    return jax.default_backend() in TPU_BACKENDS
+    return jax.default_backend() == "tpu"
 
 
 # --------------------------------------------------------------------------

@@ -29,6 +29,7 @@ from typing import Optional
 
 import jax
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from commefficient_tpu.config import FedConfig
@@ -215,6 +216,25 @@ def stacked_batch_shardings(mesh: Mesh, axis: str = "clients"):
     shards as in ``batch_shardings``."""
     worker1 = _ns(mesh, None, axis)
     return worker1, worker1, worker1
+
+
+def on_each_replica(mesh: Optional[Mesh], fn):
+    """``fn`` as every chip of ``mesh`` runs it on its OWN replica of
+    replicated operands: a ``shard_map`` over all mesh axes with
+    replicated in/out specs. Values are what the bare call gives; what
+    changes is that the compiler is told not to partition the body.
+
+    The aggregate side of a round (sketch of the all-reduced gradient,
+    server update on the replicated optimizer state) is such a
+    computation, and it holds the Pallas kernels — which the TPU compiler
+    refuses to partition automatically ("Mosaic kernels cannot be
+    automatically partitioned. Please wrap the call in a shard_map").
+    Identity off-mesh, and on a mesh with a ``model`` axis, whose flat
+    vectors are coordinate-split rather than replicated."""
+    if mesh is None or mesh.shape.get("model", 1) > 1:
+        return fn
+    return shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
+                     check_vma=False)
 
 
 def shard_state(state, cfg: FedConfig, mesh: Mesh):

@@ -41,9 +41,8 @@ from functools import lru_cache, partial
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-from commefficient_tpu.compat import shard_map
 
 from commefficient_tpu.models.gpt2 import Block, GPT2Config
 

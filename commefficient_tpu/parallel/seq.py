@@ -27,9 +27,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-from commefficient_tpu.compat import shard_map
 
 
 def _shard_rngs(rngs, *axis_names):

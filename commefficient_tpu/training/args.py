@@ -242,8 +242,9 @@ def build_parser(default_lr: float = 0.4) -> argparse.ArgumentParser:
                    help="dispatch K rounds per host call as one traced "
                         "lax.scan (api.train_rounds_scan): identical "
                         "trajectory, K-fold fewer dispatches — the host "
-                        "per-dispatch cost otherwise bounds throughput on "
-                        "remote/tunneled devices. NaN abort is detected at "
+                        "per-dispatch cost otherwise bounds throughput "
+                        "when the device round is short. NaN abort is "
+                        "detected at "
                         "window granularity (the device guard still freezes "
                         "state at the breaching round)")
     # GPT2 / PersonaChat (ref utils.py:185-208)

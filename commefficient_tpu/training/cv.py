@@ -371,6 +371,8 @@ def train(args, mesh=None, max_rounds=None, log=True):
 def main(argv=None):
     from commefficient_tpu.training.args import (parse_mesh,
                                                  round_up_workers_for_mesh)
+    from commefficient_tpu.utils.compile_cache import place_compile_cache
+    place_compile_cache()
     parser = build_parser(default_lr=0.4)
     args = parser.parse_args(argv)
     if args.do_test:

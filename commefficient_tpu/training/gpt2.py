@@ -568,6 +568,8 @@ def build_gpt2_parser():
 
 
 def main(argv=None):
+    from commefficient_tpu.utils.compile_cache import place_compile_cache
+    place_compile_cache()
     parser = build_gpt2_parser()
     args = parser.parse_args(argv)
     if args.do_test:

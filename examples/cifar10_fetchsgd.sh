@@ -25,7 +25,7 @@ python -m commefficient_tpu.training.cv \
     "$@"
 
 # --scan_rounds 8 dispatches 8 rounds per host call as one traced
-# lax.scan (trajectory-identical; api.train_rounds_scan) — on remote or
-# tunneled devices the per-round host costs otherwise bound throughput.
+# lax.scan (trajectory-identical; api.train_rounds_scan), so per-round
+# host dispatch costs do not bound throughput.
 # Add --mesh clients=8 to shard client state/batches over 8 chips, and
 # --topk_approx_recall 0.95 for the approx-top-k selector.

@@ -42,7 +42,7 @@ def test_walker_descends_custom_vjp_and_remat():
     _, stats = A.walk(closed)
     assert stats.visited("remat2"), stats.descended_into
     # inside the remat body, the (un-differentiated) custom_vjp call is
-    # still a custom_vjp_call_jaxpr eqn whose fun_jaxpr we must enter
+    # still a custom_vjp_call eqn whose call_jaxpr we must enter
     assert any("custom_vjp" in p for p in stats.descended_into), \
         stats.descended_into
     # and the sin inside f's fun_jaxpr was actually visited

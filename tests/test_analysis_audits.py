@@ -144,7 +144,7 @@ def test_gpt2_train_step_audit_passes_and_visits_remat(audited):
 def test_flash_attention_fwd_audit_visits_custom_vjp(audited):
     rep = audited("attention", 0)
     assert rep.ok, rep.format()
-    assert rep.stats.visited("custom_vjp_call_jaxpr"), \
+    assert rep.stats.visited("custom_vjp_call"), \
         rep.stats.descended_into
     assert rep.stats.visited("pallas_call"), rep.stats.descended_into
 

@@ -290,7 +290,7 @@ def test_shard_rngs_decorrelate_dropout_across_shards():
 
     from jax.sharding import PartitionSpec as P
 
-    from commefficient_tpu.compat import shard_map
+    from jax import shard_map
 
     from commefficient_tpu.parallel.mesh import make_mesh
     from commefficient_tpu.parallel.seq import _shard_rngs
@@ -320,7 +320,7 @@ def test_ring_mc_logits_replicated_across_seq_shards_under_dropout():
 
     from jax.sharding import PartitionSpec as P
 
-    from commefficient_tpu.compat import shard_map
+    from jax import shard_map
 
     from commefficient_tpu.models.gpt2 import GPT2Config, GPT2DoubleHeads
     from commefficient_tpu.parallel.mesh import make_mesh

@@ -321,3 +321,27 @@ def test_fractional_final_epoch(tmp_path):
     learner, row = train(args, log=False)
     assert row["epoch"] == 2
     assert learner.rounds_done == spe + max(1, int(round(spe * 0.5)))
+
+
+@pytest.mark.parametrize("env_dir", [None, "/some/dir"],
+                         ids=["checkout_default", "placed_from_outside"])
+def test_compile_cache_placement(monkeypatch, env_dir):
+    """The persistent compile cache is placed from OUTSIDE when
+    JAX_COMPILATION_CACHE_DIR is set (nothing is set in code); otherwise
+    it is the fixed <checkout>/.jax_cache — never a temp name."""
+    import os
+
+    from commefficient_tpu.utils import compile_cache
+    updates = []
+    monkeypatch.setattr(compile_cache.jax.config, "update",
+                        lambda name, value: updates.append((name, value)))
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        want = os.path.join(checkout, ".jax_cache")
+        assert compile_cache.place_compile_cache() == want
+        assert updates == [("jax_compilation_cache_dir", want)]
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+        assert compile_cache.place_compile_cache() == env_dir
+        assert updates == []

@@ -1,0 +1,138 @@
+"""The main path's kernels, compiled at real widths for a described v5e.
+
+The TPU's compiler is installed here and compiles for a chip that is
+described, not attached — so what it would refuse on the chip (a tile it
+cannot lower, more VMEM than a kernel may have) fails in tier-1 at no chip
+time. Interpret-mode tests cannot see any of that. Nothing runs: these
+say nothing about results or speed, and a compile that passes is not a
+chip run.
+
+The topology is described inside the module-scoped ``topo`` fixture —
+never at import or collection time, and only in this one file: the first
+process to load the TPU library keeps it until it exits, so every xdist
+worker must be able to COLLECT this file without touching it.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from commefficient_tpu.ops import sketch_kernels, topk_kernels
+from commefficient_tpu.ops.countsketch import CountSketch
+
+D_RESNET9 = 6_568_640          # ResNet-9, the paper's flagship
+D_GPT2 = 124_440_576           # GPT2-small double-heads
+ROWS, COLS, K = 5, 500_000, 50_000   # reference sketch (utils.py:142-145)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # no TPU compiler, or its library is taken
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # an executable compiled for a described chip is written to the
+    # persistent cache but cannot be read back without the chip: keep
+    # these compiles out of it
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compiled_text(fn, one_chip, *shapes_dtypes):
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in shapes_dtypes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _sketch(d):
+    return CountSketch(d=d, c=COLS, r=ROWS)
+
+
+def _table(cs):
+    return ((cs.r, cs.c_eff), jnp.float32)
+
+
+def test_sketch_vec_resnet9(one_chip):
+    cs = _sketch(D_RESNET9)
+    text = _compiled_text(lambda v: sketch_kernels.sketch_vec_pallas(cs, v),
+                          one_chip, ((cs.d,), jnp.float32))
+    assert "tpu_custom_call" in text
+
+
+def test_estimates_resnet9(one_chip):
+    cs = _sketch(D_RESNET9)
+    text = _compiled_text(lambda t: sketch_kernels.estimates_pallas(cs, t),
+                          one_chip, _table(cs))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("d", [D_RESNET9, D_GPT2],
+                         ids=["resnet9", "gpt2_small"])
+def test_unsketch_select(one_chip, d):
+    cs = _sketch(d)
+    text = _compiled_text(
+        lambda t: topk_kernels.unsketch_select_pallas(cs, t, k=K),
+        one_chip, _table(cs))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("d", [D_RESNET9, D_GPT2],
+                         ids=["resnet9", "gpt2_small"])
+def test_fused_true_topk(one_chip, d):
+    vec = ((d,), jnp.float32)
+    text = _compiled_text(
+        lambda g, v, e: topk_kernels.fused_true_topk_pallas(
+            g, v, e, k=K, rho=0.9),
+        one_chip, vec, vec, vec)
+    assert "tpu_custom_call" in text
+
+
+def test_flash_attention_fwd_bwd_dropout(one_chip):
+    from commefficient_tpu.ops.flash_attention import flash_attention
+    key = jax.random.PRNGKey(0)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(
+            q, k, v, dropout_rate=0.1, dropout_key=key
+        ).astype(jnp.float32) ** 2)
+
+    qkv = ((8, 256, 12, 64), jnp.bfloat16)
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), one_chip,
+                          qkv, qkv, qkv)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("op", ["sketch_vec", "estimates"])
+def test_batched_per_worker(one_chip, monkeypatch, op):
+    """What federated/client.py and client_store.py dispatch under the
+    round's per-worker vmap — whenever a per-worker nonlinearity
+    (--max_grad_norm, DP, local error) rules out sketch-after-aggregate,
+    and for the sketched client-state codec: the 2-D grid (W, n_tiles)
+    kernels, one table block per worker. At W=8 the default 16 MiB of
+    scoped VMEM refused both (the per-row table block is double-buffered);
+    ``sketch_kernels._batched_params`` raises the limit."""
+    cs = _sketch(D_RESNET9)
+    W = 8
+    fn, shape = {
+        "sketch_vec": (lambda v: cs.sketch_vec(v, True), (W, cs.d)),
+        "estimates": (lambda t: cs.estimates(t, True), (W,) + _table(cs)[0]),
+    }[op]
+    # the dispatch override interprets off-TPU; this compile is for the
+    # chip, so steer it here (never through an option of the program)
+    monkeypatch.setattr(sketch_kernels, "_interpret", lambda flag: False)
+    with sketch_kernels.force_dispatch("kernel"):
+        text = _compiled_text(jax.vmap(fn), one_chip, (shape, jnp.float32))
+    assert "tpu_custom_call" in text

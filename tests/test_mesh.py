@@ -27,13 +27,18 @@ def make_problem():
     return ids, (Xs, ys), mask
 
 
-def run(cfg_kw, mesh, rounds=3):
+def make_learner(cfg_kw, mesh):
     model = TinyMLP(num_classes=2, hidden=8)
     cfg = FedConfig(num_workers=8, num_clients=8, lr_scale=0.1,
                     weight_decay=0, **cfg_kw)
+    return FedLearner(model, cfg, make_cv_loss(model), None,
+                      jax.random.PRNGKey(0), make_problem()[1][0][0][:1],
+                      mesh=mesh)
+
+
+def run(cfg_kw, mesh, rounds=3):
     ids, batch, mask = make_problem()
-    ln = FedLearner(model, cfg, make_cv_loss(model), None,
-                    jax.random.PRNGKey(0), batch[0][0][:1], mesh=mesh)
+    ln = make_learner(cfg_kw, mesh)
     outs = [ln.train_round(ids, batch, mask) for _ in range(rounds)]
     return np.asarray(ln.state.weights), outs
 
@@ -56,6 +61,40 @@ def test_mesh_matches_single_device(cfg_kw):
         assert a["loss"] == pytest.approx(b["loss"], rel=2e-4)
         assert a["download_bytes"] == b["download_bytes"]
         assert a["upload_bytes"] == b["upload_bytes"]
+
+
+@pytest.mark.parametrize("cfg_kw", [
+    dict(mode="sketch", error_type="virtual", virtual_momentum=0.9,
+         k=20, num_rows=3, num_cols=500),
+    dict(mode="true_topk", error_type="virtual", k=20, virtual_momentum=0.9),
+], ids=["sketch", "true_topk"])
+def test_mesh_round_runs_kernels_per_replica(cfg_kw):
+    """The TPU compiler refuses to partition a Pallas kernel ("Mosaic
+    kernels cannot be automatically partitioned. Please wrap the call in
+    a shard_map"), so on a mesh the aggregate side of the round — the
+    sketch of the reduced gradient and the server update, replicated
+    compute on replicated state — runs inside a shard_map
+    (parallel/mesh.on_each_replica): every pallas_call of the mesh round
+    sits under one, and the trajectory is still the single-device one."""
+    from commefficient_tpu.analysis.walker import iter_eqns
+    from commefficient_tpu.ops.sketch_kernels import force_dispatch
+
+    with force_dispatch("kernel"):
+        w_single, _ = run(cfg_kw, mesh=None, rounds=2)
+        ids, batch, mask = make_problem()
+        ln = make_learner(cfg_kw, make_mesh(4))
+        jaxpr = jax.make_jaxpr(ln._round)(
+            ln.state, jnp.asarray(ids, jnp.int32),
+            tuple(jnp.asarray(c) for c in batch), jnp.asarray(mask),
+            jnp.float32(0.1), jax.random.PRNGKey(1))
+        kernel_paths = [site.path for site in iter_eqns(jaxpr)
+                        if site.primitive == "pallas_call"]
+        assert kernel_paths
+        assert all("shard_map" in path for path in kernel_paths)
+        for _ in range(2):
+            ln.train_round(ids, batch, mask)
+    np.testing.assert_allclose(np.asarray(ln.state.weights), w_single,
+                               rtol=2e-4, atol=2e-5)
 
 
 def test_mesh_divisibility_validation():
