@@ -695,8 +695,9 @@ def bench_offload_overlap(n_rounds=8):
     ln = make_learner()
     ln.train_round(ids_fn(0), batch, mask)  # compile
     ln.train_round(ids_fn(1), batch, mask)  # warm
-    stats = ln._offload_pipe.stats
-    stats["gather_s"] = stats["scatter_s"] = 0.0
+    from commefficient_tpu.utils.tracing import span_seconds
+    gather0 = span_seconds("offload.gather")
+    scatter0 = span_seconds("offload.scatter")
     t0 = time.perf_counter()
     raw = None
     for r in range(n_rounds):
@@ -711,8 +712,11 @@ def bench_offload_overlap(n_rounds=8):
         "offload_round_sync_ms": round(sync_t * 1e3, 1),
         "offload_round_async_ms": round(async_t * 1e3, 1),
         # host time spent inside gather/scatter during the async window
-        "offload_gather_ms": round(stats["gather_s"] / n_rounds * 1e3, 1),
-        "offload_scatter_ms": round(stats["scatter_s"] / n_rounds * 1e3, 1),
+        "offload_gather_ms": round(
+            (span_seconds("offload.gather") - gather0) / n_rounds * 1e3, 1),
+        "offload_scatter_ms": round(
+            (span_seconds("offload.scatter") - scatter0) / n_rounds * 1e3,
+            1),
         # fixed cost the pipeline actually took off the critical path
         "offload_gather_scatter_overlap_ms": round(
             max(sync_t - async_t, 0.0) * 1e3, 1),
@@ -737,6 +741,7 @@ def bench_client_store_gather_scatter(scales=(10_000, 1_000_000),
     from commefficient_tpu.federated.api import FedLearner
     from commefficient_tpu.federated.losses import make_cv_loss
     from commefficient_tpu.models import TinyMLP
+    from commefficient_tpu.utils.tracing import span_seconds
 
     W, B, F = 8, 16, 8
     model = TinyMLP(num_classes=10, hidden=32)  # d = 618
@@ -785,16 +790,19 @@ def bench_client_store_gather_scatter(scales=(10_000, 1_000_000),
         ids_fn = make_ids_fn(n)
         ln.train_round(ids_fn(0), batch, mask)  # compile
         ln.train_round(ids_fn(1), batch, mask)  # warm
-        stats = ln._offload_pipe.stats
-        stats["gather_s"] = stats["scatter_s"] = 0.0
+        gather0 = span_seconds("offload.gather")
+        scatter0 = span_seconds("offload.scatter")
         t0 = time.perf_counter()
         for r in range(n_rounds):
             ln.train_round(ids_fn(2 + r), batch, mask)
         t = tag(n)
         out[f"round_ms_{t}"] = round(
             (time.perf_counter() - t0) / n_rounds * 1e3, 2)
-        out[f"gather_ms_{t}"] = round(stats["gather_s"] / n_rounds * 1e3, 2)
-        out[f"scatter_ms_{t}"] = round(stats["scatter_s"] / n_rounds * 1e3, 2)
+        out[f"gather_ms_{t}"] = round(
+            (span_seconds("offload.gather") - gather0) / n_rounds * 1e3, 2)
+        out[f"scatter_ms_{t}"] = round(
+            (span_seconds("offload.scatter") - scatter0) / n_rounds * 1e3,
+            2)
         out[f"arena_mb_{t}"] = round(ln.host_store.nbytes() / 2**20, 1)
     return out
 

@@ -14,6 +14,7 @@ from typing import Iterator, Optional, Tuple
 import numpy as np
 
 from commefficient_tpu.data.sampler import FedSampler
+from commefficient_tpu.utils.tracing import span, spanned
 
 
 class FedBatcher:
@@ -42,7 +43,7 @@ class FedBatcher:
         uninterrupted run's bitwise round sequence (docs/ROBUSTNESS.md)."""
         W, B = self.num_workers, self.pad_size
         self._epoch_start_aug = self._aug_state()
-        for round_batches in self.sampler.epoch():
+        for round_batches in spanned(self.sampler.epoch(), "data.sample"):
             ids = np.zeros(W, np.int32)
             mask = np.zeros((W, B), np.float32)
             cols = None
@@ -50,14 +51,15 @@ class FedBatcher:
                 data = self.dataset.get_flat_batch(flat_idxs)
                 if skip > 0:
                     continue
-                if cols is None:
-                    cols = [np.zeros((W, B) + d.shape[1:], d.dtype)
-                            for d in data]
-                n = min(len(flat_idxs), B)
-                ids[w] = client_id
-                mask[w, :n] = 1.0
-                for c, d in zip(cols, data):
-                    c[w, :n] = d[:n]
+                with span("data.assemble"):
+                    if cols is None:
+                        cols = [np.zeros((W, B) + d.shape[1:], d.dtype)
+                                for d in data]
+                    n = min(len(flat_idxs), B)
+                    ids[w] = client_id
+                    mask[w, :n] = 1.0
+                    for c, d in zip(cols, data):
+                        c[w, :n] = d[:n]
             if skip > 0:
                 skip -= 1
                 continue

@@ -22,6 +22,8 @@ from typing import List, Optional, Tuple
 
 import numpy as np
 
+from commefficient_tpu.utils.tracing import count, span
+
 
 class FedDataset:
     def __init__(self, dataset_dir: str = "./dataset", do_iid: bool = False,
@@ -109,19 +111,22 @@ class FedDataset:
 
     def get_flat_batch(self, flat_idxs: np.ndarray) -> Tuple[np.ndarray, ...]:
         """Fetch arbitrary flat train indices (crossing natural clients)."""
-        clients, within = self._flat_to_natural(np.asarray(flat_idxs))
-        parts = []
-        order = np.argsort(clients, kind="stable")
-        inv = np.empty_like(order)
-        inv[order] = np.arange(len(order))
-        for c in np.unique(clients):
-            rows = within[clients == c]
-            parts.append(self._get_train_batch(int(c), rows))
-        cols = [np.concatenate([p[i] for p in parts])
-                for i in range(len(parts[0]))]
-        cols = [c[inv] for c in cols]  # restore request order
+        with span("data.fetch"):
+            clients, within = self._flat_to_natural(np.asarray(flat_idxs))
+            parts = []
+            order = np.argsort(clients, kind="stable")
+            inv = np.empty_like(order)
+            inv[order] = np.arange(len(order))
+            for c in np.unique(clients):
+                rows = within[clients == c]
+                parts.append(self._get_train_batch(int(c), rows))
+            cols = [np.concatenate([p[i] for p in parts])
+                    for i in range(len(parts[0]))]
+            cols = [c[inv] for c in cols]  # restore request order
+        count("data.rows", len(cols[0]))
         if self.transform is not None:
-            cols = self.transform(cols, self.rng)
+            with span("data.augment"):
+                cols = self.transform(cols, self.rng)
         return tuple(cols)
 
     def get_val_batch(self, idxs: np.ndarray) -> Tuple[np.ndarray, ...]:

@@ -17,6 +17,8 @@ from typing import Iterable, Iterator
 
 import jax
 
+from commefficient_tpu.utils.tracing import count, span
+
 
 def device_prefetch(batches: Iterable, size: int = 2,
                     shardings=None) -> Iterator:
@@ -36,7 +38,10 @@ def device_prefetch(batches: Iterable, size: int = 2,
     else:
         put = lambda item: jax.device_put(item, shardings)
     for item in batches:
-        buf.append(put(item))
+        with span("data.h2d"):
+            buf.append(put(item))
+        count("data.h2d_bytes", sum(
+            getattr(x, "nbytes", 0) for x in jax.tree_util.tree_leaves(item)))
         if len(buf) > size:
             yield buf.popleft()
     while buf:

@@ -17,7 +17,6 @@ to coordinate — state is explicit and the round is one jitted function:
 
 from __future__ import annotations
 
-import time
 from collections import deque
 from typing import Callable, Iterable, Optional
 
@@ -36,6 +35,7 @@ from commefficient_tpu.federated.state import (CLIENT_STATE_FIELDS,
 from commefficient_tpu.ops.countsketch import LANES
 from commefficient_tpu.utils.params import flatten_params
 from commefficient_tpu.utils.schedules import PiecewiseLinear
+from commefficient_tpu.utils.tracing import count, round_mark, span
 
 # --------------------------------------------------------------------------
 # Transfer guard around the round dispatch.
@@ -106,7 +106,11 @@ class FedLearner:
             unflatten = lambda fp: base_unflatten(fp[:d_logical])  # noqa: E731
         self.unflatten = unflatten
         self.mesh = mesh
-        self.state: FedState = init_fed_state(self.cfg, flat)
+        self._weights_sh = None
+        if mesh is not None:
+            from commefficient_tpu.parallel.mesh import fed_state_shardings
+            self._weights_sh = fed_state_shardings(self.cfg, mesh).weights
+        self.state = init_fed_state(self.cfg, flat)   # placed by the setter
         # Host-offloaded client state (cfg.client_state_offload): the
         # momentum/error/weight rows live in mesh-sharded host arenas
         # (client_store.HostArenaStore) — the row space block-partitioned
@@ -136,9 +140,7 @@ class FedLearner:
                 # round compiles exactly once (analysis/ retrace guard)
                 self.state = jax.device_put(self.state, self._s_dev)
         if mesh is not None:
-            from commefficient_tpu.parallel.mesh import (batch_shardings,
-                                                         shard_state)
-            self.state = shard_state(self.state, self.cfg, mesh)
+            from commefficient_tpu.parallel.mesh import batch_shardings
             self._batch_sh = batch_shardings(mesh)
         round_unflatten = unflatten
         if mesh is not None and param_specs is not None:
@@ -213,6 +215,23 @@ class FedLearner:
         self.total_download_bytes = 0.0
         self.total_upload_bytes = 0.0
 
+    @property
+    def state(self) -> FedState:
+        return self._state
+
+    @state.setter
+    def state(self, new: FedState):
+        """Under a mesh, a state that does not sit where the round's
+        ``in_shardings`` want it (fresh from ``init_fed_state``, or handed
+        in with weights made on one device) is placed here, explicitly —
+        the guarded dispatch may not move it. The round's own outputs
+        already match and pass through on one comparison."""
+        if (self._weights_sh is not None
+                and new.weights.sharding != self._weights_sh):
+            from commefficient_tpu.parallel.mesh import shard_state
+            new = shard_state(new, self.cfg, self.mesh)
+        self._state = new
+
     def _init_host_rows(self, flat):
         """Allocate the host-side client state: one ``HostArenaStore`` of
         per-shard numpy arenas, block-partitioned along the mesh's
@@ -257,7 +276,8 @@ class FedLearner:
         tests, checkpointing — always see current rows; async loops defer
         it to epoch boundaries."""
         if self._offload_pipe is not None:
-            self._offload_pipe.flush_all()
+            with span("offload.flush"):
+                self._offload_pipe.flush_all()
 
     @property
     def batch_shardings(self):
@@ -316,43 +336,47 @@ class FedLearner:
         output rows write back lazily (HostOffloadPipeline), so the
         host<->device row traffic overlaps compute instead of serializing
         the round."""
-        lr = self.lr_at(self.rounds_done if epoch_frac is None else epoch_frac)
-        self.rng, round_rng = jax.random.split(self.rng)
-        ids = jnp.asarray(client_ids, jnp.int32)
-        cols = tuple(jnp.asarray(t) for t in batch)
-        m = jnp.asarray(mask, jnp.float32)
-        if self.mesh is not None:
-            ids_sh, cols_sh, mask_sh = self._batch_sh
-            ids = jax.device_put(ids, ids_sh)
-            cols = jax.device_put(cols, cols_sh)
-            m = jax.device_put(m, mask_sh)
-        # device scalar, not a python float: the guarded dispatch below
-        # must not trigger an implicit h2d, and a weak-typed scalar is
-        # one dtype-promotion away from a retrace
-        lr_in = (jnp.float32(lr) if self.lr_scale_vec is None
-                 else lr * self.lr_scale_vec)
-        if self.mesh is not None:
-            lr_in, round_rng = self._replicate(lr_in, round_rng)
-        ks = ((self._client_ks(client_ids),) if self.cfg.client_k_active
-              else ())
-        if self._offload:
-            ids_np = np.asarray(client_ids).astype(np.int64)
-            valid = np.asarray(mask).any(axis=1)
-            rows = self._offload_pipe.gather(ids_np)
-            with _dispatch_guard():
-                self.state, out_rows, metrics = self._round(
-                    self.state, rows, ids, cols, m, lr_in, round_rng, *ks)
-            self._offload_pipe.push(ids_np, valid, out_rows)
-            if next_client_ids is not None:
-                self._offload_pipe.prefetch(
-                    np.asarray(next_client_ids).astype(np.int64))
-        else:
-            with _dispatch_guard():
-                self.state, metrics = self._round(self.state, ids, cols, m,
-                                                  lr_in, round_rng, *ks)
-        self.rounds_done += 1
-        metrics["lr"] = lr
-        return metrics
+        round_mark(self.rounds_done)
+        count("rounds")
+        with span("round.dispatch"):
+            lr = self.lr_at(self.rounds_done if epoch_frac is None
+                            else epoch_frac)
+            self.rng, round_rng = jax.random.split(self.rng)
+            ids = jnp.asarray(client_ids, jnp.int32)
+            cols = tuple(jnp.asarray(t) for t in batch)
+            m = jnp.asarray(mask, jnp.float32)
+            if self.mesh is not None:
+                ids_sh, cols_sh, mask_sh = self._batch_sh
+                ids = jax.device_put(ids, ids_sh)
+                cols = jax.device_put(cols, cols_sh)
+                m = jax.device_put(m, mask_sh)
+            # device scalar, not a python float: the guarded dispatch below
+            # must not trigger an implicit h2d, and a weak-typed scalar is
+            # one dtype-promotion away from a retrace
+            lr_in = (jnp.float32(lr) if self.lr_scale_vec is None
+                     else lr * self.lr_scale_vec)
+            if self.mesh is not None:
+                lr_in, round_rng = self._replicate(lr_in, round_rng)
+            ks = ((self._client_ks(client_ids),) if self.cfg.client_k_active
+                  else ())
+            if self._offload:
+                ids_np = np.asarray(client_ids).astype(np.int64)
+                valid = np.asarray(mask).any(axis=1)
+                rows = self._offload_pipe.gather(ids_np)
+                with _dispatch_guard():
+                    self.state, out_rows, metrics = self._round(
+                        self.state, rows, ids, cols, m, lr_in, round_rng, *ks)
+                self._offload_pipe.push(ids_np, valid, out_rows)
+                if next_client_ids is not None:
+                    self._offload_pipe.prefetch(
+                        np.asarray(next_client_ids).astype(np.int64))
+            else:
+                with _dispatch_guard():
+                    self.state, metrics = self._round(self.state, ids, cols, m,
+                                                      lr_in, round_rng, *ks)
+            self.rounds_done += 1
+            metrics["lr"] = lr
+            return metrics
 
     def finalize_round_metrics(self, raw):
         """Block on one round's device metrics and roll them up host-side
@@ -366,7 +390,8 @@ class FedLearner:
             raise TypeError("this is a train_rounds_scan result; use "
                             "finalize_scan_metrics")
         lr = raw.pop("lr")
-        out = jax.device_get(raw)
+        with span("round.sync"):
+            out = jax.device_get(raw)
         n = max(float(out["num_datapoints"]), 1.0)
         self.total_download_bytes += float(out["download_bytes"])
         self.total_upload_bytes += float(out["upload_bytes"])
@@ -538,19 +563,21 @@ class FedLearner:
     def evaluate(self, batches: Iterable):
         """Centralized validation over an iterable of (batch_tuple, mask)."""
         loss_sum, metric_sums, n_total = 0.0, None, 0.0
-        for batch, mask in batches:
-            self.rng, eval_rng = jax.random.split(self.rng)
-            cols = tuple(jnp.asarray(t) for t in batch)
-            m = jnp.asarray(mask, jnp.float32)
-            if self.mesh is not None:
-                cols, m, eval_rng = self._replicate(cols, m, eval_rng)
-            with _dispatch_guard():
-                out_dev = self._eval(self.state.weights, cols, m, eval_rng)
-            out = jax.device_get(out_dev)
-            loss_sum += float(out["loss_sum"])
-            ms = np.asarray(out["metric_sums"])
-            metric_sums = ms if metric_sums is None else metric_sums + ms
-            n_total += float(out["num_datapoints"])
+        with span("eval"):
+            for batch, mask in batches:
+                self.rng, eval_rng = jax.random.split(self.rng)
+                cols = tuple(jnp.asarray(t) for t in batch)
+                m = jnp.asarray(mask, jnp.float32)
+                if self.mesh is not None:
+                    cols, m, eval_rng = self._replicate(cols, m, eval_rng)
+                with _dispatch_guard():
+                    out_dev = self._eval(self.state.weights, cols, m,
+                                         eval_rng)
+                out = jax.device_get(out_dev)
+                loss_sum += float(out["loss_sum"])
+                ms = np.asarray(out["metric_sums"])
+                metric_sums = ms if metric_sums is None else metric_sums + ms
+                n_total += float(out["num_datapoints"])
         n = max(n_total, 1.0)
         return {"loss": loss_sum / n,
                 "metrics": (metric_sums if metric_sums is not None
@@ -595,9 +622,9 @@ class HostOffloadPipeline:
     rows, and byte accounting, including abort and padded-tail rounds —
     is pinned in tests/test_offload_async.py.
 
-    ``stats`` counts gathers/prefetch hits/pending-row hits and
-    accumulates host-side seconds spent building gathers vs flushing
-    writebacks (bench.py reports the overlap these buy)."""
+    ``stats`` counts gathers/prefetch hits/pending-row hits; the host
+    time spent building gathers and flushing writebacks is in the
+    ``offload.gather`` / ``offload.scatter`` spans (utils/tracing.py)."""
 
     def __init__(self, learner: "FedLearner", depth: int = 2):
         self.learner = learner
@@ -628,8 +655,7 @@ class HostOffloadPipeline:
         self._pushes = 0            # pending-queue generation counter
         self._prefetch_gen = -1
         self.stats = {"gathers": 0, "prefetch_hits": 0,
-                      "rows_from_pending": 0, "flushed_rounds": 0,
-                      "gather_s": 0.0, "scatter_s": 0.0}
+                      "rows_from_pending": 0, "flushed_rounds": 0}
 
     # --- gather side -----------------------------------------------------
     def _resolve_row(self, field, cid, lst):
@@ -658,43 +684,42 @@ class HostOffloadPipeline:
         ``client_rows_shardings`` — worker-dim sharded like the batch, so
         each shard's devices receive the rows its own arena owns."""
         ln = self.learner
-        t0 = time.perf_counter()
-        fields = {}
-        for field in CLIENT_STATE_FIELDS:
-            lst = ln.host_clients[field]
-            if lst is None:
-                fields[field] = None
-                continue
-            n = len(lst)
-            picked, any_pending = [], False
-            for i in ids_np:
-                row, from_pending = self._resolve_row(
-                    field, int(np.clip(i, 0, n - 1)), lst)
-                any_pending = any_pending or from_pending
-                picked.append(row)
-            if ln._s_host is None and not any_pending:
-                # numpy arena rows, nothing in flight: ONE stacked
-                # host->device transfer per leaf instead of W row puts.
-                # Committed placement (device_put, not jnp.asarray) so the
-                # round sees the SAME input sharding as the pending-row
-                # path below — mixing committed and uncommitted rows
-                # would recompile the round on every path flip
-                stacked = jax.tree.map(
-                    lambda *rs: jax.device_put(np.stack(rs), ln._s_dev),
-                    *picked)
-            else:
-                # device_put is a no-op for rows already on device
-                # (pending-queue slices)
-                picked = [jax.tree.map(
-                    lambda r: jax.device_put(r, ln._s_dev), row)
-                    for row in picked]
-                stacked = jax.tree.map(lambda *rs: jnp.stack(rs), *picked)
-            if ln.mesh is not None:
-                stacked = jax.device_put(stacked,
-                                         getattr(ln._rows_sh, field))
-            fields[field] = stacked
+        with span("offload.gather"):
+            fields = {}
+            for field in CLIENT_STATE_FIELDS:
+                lst = ln.host_clients[field]
+                if lst is None:
+                    fields[field] = None
+                    continue
+                n = len(lst)
+                picked, any_pending = [], False
+                for i in ids_np:
+                    row, from_pending = self._resolve_row(
+                        field, int(np.clip(i, 0, n - 1)), lst)
+                    any_pending = any_pending or from_pending
+                    picked.append(row)
+                if ln._s_host is None and not any_pending:
+                    # numpy arena rows, nothing in flight: ONE stacked
+                    # host->device transfer per leaf instead of W row puts.
+                    # Committed placement (device_put, not jnp.asarray) so the
+                    # round sees the SAME input sharding as the pending-row
+                    # path below — mixing committed and uncommitted rows
+                    # would recompile the round on every path flip
+                    stacked = jax.tree.map(
+                        lambda *rs: jax.device_put(np.stack(rs), ln._s_dev),
+                        *picked)
+                else:
+                    # device_put is a no-op for rows already on device
+                    # (pending-queue slices)
+                    picked = [jax.tree.map(
+                        lambda r: jax.device_put(r, ln._s_dev), row)
+                        for row in picked]
+                    stacked = jax.tree.map(lambda *rs: jnp.stack(rs), *picked)
+                if ln.mesh is not None:
+                    stacked = jax.device_put(stacked,
+                                             getattr(ln._rows_sh, field))
+                fields[field] = stacked
         self.stats["gathers"] += 1
-        self.stats["gather_s"] += time.perf_counter() - t0
         return ClientState(**fields)
 
     def gather(self, ids_np):
@@ -728,22 +753,21 @@ class HostOffloadPipeline:
 
     def _flush_one(self):
         ln = self.learner
-        t0 = time.perf_counter()
-        ids_np, valid, out = self._pending.popleft()
-        for field in CLIENT_STATE_FIELDS:
-            lst = ln.host_clients[field]
-            new = getattr(out, field)
-            if lst is None or new is None:
-                continue
-            # one device->host transfer per leaf, then per-row numpy
-            # slices encoded into the owning shard's arena
-            new_np = jax.tree.map(np.asarray, new)
-            for w, cid in enumerate(ids_np):
-                if valid[w] and 0 <= cid < len(lst):
-                    lst[int(cid)] = self._arena_encode(
-                        jax.tree.map(lambda a: a[w], new_np))
+        with span("offload.scatter"):
+            ids_np, valid, out = self._pending.popleft()
+            for field in CLIENT_STATE_FIELDS:
+                lst = ln.host_clients[field]
+                new = getattr(out, field)
+                if lst is None or new is None:
+                    continue
+                # one device->host transfer per leaf, then per-row numpy
+                # slices encoded into the owning shard's arena
+                new_np = jax.tree.map(np.asarray, new)
+                for w, cid in enumerate(ids_np):
+                    if valid[w] and 0 <= cid < len(lst):
+                        lst[int(cid)] = self._arena_encode(
+                            jax.tree.map(lambda a: a[w], new_np))
         self.stats["flushed_rounds"] += 1
-        self.stats["scatter_s"] += time.perf_counter() - t0
 
     def flush_all(self):
         """Apply every pending writeback and drop the gather-ahead buffer

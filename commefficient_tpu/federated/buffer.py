@@ -87,6 +87,8 @@ from commefficient_tpu.federated.faults import FaultModel
 from commefficient_tpu.federated.round import FedState, download_counts
 from commefficient_tpu.federated.server import make_sketch, server_update
 from commefficient_tpu.federated.state import BufferState, ClientState
+from commefficient_tpu.utils.tracing import (count, phase, round_mark,
+                                             span)
 
 
 def build_buffer_programs(apply_loss: Callable, unflatten: Callable,
@@ -206,7 +208,8 @@ def build_buffer_programs(apply_loss: Callable, unflatten: Callable,
         # download accounting snapshot: counts vs the weights the client
         # pulls NOW; billed at apply time (gated by that apply's ok)
         stale_round = state.client_last_round[ids]
-        counts = download_counts(state.last_changed, stale_round)   # (W,)
+        with phase("download_accounting"):
+            counts = download_counts(state.last_changed, stale_round)
 
         if offload:
             # sampled rows arrive host-gathered (owner-routed through the
@@ -229,12 +232,14 @@ def build_buffer_programs(apply_loss: Callable, unflatten: Callable,
                 None if errs is None else 0,
                 None if stales is None else 0,
                 None, 0)
-        if client_ks is not None:
-            out = jax.vmap(one_client, in_axes=axes + (0,))(
-                w, batch, mask, vels, errs, stales, lr, rngs, client_ks)
-        else:
-            out = jax.vmap(one_client, in_axes=axes)(
-                w, batch, mask, vels, errs, stales, lr, rngs)
+        with phase("client_grad"):
+            if client_ks is not None:
+                out = jax.vmap(one_client, in_axes=axes + (0,))(
+                    w, batch, mask, vels, errs, stales, lr, rngs,
+                    client_ks)
+            else:
+                out = jax.vmap(one_client, in_axes=axes)(
+                    w, batch, mask, vels, errs, stales, lr, rngs)
 
         contrib = BufferState(
             transmit=out.transmit,
@@ -362,8 +367,10 @@ def build_buffer_programs(apply_loss: Callable, unflatten: Callable,
             # bitwise-identical to the unbatched call — and identical to
             # round.py's sync-path call site, which keeps the buffered
             # lockstep trajectory pinned bit-equal to sync
-            agg = on_each_replica(
-                mesh, lambda a: sketch.sketch_vec_batched(a, True))(agg)
+            with phase("compress"):
+                agg = on_each_replica(
+                    mesh,
+                    lambda a: sketch.sketch_vec_batched(a, True))(agg)
 
         breach = jnp.logical_or(~jnp.isfinite(loss_mean),
                                 loss_mean > cfg.nan_threshold)
@@ -372,9 +379,10 @@ def build_buffer_programs(apply_loss: Callable, unflatten: Callable,
 
         server_lr = 1.0 if is_fedavg else lr
         noise_rng = jax.random.fold_in(rng, 0x5e77e7)
-        update, new_opt = server_update(agg, state.opt, cfg, server_lr,
-                                        sketch=sketch, noise_rng=noise_rng,
-                                        mesh=mesh)
+        with phase("server_update"):
+            update, new_opt = server_update(
+                agg, state.opt, cfg, server_lr, sketch=sketch,
+                noise_rng=noise_rng, mesh=mesh)
         if trainable_mask is not None:
             update = update * trainable_mask
         # select, not multiply: NaN * 0 = NaN (mirrors round.round_core)
@@ -780,6 +788,14 @@ class BufferedFedLearner(FedLearner):
 
     def train_round_async(self, client_ids, batch, mask, epoch_frac=None,
                           next_client_ids=None):
+        round_mark(self.rounds_done)
+        count("rounds")
+        with span("round.dispatch"):
+            return self._dispatch_cohort(client_ids, batch, mask,
+                                         epoch_frac, next_client_ids)
+
+    def _dispatch_cohort(self, client_ids, batch, mask, epoch_frac,
+                         next_client_ids):
         """Dispatch one COHORT (not one apply): local steps run against
         the current weights; whether/when contributions reach the buffer
         is the fault model's call. Returned metrics merge the cohort's

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from commefficient_tpu.config import FedConfig
 from commefficient_tpu.ops.countsketch import CountSketch
 from commefficient_tpu.ops.topk import topk
+from commefficient_tpu.utils.tracing import phase
 
 
 class ClientStepOut(NamedTuple):
@@ -129,60 +130,64 @@ def compute_gradient(apply_loss, unflatten, forward_weights, batch, mask,
     compression — the analog of the reference's requires_grad=False
     (frozen params never enter the gradient vector there), so top-k budgets
     and sketch capacity are spent only on trainable weights."""
-    n = jnp.sum(mask)
-    safe_n = jnp.maximum(n, 1.0)
-    grad_sum, loss_sum, metric_sums = _masked_loss_and_grad(
-        apply_loss, unflatten, forward_weights, batch, mask, rng,
-        microbatch_size=cfg.microbatch_size)
-    grad = grad_sum / safe_n
-    if trainable_mask is not None:
-        grad = grad * trainable_mask
-
-    # gradient clipping on the raw gradient, before weight decay — matches
-    # clip_grad_norm_ placement at ref fed_worker.py:290-292 (non-sketch)
-    if cfg.max_grad_norm is not None and cfg.mode != "sketch":
-        grad = _clip_to_norm(grad, cfg.max_grad_norm)
-
-    # weight decay folded into the gradient (ref utils.py:254-259); divided
-    # by num_workers because every worker adds it and the server sums;
-    # frozen coordinates get no decay (they're not trainable params)
-    if cfg.weight_decay != 0:
-        wd = (cfg.weight_decay / cfg.num_workers) * forward_weights
+    with phase("client_grad"):
+        n = jnp.sum(mask)
+        safe_n = jnp.maximum(n, 1.0)
+        grad_sum, loss_sum, metric_sums = _masked_loss_and_grad(
+            apply_loss, unflatten, forward_weights, batch, mask, rng,
+            microbatch_size=cfg.microbatch_size)
+        grad = grad_sum / safe_n
         if trainable_mask is not None:
-            wd = wd * trainable_mask
-        grad = grad + wd
+            grad = grad * trainable_mask
 
-    # worker-side differential privacy (ref fed_worker.py:304-309)
-    if cfg.do_dp:
-        grad = _clip_to_norm(grad, cfg.l2_norm_clip)
-        if cfg.dp_mode == "worker":
-            noise_rng = jax.random.fold_in(rng, 1)
-            grad = grad + (cfg.noise_multiplier *
-                           jnp.sqrt(float(cfg.num_workers)) *
-                           jax.random.normal(noise_rng, grad.shape))
+        # gradient clipping on the raw gradient, before weight decay —
+        # matches clip_grad_norm_ placement at ref fed_worker.py:290-292
+        # (non-sketch)
+        if cfg.max_grad_norm is not None and cfg.mode != "sketch":
+            grad = _clip_to_norm(grad, cfg.max_grad_norm)
 
-    # sketch is None in sketch mode when the round uses the
-    # sketch-after-aggregate fast path (see round.build_round_step):
-    # with no per-worker nonlinearity the sum of sketches equals the
-    # sketch of the sum, so the round sketches once after aggregation
-    if cfg.mode == "sketch" and sketch is not None:
-        # this call runs under the round's per-worker vmap, and on TPU
-        # backends it DISPATCHES the batched Pallas sketch kernel: the
-        # batch guard's custom_vmap rule (ops/sketch_kernels._batch_guard)
-        # selects the 2-D grid (W, n_tiles) variant, bit-identical per
-        # worker row to the XLA formulation, so all W sketches run on the
-        # kernel in one pallas_call. CPU, nested vmap, and over-budget
-        # shapes still fall back to the bit-identical XLA path — asserted
-        # by the sketch_batched graft-audit target (analysis/targets.py)
-        g = sketch.sketch_vec(grad, use_kernel=True)
-        if cfg.max_grad_norm is not None:
-            # sketch-space clip via l2 estimate (ref fed_worker.py:317-319)
-            est = sketch.l2estimate(g)
-            scale = jnp.where(est > cfg.max_grad_norm,
-                              cfg.max_grad_norm / jnp.maximum(est, 1e-12), 1.0)
-            g = g * scale
-    else:
-        g = grad
+        # weight decay folded into the gradient (ref utils.py:254-259); divided
+        # by num_workers because every worker adds it and the server sums;
+        # frozen coordinates get no decay (they're not trainable params)
+        if cfg.weight_decay != 0:
+            wd = (cfg.weight_decay / cfg.num_workers) * forward_weights
+            if trainable_mask is not None:
+                wd = wd * trainable_mask
+            grad = grad + wd
+
+        # worker-side differential privacy (ref fed_worker.py:304-309)
+        if cfg.do_dp:
+            grad = _clip_to_norm(grad, cfg.l2_norm_clip)
+            if cfg.dp_mode == "worker":
+                noise_rng = jax.random.fold_in(rng, 1)
+                grad = grad + (cfg.noise_multiplier *
+                               jnp.sqrt(float(cfg.num_workers)) *
+                               jax.random.normal(noise_rng, grad.shape))
+
+    with phase("compress"):
+        # sketch is None in sketch mode when the round uses the
+        # sketch-after-aggregate fast path (see round.build_round_step):
+        # with no per-worker nonlinearity the sum of sketches equals the
+        # sketch of the sum, so the round sketches once after aggregation
+        if cfg.mode == "sketch" and sketch is not None:
+            # this call runs under the round's per-worker vmap, and on TPU
+            # backends it DISPATCHES the batched Pallas sketch kernel: the
+            # batch guard's custom_vmap rule (ops/sketch_kernels._batch_guard)
+            # selects the 2-D grid (W, n_tiles) variant, bit-identical per
+            # worker row to the XLA formulation, so all W sketches run on the
+            # kernel in one pallas_call. CPU, nested vmap, and over-budget
+            # shapes still fall back to the bit-identical XLA path — asserted
+            # by the sketch_batched graft-audit target (analysis/targets.py)
+            g = sketch.sketch_vec(grad, use_kernel=True)
+            if cfg.max_grad_norm is not None:
+                # sketch-space clip via l2 estimate (ref fed_worker.py:317-319)
+                est = sketch.l2estimate(g)
+                scale = jnp.where(est > cfg.max_grad_norm,
+                                  cfg.max_grad_norm
+                                  / jnp.maximum(est, 1e-12), 1.0)
+                g = g * scale
+        else:
+            g = grad
 
     return g, loss_sum, metric_sums, n
 
@@ -199,61 +204,63 @@ def client_step(apply_loss, unflatten, ps_weights, batch, mask, velocity,
     (federated dropout-style partial participation). Coordinates masked
     out by the budget keep their error-feedback mass — they are simply
     not transmitted this round."""
-    if cfg.do_topk_down:
-        forward_weights = reconstruct_worker_weights(
-            ps_weights, stale_weights, cfg)
-        new_stale = forward_weights
-    else:
-        forward_weights = ps_weights
-        new_stale = None
+    with phase("client_grad"):
+        if cfg.do_topk_down:
+            forward_weights = reconstruct_worker_weights(
+                ps_weights, stale_weights, cfg)
+            new_stale = forward_weights
+        else:
+            forward_weights = ps_weights
+            new_stale = None
 
     g, loss_sum, metric_sums, n = compute_gradient(
         apply_loss, unflatten, forward_weights, batch, mask, rng, cfg, sketch,
         trainable_mask=trainable_mask)
 
-    # sum-of-gradients semantics: scale the mean grad back up by the true
-    # batch size so the server can divide by total datapoints (ref :190)
-    g = g * n
+    with phase("compress"):
+        # sum-of-gradients semantics: scale the mean grad back up by the true
+        # batch size so the server can divide by total datapoints (ref :190)
+        g = g * n
 
-    if cfg.local_momentum > 0:
-        velocity = g + cfg.local_momentum * velocity
-        carrier = velocity
-    else:
-        carrier = g
-
-    if cfg.error_type == "local":
-        error = error + carrier
-        to_transmit = error
-    else:
-        to_transmit = carrier
-
-    if cfg.mode == "local_topk":
-        if client_k is not None and not cfg.topk_approx_recall:
-            # per-client budget, selected in ONE pass: keep the first
-            # client_k slots of the stable selection order (the length-
-            # k_i prefix of the magnitude order — the same set the
-            # legacy topk-then-re-rank two-stage kept). Under the round
-            # vmap this is the batched per-row-k kernel path; masked
-            # coordinates keep their error-feedback mass below.
-            to_transmit = topk(to_transmit, cfg.k, row_k=client_k)
-        else:
-            to_transmit = topk(to_transmit, cfg.k,
-                               cfg.topk_approx_recall or None)
-            if client_k is not None:
-                # approx selection has no stable prefix to cut, so the
-                # budget still ranks the provisioned selection and keeps
-                # the client_k largest. Slots that point at zero
-                # coordinates (selection narrower than cfg.k) are
-                # harmless: where() writes 0.0 over 0.0.
-                _, sel = jax.lax.top_k(jnp.abs(to_transmit), cfg.k)
-                keep = jnp.zeros(to_transmit.shape, bool).at[sel].set(
-                    jnp.arange(cfg.k) < client_k)
-                to_transmit = jnp.where(keep, to_transmit, 0.0)
-        support = to_transmit != 0
-        if cfg.error_type == "local":
-            error = jnp.where(support, 0.0, error)   # error feedback
         if cfg.local_momentum > 0:
-            velocity = jnp.where(support, 0.0, velocity)  # factor masking
+            velocity = g + cfg.local_momentum * velocity
+            carrier = velocity
+        else:
+            carrier = g
+
+        if cfg.error_type == "local":
+            error = error + carrier
+            to_transmit = error
+        else:
+            to_transmit = carrier
+
+        if cfg.mode == "local_topk":
+            if client_k is not None and not cfg.topk_approx_recall:
+                # per-client budget, selected in ONE pass: keep the first
+                # client_k slots of the stable selection order (the length-
+                # k_i prefix of the magnitude order — the same set the
+                # legacy topk-then-re-rank two-stage kept). Under the round
+                # vmap this is the batched per-row-k kernel path; masked
+                # coordinates keep their error-feedback mass below.
+                to_transmit = topk(to_transmit, cfg.k, row_k=client_k)
+            else:
+                to_transmit = topk(to_transmit, cfg.k,
+                                   cfg.topk_approx_recall or None)
+                if client_k is not None:
+                    # approx selection has no stable prefix to cut, so the
+                    # budget still ranks the provisioned selection and keeps
+                    # the client_k largest. Slots that point at zero
+                    # coordinates (selection narrower than cfg.k) are
+                    # harmless: where() writes 0.0 over 0.0.
+                    _, sel = jax.lax.top_k(jnp.abs(to_transmit), cfg.k)
+                    keep = jnp.zeros(to_transmit.shape, bool).at[sel].set(
+                        jnp.arange(cfg.k) < client_k)
+                    to_transmit = jnp.where(keep, to_transmit, 0.0)
+            support = to_transmit != 0
+            if cfg.error_type == "local":
+                error = jnp.where(support, 0.0, error)   # error feedback
+            if cfg.local_momentum > 0:
+                velocity = jnp.where(support, 0.0, velocity)  # factor masking
 
     return ClientStepOut(transmit=to_transmit, velocity=velocity, error=error,
                          client_weights=new_stale, loss_sum=loss_sum,

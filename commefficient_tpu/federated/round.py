@@ -41,6 +41,7 @@ from commefficient_tpu.federated.client_store import (gather_rows,
 from commefficient_tpu.federated.server import make_sketch, server_update
 from commefficient_tpu.federated.state import (BufferState, ClientState,
                                                GradBuckets, ServerOptState)
+from commefficient_tpu.utils.tracing import phase
 
 
 @struct.dataclass
@@ -272,9 +273,10 @@ def build_round_step(apply_loss: Callable, unflatten: Callable,
                 return on_each_replica(
                     mesh, lambda c: sketch.sketch_range(c, off, True))(ch)
 
-            table = sketch_bucket(buckets.offsets[0], chunks[0])
-            for off, ch in zip(buckets.offsets[1:], chunks[1:]):
-                table = table + sketch_bucket(off, ch)
+            with phase("compress"):
+                table = sketch_bucket(buckets.offsets[0], chunks[0])
+                for off, ch in zip(buckets.offsets[1:], chunks[1:]):
+                    table = table + sketch_bucket(off, ch)
             return table
         return jnp.concatenate(chunks)
 
@@ -299,296 +301,311 @@ def build_round_step(apply_loss: Callable, unflatten: Callable,
                    client_ks=None):
         w = state.weights
         ids = client_ids
-        # epoch-tail rounds can carry fewer than num_workers real clients;
-        # padded slots have all-zero masks and must neither transmit, nor
-        # write state rows, nor count in the byte accounting
-        valid_w = jnp.any(mask > 0, axis=1)                         # (W,)
-        num_clients = (state.client_last_round.shape[0])
-        if cfg.client_quarantine:
-            # benched clients sit the round out entirely: no weight pull,
-            # no compute counted, no upload (their vmapped slot still
-            # computes — dead lanes — but every consumer masks it)
-            benched_w = state.quarantine[ids] > 0                   # (W,)
-            pull_w = jnp.logical_and(valid_w, ~benched_w)
-        else:
-            pull_w = valid_w
+        with phase("client_grad"):
+            # epoch-tail rounds can carry fewer than num_workers real clients;
+            # padded slots have all-zero masks and must neither transmit, nor
+            # write state rows, nor count in the byte accounting
+            valid_w = jnp.any(mask > 0, axis=1)                         # (W,)
+            num_clients = (state.client_last_round.shape[0])
+            if cfg.client_quarantine:
+                # benched clients sit the round out entirely: no weight pull,
+                # no compute counted, no upload (their vmapped slot still
+                # computes — dead lanes — but every consumer masks it)
+                benched_w = state.quarantine[ids] > 0                   # (W,)
+                pull_w = jnp.logical_and(valid_w, ~benched_w)
+            else:
+                pull_w = valid_w
 
-        # --- download accounting (before this round's update; exact count
-        # of weights changed since each participant last pulled). ``>=``
-        # because a weight updated at the END of the round a client last
-        # pulled in was not seen by that client (last_changed init is -2 so
-        # a never-changed weight never counts). O(d + W log W) histogram
-        # formulation: see download_counts above. ---
-        stale_round = state.client_last_round[ids]                  # (W,)
-        counts = download_counts(state.last_changed, stale_round)   # (W,)
-        download_floats = jnp.sum(
-            counts * pull_w.astype(jnp.int32)).astype(jnp.float32)
+        with phase("download_accounting"):
+            # --- download accounting (before this round's update; exact count
+            # of weights changed since each participant last pulled). ``>=``
+            # because a weight updated at the END of the round a client last
+            # pulled in was not seen by that client (last_changed init is -2 so
+            # a never-changed weight never counts). O(d + W log W) histogram
+            # formulation: see download_counts above. ---
+            stale_round = state.client_last_round[ids]                  # (W,)
+            counts = download_counts(state.last_changed, stale_round)   # (W,)
+            download_floats = jnp.sum(
+                counts * pull_w.astype(jnp.int32)).astype(jnp.float32)
 
         if fused_clients:
-            flat_cols = tuple(c.reshape((-1,) + c.shape[2:]) for c in batch)
-            flat_mask = mask.reshape(-1)
-            grad_sum, loss_total, metric_totals = \
-                client_lib._masked_loss_and_grad(
-                    apply_loss, unflatten, w, flat_cols, flat_mask, rng)
-            if trainable_mask is not None:
-                grad_sum = grad_sum * trainable_mask
-            total_n = jnp.sum(flat_mask)
-            if cfg.weight_decay != 0:
-                # each valid worker adds (wd/W)*w scaled by its datapoint
-                # count (client.compute_gradient); summed: (wd/W)*w*total_n
-                wd = (cfg.weight_decay / cfg.num_workers) * w * total_n
+            with phase("client_grad"):
+                flat_cols = tuple(c.reshape((-1,) + c.shape[2:])
+                                  for c in batch)
+                flat_mask = mask.reshape(-1)
+                grad_sum, loss_total, metric_totals = \
+                    client_lib._masked_loss_and_grad(
+                        apply_loss, unflatten, w, flat_cols, flat_mask, rng)
                 if trainable_mask is not None:
-                    wd = wd * trainable_mask
-                grad_sum = grad_sum + wd
-            if bucketed:
-                # slicing the fused gradient keeps each bucket's
-                # normalize+sketch an independent consumer of its slice
-                # (elementwise divide commutes with slicing: dense
-                # reassembly is bitwise identical)
-                agg = bucketed_compress(
-                    lambda o, s: grad_sum[o:o + s]
-                    / jnp.maximum(total_n, 1.0))
-            else:
-                agg = grad_sum / jnp.maximum(total_n, 1.0)
+                    grad_sum = grad_sum * trainable_mask
+                total_n = jnp.sum(flat_mask)
+                if cfg.weight_decay != 0:
+                    # each valid worker adds (wd/W)*w scaled by its datapoint
+                    # count (client.compute_gradient); summed: (wd/W)*w*total_n
+                    wd = (cfg.weight_decay / cfg.num_workers) * w * total_n
+                    if trainable_mask is not None:
+                        wd = wd * trainable_mask
+                    grad_sum = grad_sum + wd
+            with phase("reduce"):
+                if bucketed:
+                    # slicing the fused gradient keeps each bucket's
+                    # normalize+sketch an independent consumer of its slice
+                    # (elementwise divide commutes with slicing: dense
+                    # reassembly is bitwise identical)
+                    agg = bucketed_compress(
+                        lambda o, s: grad_sum[o:o + s]
+                        / jnp.maximum(total_n, 1.0))
+                else:
+                    agg = grad_sum / jnp.maximum(total_n, 1.0)
             new_vels = out_errors = out_client_weights = None
             contrib_w = valid_w   # no per-worker transmits to judge
         else:
-            if offload:
-                def _dec(enc):
-                    if enc is None or host_codec:   # already dense rows
-                        return enc
-                    return codec.decode_rows(enc)
-                vels, errs, stales = (_dec(rows.velocities),
-                                      _dec(rows.errors),
-                                      _dec(rows.weights))
-            else:
-                vels = gather_rows(state.clients.velocities, ids, codec)
-                errs = gather_rows(state.clients.errors, ids, codec)
-                stales = gather_rows(state.clients.weights, ids, codec)
-            rngs = jax.vmap(lambda i: jax.random.fold_in(rng, i))(ids)
-
-            axes = (None, 0, 0,
-                    None if vels is None else 0,
-                    None if errs is None else 0,
-                    None if stales is None else 0,
-                    None, 0)
-            if client_ks is not None:
-                out = jax.vmap(one_client, in_axes=axes + (0,))(
-                    w, batch, mask, vels, errs, stales, lr, rngs,
-                    client_ks)
-            else:
-                out = jax.vmap(one_client, in_axes=axes)(
-                    w, batch, mask, vels, errs, stales, lr, rngs)
-
-            if cfg.client_quarantine:
-                # graceful degradation: judge each contribution's
-                # finiteness individually; a non-finite slot is EXCLUDED
-                # (jnp.where, not a multiply — NaN * 0 is NaN) instead of
-                # poisoning the aggregate and tripping the global abort
-                finite_w = jnp.logical_and(
-                    jnp.isfinite(out.loss_sum),
-                    jnp.all(jnp.isfinite(
-                        out.transmit.reshape((out.transmit.shape[0], -1))),
-                        axis=1))                                    # (W,)
-                contrib_w = jnp.logical_and(pull_w, finite_w)
-                cb = contrib_w.reshape(
-                    (-1,) + (1,) * (out.transmit.ndim - 1))
-                total_n = jnp.sum(
-                    jnp.where(contrib_w, out.num_datapoints, 0.0))
-                if bucketed:
-                    agg = bucketed_compress(
-                        lambda o, s: jnp.sum(
-                            jnp.where(cb, out.transmit[:, o:o + s], 0.0),
-                            axis=0) / jnp.maximum(total_n, 1.0))
+            with phase("client_grad"):
+                if offload:
+                    def _dec(enc):
+                        if enc is None or host_codec:   # already dense rows
+                            return enc
+                        return codec.decode_rows(enc)
+                    vels, errs, stales = (_dec(rows.velocities),
+                                          _dec(rows.errors),
+                                          _dec(rows.weights))
                 else:
-                    agg = (jnp.sum(jnp.where(cb, out.transmit, 0.0),
-                                   axis=0) / jnp.maximum(total_n, 1.0))
-                loss_total = jnp.sum(
-                    jnp.where(contrib_w, out.loss_sum, 0.0))
-                metric_totals = jnp.sum(
-                    jnp.where(contrib_w[:, None], out.metric_sums, 0.0),
-                    axis=0)
-            else:
-                contrib_w = valid_w
-                total_n = jnp.sum(out.num_datapoints)
-                # the reduce across the worker/mesh axis (ref
-                # fed_worker.py:138 + fed_aggregator.py:329-332): sum
-                # transmits, divide by datapoints. Padded workers are
-                # zeroed: with local error feedback their transmit would
-                # otherwise leak the aliased client's error row.
-                valid_bcast = valid_w.reshape(
-                    (-1,) + (1,) * (out.transmit.ndim - 1))
-                if bucketed:
-                    # one independent (W, s_k) -> (s_k,) reduce per bucket
-                    # — on a mesh, one psum per bucket instead of one
-                    # monolithic (d,)-sized all-reduce
-                    agg = bucketed_compress(
-                        lambda o, s: jnp.sum(
-                            out.transmit[:, o:o + s] * valid_bcast,
-                            axis=0) / jnp.maximum(total_n, 1.0))
+                    vels = gather_rows(state.clients.velocities, ids, codec)
+                    errs = gather_rows(state.clients.errors, ids, codec)
+                    stales = gather_rows(state.clients.weights, ids, codec)
+                rngs = jax.vmap(lambda i: jax.random.fold_in(rng, i))(ids)
+
+                axes = (None, 0, 0,
+                        None if vels is None else 0,
+                        None if errs is None else 0,
+                        None if stales is None else 0,
+                        None, 0)
+                if client_ks is not None:
+                    out = jax.vmap(one_client, in_axes=axes + (0,))(
+                        w, batch, mask, vels, errs, stales, lr, rngs,
+                        client_ks)
                 else:
-                    agg = (jnp.sum(out.transmit * valid_bcast, axis=0) /
-                           jnp.maximum(total_n, 1.0))
-                loss_total = jnp.sum(out.loss_sum)
-                metric_totals = jnp.sum(out.metric_sums, axis=0)
+                    out = jax.vmap(one_client, in_axes=axes)(
+                        w, batch, mask, vels, errs, stales, lr, rngs)
+
+            with phase("reduce"):
+                if cfg.client_quarantine:
+                    # graceful degradation: judge each contribution's
+                    # finiteness individually; a non-finite slot is EXCLUDED
+                    # (jnp.where, not a multiply — NaN * 0 is NaN) instead of
+                    # poisoning the aggregate and tripping the global abort
+                    finite_w = jnp.logical_and(
+                        jnp.isfinite(out.loss_sum),
+                        jnp.all(jnp.isfinite(
+                            out.transmit.reshape((out.transmit.shape[0], -1))),
+                            axis=1))                                    # (W,)
+                    contrib_w = jnp.logical_and(pull_w, finite_w)
+                    cb = contrib_w.reshape(
+                        (-1,) + (1,) * (out.transmit.ndim - 1))
+                    total_n = jnp.sum(
+                        jnp.where(contrib_w, out.num_datapoints, 0.0))
+                    if bucketed:
+                        agg = bucketed_compress(
+                            lambda o, s: jnp.sum(
+                                jnp.where(cb, out.transmit[:, o:o + s], 0.0),
+                                axis=0) / jnp.maximum(total_n, 1.0))
+                    else:
+                        agg = (jnp.sum(jnp.where(cb, out.transmit, 0.0),
+                                       axis=0) / jnp.maximum(total_n, 1.0))
+                    loss_total = jnp.sum(
+                        jnp.where(contrib_w, out.loss_sum, 0.0))
+                    metric_totals = jnp.sum(
+                        jnp.where(contrib_w[:, None], out.metric_sums, 0.0),
+                        axis=0)
+                else:
+                    contrib_w = valid_w
+                    total_n = jnp.sum(out.num_datapoints)
+                    # the reduce across the worker/mesh axis (ref
+                    # fed_worker.py:138 + fed_aggregator.py:329-332): sum
+                    # transmits, divide by datapoints. Padded workers are
+                    # zeroed: with local error feedback their transmit would
+                    # otherwise leak the aliased client's error row.
+                    valid_bcast = valid_w.reshape(
+                        (-1,) + (1,) * (out.transmit.ndim - 1))
+                    if bucketed:
+                        # one independent (W, s_k) -> (s_k,) reduce per bucket
+                        # — on a mesh, one psum per bucket instead of one
+                        # monolithic (d,)-sized all-reduce
+                        agg = bucketed_compress(
+                            lambda o, s: jnp.sum(
+                                out.transmit[:, o:o + s] * valid_bcast,
+                                axis=0) / jnp.maximum(total_n, 1.0))
+                    else:
+                        agg = (jnp.sum(out.transmit * valid_bcast, axis=0) /
+                               jnp.maximum(total_n, 1.0))
+                    loss_total = jnp.sum(out.loss_sum)
+                    metric_totals = jnp.sum(out.metric_sums, axis=0)
             new_vels = out.velocity
             out_errors = out.error
             out_client_weights = out.client_weights
-        if sketch_after_aggregate and not bucketed:
-            # aggregate-side sketch via the batch-guard dispatch at batch
-            # 1 (bucketed runs already sketched per bucket above): same
-            # 2-D grid kernel as the per-worker vmapped paths, and the
-            # same call buffer.py makes — buffered lockstep stays bitwise
-            # equal to sync
-            agg = on_each_replica(
-                mesh, lambda a: sketch.sketch_vec_batched(a, True))(agg)
+        with phase("compress"):
+            if sketch_after_aggregate and not bucketed:
+                # aggregate-side sketch via the batch-guard dispatch at batch
+                # 1 (bucketed runs already sketched per bucket above): same
+                # 2-D grid kernel as the per-worker vmapped paths, and the
+                # same call buffer.py makes — buffered lockstep stays bitwise
+                # equal to sync
+                agg = on_each_replica(
+                    mesh, lambda a: sketch.sketch_vec_batched(a, True))(agg)
 
-        # --- in-round NaN guard (ref cv_train.py:221-229: the reference
-        # checks the round's loss BEFORE opt.step, so a breaching round's
-        # update is never applied). The host pipeline detects divergence
-        # one round late (RoundPipeline), but this device-side guard makes
-        # the breaching round — and every round after it — a state no-op,
-        # so abort-lag never pollutes weights/state/accounting. ---
-        loss_mean = loss_total / jnp.maximum(total_n, 1.0)
-        # under client_quarantine, loss_mean is post-exclusion — a single
-        # bad client no longer trips this; only a SERVER-side breach
-        # (post-exclusion divergence past the threshold) aborts the run
-        breach = jnp.logical_or(~jnp.isfinite(loss_mean),
-                                loss_mean > cfg.nan_threshold)
-        ok = jnp.logical_and(~breach, ~state.aborted)
-        okf = ok.astype(jnp.float32)
-        scatter_ids = jnp.where(jnp.logical_and(contrib_w, ok), ids,
-                                num_clients)                # OOB => dropped
+        with phase("server_update"):
+            # --- in-round NaN guard (ref cv_train.py:221-229: the reference
+            # checks the round's loss BEFORE opt.step, so a breaching round's
+            # update is never applied). The host pipeline detects divergence
+            # one round late (RoundPipeline), but this device-side guard makes
+            # the breaching round — and every round after it — a state
+            # no-op, so abort-lag never pollutes weights/state/accounting. ---
+            loss_mean = loss_total / jnp.maximum(total_n, 1.0)
+            # under client_quarantine, loss_mean is post-exclusion — a single
+            # bad client no longer trips this; only a SERVER-side breach
+            # (post-exclusion divergence past the threshold) aborts the run
+            breach = jnp.logical_or(~jnp.isfinite(loss_mean),
+                                    loss_mean > cfg.nan_threshold)
+            ok = jnp.logical_and(~breach, ~state.aborted)
+            okf = ok.astype(jnp.float32)
+            scatter_ids = jnp.where(jnp.logical_and(contrib_w, ok), ids,
+                                    num_clients)            # OOB => dropped
 
-        server_lr = 1.0 if is_fedavg else lr
-        noise_rng = jax.random.fold_in(rng, 0x5e77e7)
-        # on TPU (or forced dispatch) the exact-mode true_topk/sketch
-        # recovery inside runs the fused streaming top-k path
-        # (ops/topk_kernels, gated by cfg.server_fused) — bitwise-
-        # identical to the lax.top_k chain, audited by
-        # `graft-audit --target server_update_fused`
-        update, new_opt = server_update(agg, state.opt, cfg, server_lr,
-                                        sketch=sketch, noise_rng=noise_rng,
-                                        mesh=mesh)
-        if trainable_mask is not None:
-            update = update * trainable_mask
-        # aborted => no weight/last_changed movement. A select, not a
-        # multiply: a non-finite breach makes the update itself NaN, and
-        # NaN * 0 = NaN would poison the weights on the breach round
-        # instead of freezing them
-        update = jnp.where(ok, update, 0.0)
-        if cfg.grad_dim != cfg.grad_size:
-            # model-axis pad coordinates must NEVER move: sketch recovery
-            # (hash-collision noise puts pad indices in the top-k like any
-            # true-zero coordinate) and server DP noise both produce
-            # nonzero pad updates; letting them through would drift the
-            # pads and bill them to every client's download accounting
-            update = update.at[cfg.grad_size:].set(0.0)
-        new_opt = jax.tree.map(lambda new, old: jnp.where(ok, new, old),
-                               new_opt, state.opt)
-        new_w = w - update
+            server_lr = 1.0 if is_fedavg else lr
+            noise_rng = jax.random.fold_in(rng, 0x5e77e7)
+            # on TPU (or forced dispatch) the exact-mode true_topk/sketch
+            # recovery inside runs the fused streaming top-k path
+            # (ops/topk_kernels, gated by cfg.server_fused) — bitwise-
+            # identical to the lax.top_k chain, audited by
+            # `graft-audit --target server_update_fused`
+            update, new_opt = server_update(agg, state.opt, cfg, server_lr,
+                                            sketch=sketch, noise_rng=noise_rng,
+                                            mesh=mesh)
+            if trainable_mask is not None:
+                update = update * trainable_mask
+            # aborted => no weight/last_changed movement. A select, not a
+            # multiply: a non-finite breach makes the update itself NaN, and
+            # NaN * 0 = NaN would poison the weights on the breach round
+            # instead of freezing them
+            update = jnp.where(ok, update, 0.0)
+            if cfg.grad_dim != cfg.grad_size:
+                # model-axis pad coordinates must NEVER move: sketch recovery
+                # (hash-collision noise puts pad indices in the top-k like any
+                # true-zero coordinate) and server DP noise both produce
+                # nonzero pad updates; letting them through would drift the
+                # pads and bill them to every client's download accounting
+                update = update.at[cfg.grad_size:].set(0.0)
+            new_opt = jax.tree.map(lambda new, old: jnp.where(ok, new, old),
+                                   new_opt, state.opt)
+            new_w = w - update
 
-        if (cfg.mode == "true_topk" and cfg.local_momentum > 0
-                and new_vels is not None):
-            # momentum factor masking of participating clients' velocities at
-            # the *global* top-k support — the reference intends this but
-            # crashes on a missing global (fed_aggregator.py:528-533 vs :219)
-            support = (update != 0)[None, :]
-            new_vels = jnp.where(support, 0.0, new_vels)
+            if (cfg.mode == "true_topk" and cfg.local_momentum > 0
+                    and new_vels is not None):
+                # momentum factor masking of participating clients'
+                # velocities at the *global* top-k support — the reference
+                # intends this but crashes on a missing global
+                # (fed_aggregator.py:528-533 vs :219)
+                support = (update != 0)[None, :]
+                new_vels = jnp.where(support, 0.0, new_vels)
 
-        if offload:
-            keep = jnp.logical_and(valid_w, ok)               # (W,)
+            if offload:
+                keep = jnp.logical_and(valid_w, ok)               # (W,)
 
-            def _keep(new_dense, old_enc):
-                # slot-freeze on the stored rows: a frozen slot returns
-                # its input (dense row under a host-side codec, encoding
-                # otherwise) verbatim — bitwise, never a re-encode of
-                # its decode
-                if old_enc is None:
-                    return None
-                if new_dense is None:
-                    return old_enc
-                if host_codec:   # rows travel dense; arena encodes
-                    return jnp.where(keep[:, None], new_dense, old_enc)
-                return select_rows(keep, codec.encode_rows(new_dense),
-                                   old_enc)
+                def _keep(new_dense, old_enc):
+                    # slot-freeze on the stored rows: a frozen slot returns
+                    # its input (dense row under a host-side codec, encoding
+                    # otherwise) verbatim — bitwise, never a re-encode of
+                    # its decode
+                    if old_enc is None:
+                        return None
+                    if new_dense is None:
+                        return old_enc
+                    if host_codec:   # rows travel dense; arena encodes
+                        return jnp.where(keep[:, None], new_dense, old_enc)
+                    return select_rows(keep, codec.encode_rows(new_dense),
+                                       old_enc)
 
-            out_rows = ClientState(velocities=_keep(new_vels,
-                                                    rows.velocities),
-                                   errors=_keep(out_errors, rows.errors),
-                                   weights=_keep(out_client_weights,
-                                                 rows.weights))
-            new_clients = state.clients
-        else:
-            out_rows = None
-            new_clients = ClientState(
-                velocities=scatter_rows(state.clients.velocities,
-                                        scatter_ids, new_vels, codec),
-                errors=scatter_rows(state.clients.errors, scatter_ids,
-                                    out_errors, codec),
-                weights=scatter_rows(state.clients.weights, scatter_ids,
-                                     out_client_weights, codec),
+                out_rows = ClientState(velocities=_keep(new_vels,
+                                                        rows.velocities),
+                                       errors=_keep(out_errors, rows.errors),
+                                       weights=_keep(out_client_weights,
+                                                     rows.weights))
+                new_clients = state.clients
+            else:
+                out_rows = None
+                new_clients = ClientState(
+                    velocities=scatter_rows(state.clients.velocities,
+                                            scatter_ids, new_vels, codec),
+                    errors=scatter_rows(state.clients.errors, scatter_ids,
+                                        out_errors, codec),
+                    weights=scatter_rows(state.clients.weights, scatter_ids,
+                                         out_client_weights, codec),
+                )
+
+        with phase("download_accounting"):
+            new_last_changed = jnp.where(update != 0, state.round_idx,
+                                         state.last_changed)
+            if cfg.client_quarantine:
+                # every client that PULLED this round re-syncs its download
+                # baseline, even if its contribution was later dropped as
+                # non-finite (the bytes moved regardless)
+                pull_ids = jnp.where(jnp.logical_and(pull_w, ok), ids,
+                                     num_clients)
+                new_client_last = state.client_last_round.at[pull_ids].set(
+                    state.round_idx, mode="drop")
+                # bench clients whose update came back non-finite for
+                # quarantine_rounds rounds; everyone else's bench clock ticks
+                # down one per applied round
+                bad_ids = jnp.where(
+                    jnp.logical_and(jnp.logical_and(pull_w, ~finite_w), ok),
+                    ids, num_clients)
+                new_quarantine = jnp.maximum(
+                    state.quarantine - ok.astype(jnp.int32), 0
+                ).at[bad_ids].set(jnp.int32(cfg.quarantine_rounds),
+                                  mode="drop")
+            else:
+                new_client_last = state.client_last_round.at[scatter_ids].set(
+                    state.round_idx, mode="drop")
+                new_quarantine = state.quarantine
+            # a guarded round transferred nothing (the reference returns
+            # before accumulating the breaching round's bytes,
+            # cv_train.py:222-226)
+            download_bytes = 4.0 * download_floats * okf
+
+        with phase("server_update"):
+            new_state = FedState(
+                weights=new_w, opt=new_opt, clients=new_clients,
+                round_idx=state.round_idx + ok.astype(jnp.int32),
+                last_changed=new_last_changed,
+                client_last_round=new_client_last,
+                aborted=jnp.logical_or(state.aborted, breach),
+                weights_version=state.weights_version + ok.astype(jnp.int32),
+                quarantine=new_quarantine,
+                buffer=state.buffer,
             )
-
-        new_last_changed = jnp.where(update != 0, state.round_idx,
-                                     state.last_changed)
-        if cfg.client_quarantine:
-            # every client that PULLED this round re-syncs its download
-            # baseline, even if its contribution was later dropped as
-            # non-finite (the bytes moved regardless)
-            pull_ids = jnp.where(jnp.logical_and(pull_w, ok), ids,
-                                 num_clients)
-            new_client_last = state.client_last_round.at[pull_ids].set(
-                state.round_idx, mode="drop")
-            # bench clients whose update came back non-finite for
-            # quarantine_rounds rounds; everyone else's bench clock ticks
-            # down one per applied round
-            bad_ids = jnp.where(
-                jnp.logical_and(jnp.logical_and(pull_w, ~finite_w), ok),
-                ids, num_clients)
-            new_quarantine = jnp.maximum(
-                state.quarantine - ok.astype(jnp.int32), 0
-            ).at[bad_ids].set(jnp.int32(cfg.quarantine_rounds),
-                              mode="drop")
-        else:
-            new_client_last = state.client_last_round.at[scatter_ids].set(
-                state.round_idx, mode="drop")
-            new_quarantine = state.quarantine
-
-        new_state = FedState(
-            weights=new_w, opt=new_opt, clients=new_clients,
-            round_idx=state.round_idx + ok.astype(jnp.int32),
-            last_changed=new_last_changed,
-            client_last_round=new_client_last,
-            aborted=jnp.logical_or(state.aborted, breach),
-            weights_version=state.weights_version + ok.astype(jnp.int32),
-            quarantine=new_quarantine,
-            buffer=state.buffer,
-        )
-        # a guarded round transferred nothing (the reference returns before
-        # accumulating the breaching round's bytes, cv_train.py:222-226)
-        metrics = {
-            "loss_sum": loss_total,
-            "metric_sums": metric_totals,
-            "num_datapoints": total_n,
-            # host-visible guard state: the ONLY reliable abort signal for
-            # a training loop (a later pipelined round's loss can look
-            # healthy again because the guard froze the weights)
-            "aborted": jnp.logical_or(state.aborted, breach),
-            "download_bytes": 4.0 * download_floats * okf,
-            "upload_bytes": (4.0 * cfg.upload_floats_per_client *
-                             jnp.sum(pull_w.astype(jnp.float32)) * okf),
-            "update_l2": jnp.linalg.norm(update),
-        }
-        if cfg.client_quarantine:
-            # observability for the degraded path (gated so the legacy
-            # metric pytree — and every trajectory test built on it —
-            # is unchanged when the flag is off)
-            metrics["dropped_contributions"] = jnp.sum(
-                jnp.logical_and(pull_w, ~finite_w).astype(jnp.float32)
-            ) * okf
-            metrics["num_quarantined"] = jnp.sum(
-                (new_quarantine > 0).astype(jnp.int32))
+            metrics = {
+                "loss_sum": loss_total,
+                "metric_sums": metric_totals,
+                "num_datapoints": total_n,
+                # host-visible guard state: the ONLY reliable abort signal for
+                # a training loop (a later pipelined round's loss can look
+                # healthy again because the guard froze the weights)
+                "aborted": jnp.logical_or(state.aborted, breach),
+                "download_bytes": download_bytes,
+                # (a guarded round uploaded nothing either)
+                "upload_bytes": (4.0 * cfg.upload_floats_per_client *
+                                 jnp.sum(pull_w.astype(jnp.float32)) * okf),
+                "update_l2": jnp.linalg.norm(update),
+            }
+            if cfg.client_quarantine:
+                # observability for the degraded path (gated so the legacy
+                # metric pytree — and every trajectory test built on it —
+                # is unchanged when the flag is off)
+                metrics["dropped_contributions"] = jnp.sum(
+                    jnp.logical_and(pull_w, ~finite_w).astype(jnp.float32)
+                ) * okf
+                metrics["num_quarantined"] = jnp.sum(
+                    (new_quarantine > 0).astype(jnp.int32))
         if offload:
             return new_state, out_rows, metrics
         return new_state, metrics

@@ -254,7 +254,7 @@ def estimates_pallas(cs, table, interpret: bool = False):
                                            jnp.float32),
             scratch_shapes=[pltpu.VMEM((cs.r, TILE_BLOCKS, LANES),
                                        jnp.float32)],
-            interpret=interp,
+            interpret=interp, name="estimates_pallas",
         )(tab)
         return out.reshape(-1)[:cs.d]
 
@@ -275,7 +275,7 @@ def estimates_pallas(cs, table, interpret: bool = False):
             scratch_shapes=[pltpu.VMEM((cs.r, TILE_BLOCKS, LANES),
                                        jnp.float32)],
             compiler_params=_batched_params(cs),
-            interpret=interp,
+            interpret=interp, name="estimates_pallas",
         )(tabs)
         return out.reshape(B, -1)[:, :cs.d]
 
@@ -389,7 +389,7 @@ def sketch_vec_pallas(cs, vec, interpret: bool = False,
             scratch_shapes=[
                 pltpu.VMEM((cs.r, TILE_BLOCKS, LANES), jnp.float32),
             ],
-            interpret=interp,
+            interpret=interp, name="sketch_vec_pallas",
         )(_padded(v))
 
     def batched_call(vs):
@@ -411,7 +411,7 @@ def sketch_vec_pallas(cs, vec, interpret: bool = False,
                 pltpu.VMEM((cs.r, TILE_BLOCKS, LANES), jnp.float32),
             ],
             compiler_params=_batched_params(cs),
-            interpret=interp,
+            interpret=interp, name="sketch_vec_pallas",
         )(vp)
 
     return _batch_guard(

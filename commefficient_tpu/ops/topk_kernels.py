@@ -103,6 +103,10 @@ TILE_N = TILE_BLOCKS * LANES          # elements per grid step (8,192)
 _NIBBLES = 16                          # candidates per radix round
 _SENTINEL = np.int32(-(2 ** 31))      # below every valid score's bits
 _I32_MAX = np.int32(2 ** 31 - 1)
+#: every kernel here carries a ``name=``: it is the instruction's name in a
+#: device trace (an unnamed call inside the radix ``while`` read
+#: ``closed_call.N``). The select pass takes its public caller's name.
+COUNT_KERNEL_NAME = "radix_count_pallas"
 
 
 def topk_kernel_ok(approx_recall=None) -> bool:
@@ -242,7 +246,7 @@ def _count_call(streams, cands, *, n, n_tiles, interp, src,
             out_specs=pl.BlockSpec((1, _NIBBLES), lambda b, i: (b, 0),
                                    **cand_smem),
             out_shape=jax.ShapeDtypeStruct((B, _NIBBLES), jnp.int32),
-            interpret=interp)(*streams, cands)
+            interpret=interp, name=COUNT_KERNEL_NAME)(*streams, cands)
     if src == "est":
         in_specs = [pl.BlockSpec((cs.r, cs.c_eff), lambda i: (0, 0),
                                  memory_space=pltpu.VMEM)]
@@ -259,7 +263,8 @@ def _count_call(streams, cands, *, n, n_tiles, interp, src,
         out_specs=pl.BlockSpec((1, _NIBBLES), lambda i: (0, 0), **cand_smem),
         out_shape=jax.ShapeDtypeStruct((1, _NIBBLES), jnp.int32),
         scratch_shapes=scratch,
-        interpret=interp)(*streams, cands.reshape(1, _NIBBLES))
+        interpret=interp,
+        name=COUNT_KERNEL_NAME)(*streams, cands.reshape(1, _NIBBLES))
     return out.reshape(_NIBBLES)
 
 
@@ -397,7 +402,7 @@ def _select_kernel(*refs, n, src, coeffs, nwindows, r, batched,
             store(mask_ref, sel.astype(jnp.int32))
 
 
-def _select_call(streams, t, take, *, n, n_tiles, interp, src,
+def _select_call(streams, t, take, *, n, n_tiles, interp, src, name,
                  cs=None, batched=False, with_mask=False):
     kern = partial(_select_kernel, n=n, src=src,
                    coeffs=None if cs is None else cs.coeffs,
@@ -423,7 +428,8 @@ def _select_call(streams, t, take, *, n, n_tiles, interp, src,
             out_shape=[jax.ShapeDtypeStruct((B, rows, LANES), dt)
                        for dt in out_dtypes],
             scratch_shapes=[pltpu.SMEM((1, 1), jnp.int32)],
-            interpret=interp)(*streams, t.reshape(B, 1), take.reshape(B, 1))
+            interpret=interp,
+            name=name)(*streams, t.reshape(B, 1), take.reshape(B, 1))
         return tuple(o.reshape(B, -1)[:, :n] for o in outs)
     tile = pl.BlockSpec((TILE_BLOCKS, LANES), lambda i: (i, 0),
                         memory_space=pltpu.VMEM)
@@ -443,7 +449,8 @@ def _select_call(streams, t, take, *, n, n_tiles, interp, src,
         out_shape=[jax.ShapeDtypeStruct((rows, LANES), dt)
                    for dt in out_dtypes],
         scratch_shapes=scratch,
-        interpret=interp)(*streams, t.reshape(1, 1), take.reshape(1, 1))
+        interpret=interp,
+        name=name)(*streams, t.reshape(1, 1), take.reshape(1, 1))
     return tuple(o.reshape(-1)[:n] for o in outs)
 
 
@@ -559,7 +566,8 @@ def topk_select_pallas(vec, kk, *, k, with_mask=False, interpret=False):
             lambda cands: _count_call((vp,), cands, n=n, n_tiles=n_tiles,
                                       interp=interp, src="plain"), kk_)
         outs = _select_call((vp,), t, ntake, n=n, n_tiles=n_tiles,
-                            interp=interp, src="plain", with_mask=with_mask)
+                            interp=interp, src="plain", with_mask=with_mask,
+                            name="topk_select_pallas")
         return outs if with_mask else outs[0]
 
     def fallback(v, kk_):
@@ -576,7 +584,7 @@ def topk_select_pallas(vec, kk, *, k, with_mask=False, interpret=False):
                                       batched=True), kks)
         outs = _select_call((vp,), t, ntake, n=n, n_tiles=n_tiles,
                             interp=interp, src="plain", batched=True,
-                            with_mask=with_mask)
+                            with_mask=with_mask, name="topk_select_pallas")
         return outs if with_mask else outs[0]
 
     return _guard2(kernel_call, fallback, batched_call)(vec, kk)
@@ -618,7 +626,8 @@ def fused_true_topk_pallas(gradient, vvelocity, verror, *, k, rho,
                                       interp=interp, src="plain"),
             jnp.int32(k))
         return _select_call((errp, vp), t, ntake, n=n, n_tiles=n_tiles,
-                            interp=interp, src="resid")
+                            interp=interp, src="resid",
+                            name="fused_true_topk_pallas")
 
     return _guard_fallback_only(kernel_call, fb)(gradient, vvelocity,
                                                  verror)
@@ -644,7 +653,8 @@ def unsketch_select_pallas(cs, table, *, k, interpret=False):
                                       interp=interp, src="est", cs=cs),
             jnp.int32(k))
         return _select_call((tab,), t, ntake, n=n, n_tiles=n_tiles,
-                            interp=interp, src="est", cs=cs)
+                            interp=interp, src="est", cs=cs,
+                            name="unsketch_select_pallas")
 
     def fallback(tab):
         est = cs.estimates(tab, use_kernel=False)

@@ -25,6 +25,7 @@ from commefficient_tpu.models import get_model
 from commefficient_tpu.training.args import args_to_config, build_parser
 from commefficient_tpu.utils.logging import TableLogger, Timer
 from commefficient_tpu.utils.schedules import cifar_lr_schedule
+from commefficient_tpu.utils.tracing import compile_counters, span
 
 DATASET_CLASSES = {"CIFAR10": 10, "CIFAR100": 100, "EMNIST": 62,
                    "ImageNet": 1000, "Synthetic": 10, "Digits": 10,
@@ -101,6 +102,7 @@ def build_learner(args, sample_input, num_classes, channels, mesh=None):
 def train(args, mesh=None, max_rounds=None, log=True):
     from commefficient_tpu.federated.api import set_transfer_guard
     set_transfer_guard(getattr(args, "transfer_guard", "disallow"))
+    compile_counters()
     if mesh is not None and mesh.shape.get("seq", 1) > 1:
         # CV models have no sequence dimension; a seq axis here would
         # silently replicate and waste chips (the dead-flag defect class,
@@ -124,8 +126,9 @@ def train(args, mesh=None, max_rounds=None, log=True):
         raise ValueError("--mesh expert=E (MoE expert parallelism) is "
                          "wired for the gpt2 entrypoint; CV models have "
                          "no MoE blocks")
-    train_set = make_dataset(args, train=True)
-    val_set = make_dataset(args, train=False)
+    with span("setup.data"):
+        train_set = make_dataset(args, train=True)
+        val_set = make_dataset(args, train=False)
     args.num_clients = train_set.num_clients
     num_classes = (train_set.num_classes
                    if hasattr(train_set, "num_classes")
@@ -135,8 +138,9 @@ def train(args, mesh=None, max_rounds=None, log=True):
     batcher = FedBatcher(train_set, args.num_workers, args.local_batch_size,
                          seed=args.seed)
     ids0, cols0, mask0 = next(iter(batcher.epoch()))
-    learner = build_learner(args, cols0[0][0][:1], num_classes, channels,
-                            mesh=mesh)
+    with span("setup.learner"):
+        learner = build_learner(args, cols0[0][0][:1], num_classes,
+                                channels, mesh=mesh)
 
     # periodic crash-consistent checkpoints + resume (the probe round
     # above runs before resume() so its sampler/aug draws — identical in
@@ -164,7 +168,9 @@ def train(args, mesh=None, max_rounds=None, log=True):
         # the learner rng: evaluate() splits the shared stream, and a
         # logging-only flag must not perturb the training trajectory
         rng_before = learner.rng
-        val0 = learner.evaluate(val_batches(val_set, args.valid_batch_size))
+        with span("setup.eval"):
+            val0 = learner.evaluate(val_batches(val_set,
+                                                args.valid_batch_size))
         learner.rng = rng_before
         if log:
             print(f"eval before start: loss={val0['loss']:.4f} "

@@ -31,6 +31,7 @@ from commefficient_tpu.training.args import (args_to_config, build_parser,
                                              resolve_fused_ce)
 from commefficient_tpu.utils.logging import TableLogger, Timer
 from commefficient_tpu.utils.schedules import gpt2_lr_schedule
+from commefficient_tpu.utils.tracing import compile_counters, span
 
 
 def save_pretrained(log_dir: str, learner, gpt2_config: GPT2Config,
@@ -65,9 +66,11 @@ def make_persona(args, tokenizer, train: bool):
 def train(args, mesh=None, max_rounds=None, log=True):
     from commefficient_tpu.federated.api import set_transfer_guard
     set_transfer_guard(getattr(args, "transfer_guard", "disallow"))
-    tokenizer = get_tokenizer(args.model_checkpoint)
-    train_set = make_persona(args, tokenizer, train=True)
-    val_set = make_persona(args, tokenizer, train=False)
+    compile_counters()
+    with span("setup.data"):
+        tokenizer = get_tokenizer(args.model_checkpoint)
+        train_set = make_persona(args, tokenizer, train=True)
+        val_set = make_persona(args, tokenizer, train=False)
     args.num_clients = train_set.num_clients
     from commefficient_tpu.parallel.mesh import padded_num_clients
     num_clients = padded_num_clients(args.num_clients, mesh)
@@ -299,11 +302,12 @@ def train(args, mesh=None, max_rounds=None, log=True):
         raise ValueError("--scan_rounds > 1 is a sync-mode optimization; "
                          "the buffered server dispatches cohorts through "
                          "a host event loop")
-    learner = learner_cls(_Wrap(), cfg, loss_tr, loss_val,
-                          jax.random.PRNGKey(args.seed), sample_in,
-                          lr_schedule=sched, mesh=mesh,
-                          init_params=init_params, param_specs=param_specs,
-                          **learner_extra)
+    with span("setup.learner"):
+        learner = learner_cls(_Wrap(), cfg, loss_tr, loss_val,
+                              jax.random.PRNGKey(args.seed), sample_in,
+                              lr_schedule=sched, mesh=mesh,
+                              init_params=init_params,
+                              param_specs=param_specs, **learner_extra)
 
     # periodic crash-consistent checkpoints + resume (training/preempt.py;
     # this entrypoint never materialized a probe round, so the restored
@@ -327,7 +331,9 @@ def train(args, mesh=None, max_rounds=None, log=True):
         # baseline validation at init (ref cv_train.py:91-103); rng
         # snapshot keeps the training trajectory flag-independent
         rng_before = learner.rng
-        val0 = learner.evaluate(val_batches(val_set, args.valid_batch_size))
+        with span("setup.eval"):
+            val0 = learner.evaluate(val_batches(val_set,
+                                                args.valid_batch_size))
         learner.rng = rng_before
         if np.size(val0["metrics"]) >= 3:
             nll0 = (float(val0["metrics"][1]) /

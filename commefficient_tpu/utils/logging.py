@@ -2,6 +2,7 @@
 
 from __future__ import annotations
 
+import contextlib
 import os
 import time
 from datetime import datetime
@@ -98,14 +99,24 @@ class Timer:
         return delta_t
 
 
+@contextlib.contextmanager
 def profile_ctx(trace_dir):
     """jax.profiler trace context, or a no-op when ``trace_dir`` is falsy
-    (the TPU analog of the reference's cProfile hooks, SURVEY.md §5)."""
-    import contextlib
+    (the TPU analog of the reference's cProfile hooks, SURVEY.md §5). On
+    exit the program's own spans and counters (utils/tracing.py) are left
+    in ``trace_dir/spans.json``, beside the device trace that holds the
+    same spans as ``fed:*`` annotations."""
     if not trace_dir:
-        return contextlib.nullcontext()
+        yield
+        return
     import jax
-    return jax.profiler.trace(trace_dir)
+
+    from commefficient_tpu.utils import tracing
+    try:
+        with jax.profiler.trace(trace_dir):
+            yield
+    finally:
+        tracing.write(trace_dir)
 
 
 def make_logdir(cfg) -> str:

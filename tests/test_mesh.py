@@ -213,3 +213,33 @@ def test_clients_x_model_sketch_nondivisible_cols():
     out = ln.train_round(np.arange(2), (Xs, ys),
                          np.ones((2, 4), np.float32))
     assert np.isfinite(out["loss"])
+
+
+def test_a_state_handed_in_on_one_device_is_placed_on_the_mesh():
+    """Weights made on one device and swapped into a mesh learner's state
+    (how a harness sets its own initial weights) are put where the round's
+    in_shardings want them by the ``state`` setter — explicitly, so the
+    guarded evaluate and dispatch do not have to; the round's own outputs
+    pass through untouched."""
+    ids, batch, mask = make_problem()
+    cfg_kw = dict(mode="sketch", error_type="virtual", virtual_momentum=0.9,
+                  k=20, num_rows=3, num_cols=500)
+    ln = make_learner(cfg_kw, make_mesh(8))
+    want = ln.state.weights.sharding
+    w0 = jnp.asarray(np.asarray(ln.state.weights) * 0.5)
+    assert w0.sharding != want
+    ln.state = ln.state.replace(weights=w0)
+    assert ln.state.weights.sharding == want
+    np.testing.assert_array_equal(np.asarray(ln.state.weights),
+                                  np.asarray(w0))
+    ln.evaluate([((batch[0][0], batch[1][0]), np.ones(16, np.float32))])
+    out = ln.train_round(ids, batch, mask)        # under the guard
+    assert np.isfinite(out["loss"])
+    held = ln.state
+    ln.state = held
+    assert ln.state is held
+    # and one device off-mesh stays as it is
+    single = make_learner(cfg_kw, None)
+    s = single.state.replace(weights=w0)
+    single.state = s
+    assert single.state is s

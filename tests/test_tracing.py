@@ -1,0 +1,359 @@
+"""The program's tracing module (utils/tracing.py) and where it is used:
+spans and counters on the host path, phase scopes and kernel names in the
+round program."""
+
+import contextlib
+import json
+import re
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from commefficient_tpu.analysis.walker import _sub_jaxprs, iter_eqns
+from commefficient_tpu.config import FedConfig
+from commefficient_tpu.federated.api import FedLearner
+from commefficient_tpu.federated.losses import make_cv_loss
+from commefficient_tpu.models import TinyMLP
+from commefficient_tpu.ops import sketch_kernels
+from commefficient_tpu.utils import tracing
+
+KERNEL_NAMES = {"sketch_vec_pallas", "estimates_pallas",
+                "radix_count_pallas", "unsketch_select_pallas",
+                "topk_select_pallas", "fused_true_topk_pallas"}
+
+MODES = {
+    "sketch": dict(mode="sketch", error_type="virtual", virtual_momentum=0.9,
+                   k=3, num_rows=3, num_cols=20),
+    "uncompressed": dict(mode="uncompressed", error_type="none",
+                         virtual_momentum=0.9),
+    "true_topk": dict(mode="true_topk", error_type="virtual",
+                      virtual_momentum=0.9, k=3),
+    "local_topk": dict(mode="local_topk", error_type="local",
+                       local_momentum=0.9, k=3),
+}
+#: the phases a mode's round has something in
+EXPECTED = {
+    "sketch": set(tracing.PHASES),
+    "uncompressed": set(tracing.PHASES) - {"compress"},
+    "true_topk": set(tracing.PHASES) - {"compress"},
+    "local_topk": set(tracing.PHASES),
+}
+W, B = 2, 4
+
+
+@pytest.fixture(autouse=True)
+def fresh_recorder():
+    tracing.reset()
+    yield
+    tracing.reset()
+
+
+def make_learner(**kw):
+    model = TinyMLP(num_classes=2, hidden=4)
+    cfg = FedConfig(weight_decay=5e-4, num_workers=W, num_clients=6,
+                    lr_scale=0.05, **kw)
+    return FedLearner(model, cfg, make_cv_loss(model), None,
+                      jax.random.PRNGKey(1), np.zeros((1, 8), np.float32))
+
+
+def round_args():
+    return (jnp.zeros((W,), jnp.int32),
+            (jnp.zeros((W, B, 8)), jnp.zeros((W, B), jnp.int32)),
+            jnp.ones((W, B)), jnp.float32(0.1), jax.random.PRNGKey(0))
+
+
+def host_batch(r):
+    rng = np.random.RandomState(r)
+    return (rng.choice(6, W, replace=False),
+            (rng.randn(W, B, 8).astype(np.float32),
+             rng.randint(0, 2, (W, B)).astype(np.int32)),
+            np.ones((W, B), np.float32))
+
+
+# ------------------------------------------------------------ the recorder
+
+def test_spans_nest_and_self_time_adds_up():
+    with tracing.span("outer"):
+        time.sleep(0.002)
+        for _ in range(2):
+            with tracing.span("inner"):
+                time.sleep(0.002)
+    spans = tracing.snapshot()["open"]["spans"]
+    outer, inner = spans["outer"], spans["inner"]
+    assert (outer[1], inner[1]) == (1, 2)
+    assert inner[0] == inner[2] >= 2 * 2_000_000      # a leaf: all its own
+    assert outer[2] == outer[0] - inner[0]            # self = total - kids
+    assert outer[2] >= 2_000_000
+
+
+def test_a_span_closes_when_its_body_raises():
+    with pytest.raises(KeyError):
+        with tracing.span("outer"):
+            with tracing.span("inner"):
+                raise KeyError("x")
+    with tracing.span("after"):
+        pass
+    spans = tracing.snapshot()["open"]["spans"]
+    assert set(spans) == {"outer", "inner", "after"}
+    assert spans["after"][0] == spans["after"][2]     # no stale parent
+
+
+def test_round_mark_files_totals_under_the_round_and_bounds_the_ring():
+    for i in range(tracing.RING_ROUNDS + 10):
+        tracing.round_mark(i)
+        with tracing.span("work"):
+            pass
+        tracing.count("things", 2)
+    snap = tracing.snapshot()
+    assert len(snap["rounds"]) == tracing.RING_ROUNDS
+    assert snap["rounds"][-1]["round"] == tracing.RING_ROUNDS + 8
+    assert snap["open"]["round"] == tracing.RING_ROUNDS + 9
+    assert all(r["spans"]["work"][1] == 1 and r["counts"] == {"things": 2}
+               for r in snap["rounds"][-100:])
+    value, changed = snap["counters"]["things"]
+    assert value == 2 * (tracing.RING_ROUNDS + 10)
+    assert changed >= snap["open"]["t_ns"]
+    assert tracing.span_seconds("work") > 0
+
+
+def test_write_and_profile_ctx_leave_spans_json(tmp_path, monkeypatch):
+    from commefficient_tpu.utils.logging import profile_ctx
+    monkeypatch.setattr(jax.profiler, "trace",
+                        lambda d: contextlib.nullcontext())
+    with profile_ctx(str(tmp_path)):
+        tracing.round_mark(0)
+        with tracing.span("round.dispatch"):
+            pass
+    with open(tmp_path / "spans.json") as f:
+        snap = json.load(f)
+    assert snap["open"]["spans"]["round.dispatch"][1] == 1
+    with profile_ctx(None):         # falsy: no trace, nothing written
+        pass
+
+
+def test_compile_counters_count_a_program_once():
+    tracing.compile_counters()
+    tracing.compile_counters()      # registers once
+
+    @jax.jit
+    def fresh(x):
+        return jnp.sin(x) * 3 + jax.jit(jnp.cos)(x)
+
+    def programs():
+        return tracing.snapshot()["counters"].get("compile.programs",
+                                                  [0])[0]
+
+    x = jnp.arange(4.0)
+    before = programs()
+    fresh(x).block_until_ready()
+    first = programs()
+    fresh(x).block_until_ready()
+    assert (first - before, programs() - first) == (1, 0)
+    counters = tracing.snapshot()["counters"]
+    for name in ("compile.trace_s", "compile.lower_s", "compile.backend_s"):
+        assert counters[name][0] > 0
+    # the inner jit is traced inside the outer one: counted once, so the
+    # traced seconds cannot exceed the wall time of this test by much
+    assert counters["compile.trace_s"][0] < 60
+
+
+# ------------------------------------------------- phases in the round
+
+def eqn_phases(jaxpr, inherited=""):
+    """[(innermost phase or None, primitive)] of every leaf equation; an
+    equation inside a call-like one sits under the caller's scopes too."""
+    out = []
+    for eqn in jaxpr.eqns:
+        stack = inherited + "/" + str(eqn.source_info.name_stack)
+        subs = [] if eqn.primitive.name == "pallas_call" else list(
+            _sub_jaxprs(eqn.params))
+        for sub in subs:
+            out += eqn_phases(sub, stack)
+        if not subs:
+            found = re.findall(r"phase:([a-z_]+)", stack)
+            out.append((found[-1] if found else None, eqn.primitive.name))
+    return out
+
+
+@pytest.mark.parametrize("mode", sorted(MODES))
+def test_every_equation_of_the_round_lies_in_one_phase(mode):
+    learner = make_learner(**MODES[mode])
+    with sketch_kernels.force_dispatch("kernel"):
+        jaxpr = jax.make_jaxpr(learner._round)(learner.state, *round_args())
+    phases = eqn_phases(jaxpr.jaxpr)
+    assert len(phases) > 50
+    outside = [prim for phase, prim in phases if phase is None]
+    assert outside == []
+    assert {phase for phase, _ in phases} == EXPECTED[mode]
+
+
+@pytest.mark.parametrize("mode", ["sketch", "uncompressed"])
+def test_lowered_round_carries_the_phases(mode):
+    learner = make_learner(**MODES[mode])
+    text = learner._round.lower(learner.state, *round_args()).as_text(
+        debug_info=True)
+    for phase in tracing.PHASES:
+        assert (f"phase:{phase}" in text) == (phase in EXPECTED[mode])
+
+
+@pytest.mark.parametrize("mode", ["sketch", "uncompressed"])
+def test_op_phases_places_the_compiled_round(mode):
+    learner = make_learner(**MODES[mode])
+    compiled = learner._round.lower(learner.state, *round_args()).compile()
+    phases = tracing.op_phases(compiled)
+    assert len(phases) > 50
+    placed = sum(1 for p in phases.values() if p != tracing.OTHER)
+    assert placed >= 0.99 * len(phases)
+    assert set(phases.values()) - {tracing.OTHER} <= set(tracing.PHASES)
+    assert EXPECTED[mode] - {"reduce"} <= set(phases.values())
+    # by its text too, and every key is an instruction_key of itself
+    assert tracing.op_phases(compiled.as_text()) == phases
+    assert all(tracing.instruction_key(k) == k for k in phases)
+
+
+HLO = """HloModule toy
+
+%fused_computation.1 (p: f32[8]) -> f32[8] {
+  %p = f32[8]{0} parameter(0)
+  ROOT %m = f32[8]{0} multiply(%p, %p), metadata={op_name="jit(f)/phase:client_grad/mul"}
+}
+
+%body (t: (s32[], f32[8])) -> (s32[], f32[8]) {
+  %t = (s32[], f32[8]{0}) parameter(0)
+  %g = f32[8]{0} get-tuple-element(%t), index=1
+  %k = f32[8]{0} custom-call(%g), custom_call_target="tpu_custom_call", metadata={op_name="jit(f)/phase:server_update/while/body/radix_count_pallas/pallas_call"}, backend_config={"x": {"y": 1}}
+  ROOT %r = (s32[], f32[8]{0}) tuple(%g, %k)
+}
+
+ENTRY %main (a: f32[8]) -> f32[8] {
+  %a = f32[8]{0:T(8)S(1)} parameter(0)
+  %fusion.1 = f32[8]{0} fusion(%a), kind=kLoop, calls=%fused_computation.1
+  %copy.1 = f32[8]{0} copy(%fusion.1)
+  %all-reduce.1 = f32[8]{0} all-reduce(%copy.1), replica_groups={}, to_apply=%add, metadata={op_name="jit(f)/phase:client_grad/transpose(jvp(m))/dot"}
+  %w = (s32[], f32[8]{0}) while(%init), condition=%cond, body=%body, metadata={op_name="jit(f)/phase:server_update/while"}
+  %lonely = f32[8]{0} iota(), iota_dimension=0
+  ROOT %out = f32[8]{0} add(%all-reduce.1, %lonely), metadata={op_name="jit(f)/phase:server_update/phase:download_accounting/add"}
+}
+"""
+
+HLO_WANT = {
+    "%m = f32[8]{0}(%p, %p)": "client_grad",
+    "%fusion.1 = f32[8]{0}(%a)": "client_grad",      # what it calls
+    "%copy.1 = f32[8]{0}(%fusion.1)": "client_grad",  # what it reads
+    "%all-reduce.1 = f32[8]{0}(%copy.1)": "reduce",   # the cohort's psum
+    "%k = f32[8]{0}(%g)": "server_update",
+    "%g = f32[8]{0}(%t)": "server_update",            # the while holds it
+    "%out = f32[8]{0}(%all-reduce.1, %lonely)": "download_accounting",
+    "%lonely = f32[8]{0}()": "download_accounting",   # what reads it
+}
+
+
+@pytest.mark.parametrize("key", sorted(HLO_WANT))
+def test_op_phases_rules_on_a_hand_made_module(key):
+    assert tracing.op_phases(HLO)[key] == HLO_WANT[key]
+
+
+@pytest.mark.parametrize("printed, key", [
+    # the profiler's print of an instruction and as_text's give one key
+    ("%copy.21 = f32[100,50]{1,0:T(8,128)S(1)} copy(f32[100,50]"
+     "{0,1:T(8,128)} %mask.1)",
+     "%copy.21 = f32[100,50]{1,0:T(8,128)S(1)}(%mask.1)"),
+    ("  ROOT %copy.21 = f32[100,50]{1,0:T(8,128)S(1)} copy(%mask.1), "
+     "sharding={replicated}, metadata={op_name=\"a(b)\"}",
+     "%copy.21 = f32[100,50]{1,0:T(8,128)S(1)}(%mask.1)"),
+    ("%s.1 = ((f32[5]{0}), f32[2]{0:S(1)}, s32[]{:S(2)}) async-start("
+     "f32[5]{0:T(8)} %v.1), calls=%async_computation.28",
+     "%s.1 = ((f32[5]{0}), f32[2]{0:S(1)}, s32[]{:S(2)})(%v.1)"),
+    ("%s.1 = ((f32[5]{0}), f32[2]{0:S(1)}, s32[]{:S(2)}) slice-start(%v.1),"
+     " slice={[0:2]}",
+     "%s.1 = ((f32[5]{0}), f32[2]{0:S(1)}, s32[]{:S(2)})(%v.1)"),
+    ("%w.2 = (s32[]{:T(128)}, f32[9]{0}) while((s32[]{:T(128)}, /*index=1*/"
+     "f32[9]{0}) %tuple.8), condition=%c, body=%b",
+     "%w.2 = (s32[]{:T(128)}, f32[9]{0})(%tuple.8)"),
+])
+def test_instruction_key_is_the_same_for_both_printers(printed, key):
+    assert tracing.instruction_key(printed) == key
+
+
+@pytest.mark.parametrize("mode", ["sketch", "true_topk", "local_topk"])
+def test_every_pallas_call_of_the_round_is_named(mode):
+    learner = make_learner(**MODES[mode])
+    with sketch_kernels.force_dispatch("kernel"):
+        jaxpr = jax.make_jaxpr(learner._round)(learner.state, *round_args())
+    names = [site.eqn.params["name"] for site in iter_eqns(jaxpr)
+             if site.primitive == "pallas_call"]
+    assert names and set(names) <= KERNEL_NAMES
+    if mode == "sketch":
+        assert set(names) == {"sketch_vec_pallas", "radix_count_pallas",
+                              "unsketch_select_pallas"}
+
+
+# ------------------------------------------------------ spans on the path
+
+def test_rounds_leave_dispatch_and_sync_spans_under_their_index():
+    learner = make_learner(**MODES["sketch"])
+    pipe = learner.pipeline()
+    for r in range(3):
+        pipe.push(learner.train_round_async(*host_batch(r)))
+    pipe.flush()
+    learner.evaluate([])
+    snap = tracing.snapshot()
+    rounds = snap["rounds"][1:] + [snap["open"]]     # [0] is set-up
+    assert [r["round"] for r in rounds] == [0, 1, 2]
+    assert all(r["spans"]["round.dispatch"][1] == 1 for r in rounds)
+    assert [r["spans"].get("round.sync", [0, 0])[1] for r in rounds] == [
+        0, 1, 2]                                     # one round behind
+    assert snap["counters"]["rounds"][0] == 3
+    assert "eval" in snap["open"]["spans"]
+
+
+def test_offload_pipeline_times_itself_in_spans_not_in_stats():
+    learner = make_learner(client_state_offload=True, **MODES["local_topk"])
+    for r in range(2):
+        learner.train_round(*host_batch(r))
+    spans = tracing.snapshot()["open"]["spans"]
+    for r in tracing.snapshot()["rounds"]:
+        for name, tot in r["spans"].items():
+            have = spans.setdefault(name, [0, 0, 0])
+            spans[name] = [a + b for a, b in zip(have, tot)]
+    stats = learner._offload_pipe.stats
+    assert set(stats) == {"gathers", "prefetch_hits", "rows_from_pending",
+                          "flushed_rounds"}
+    assert spans["offload.gather"][1] == stats["gathers"] == 2
+    assert spans["offload.scatter"][1] == stats["flushed_rounds"] == 2
+    assert spans["offload.flush"][1] == 2
+    assert spans["offload.scatter"][0] <= spans["offload.flush"][0]
+
+
+def test_two_round_train_leaves_the_data_and_round_totals(monkeypatch):
+    from commefficient_tpu.training import cv
+    monkeypatch.setattr(cv, "get_transforms",
+                        lambda name, train: (lambda cols, rng: cols))
+    args = cv.build_parser(default_lr=0.4).parse_args(
+        "--dataset_name Synthetic --model TinyMLP --mode sketch "
+        "--error_type virtual --k 10 --num_rows 3 --num_cols 100 "
+        "--num_workers 4 --local_batch_size 8 --eval_before_start "
+        "--valid_batch_size 64".split())
+    np.random.seed(0)
+    cv.train(args, max_rounds=2, log=False)
+    snap = tracing.snapshot()
+    setup, first = snap["rounds"][0], snap["rounds"][1]
+    assert setup["round"] is None
+    for name in ("setup.data", "setup.learner", "setup.eval", "eval"):
+        assert setup["spans"][name][1] == 1
+    assert setup["spans"]["setup.eval"][2] < setup["spans"]["setup.eval"][0]
+    assert [r["round"] for r in snap["rounds"][1:]] + [
+        snap["open"]["round"]] == [0, 1]
+    for name in ("data.sample", "data.fetch", "data.augment",
+                 "data.assemble", "data.h2d", "round.dispatch"):
+        assert first["spans"][name][1] >= 1, name
+    assert first["spans"]["data.fetch"][1] == 4 * first["spans"][
+        "data.h2d"][1]                               # once a client
+    assert first["counts"]["data.rows"] == 32 * first["spans"]["data.h2d"][1]
+    assert first["counts"]["data.h2d_bytes"] > 0
+    last = snap["open"]["spans"]
+    assert last["round.dispatch"][1] == 1 and last["round.sync"][1] == 2
+    assert snap["counters"]["compile.programs"][0] >= 1
