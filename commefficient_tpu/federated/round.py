@@ -129,33 +129,30 @@ def fused_clients_eligible(cfg: FedConfig) -> bool:
             and not cfg.client_quarantine)
 
 
+# participants counted in one pass over ``last_changed``: the compiler fuses
+# an unrolled step's sibling reductions into one multi-output fusion, so the
+# d-long operand is read once for eight stale rounds (v5e, d = 6.57 M,
+# W = 100: 3.9 ms at one participant a pass, 0.66 ms at eight; PERF.md, PR 27)
+_COUNTS_PER_PASS = 8
+
+
 def download_counts(last_changed: jax.Array,
                     stale_round: jax.Array) -> jax.Array:
     """Per-participant count of weights changed since it last pulled:
-    ``counts[w] = #{i : last_changed[i] >= stale_round[w]}`` — WITHOUT the
-    (W, d) boolean comparison matrix (496 MB at gpt2-small W=4 — pure
-    accounting overhead on the critical path). Bucket each coordinate by
-    how many sorted participant stale-rounds are <= its last_changed value
-    (one O(d log W) searchsorted), histogram the buckets, and read each
-    participant's count off the cumulative histogram — O(d) memory,
-    byte-identical integers (tests/test_download_accounting.py asserts
-    bit-for-bit equality with the dense formulation and that no (W, d)
-    intermediate survives in the jaxpr). Shared by the sync round and the
+    ``counts[w] = #{i : last_changed[i] >= stale_round[w]}``, as a loop
+    over the W participants whose step is one fused compare-and-sum that
+    streams ``last_changed``. No gather, scatter or sort with a d-long
+    operand (a TPU has no fast path for them: the searchsorted + histogram
+    this replaces was 391 of a 565 ms ResNet-9 round) and no (W, d)
+    comparison matrix (496 MB at gpt2-small W=4): O(d) reads a pass, O(W)
+    memory, the dense formulation's integers bit for bit
+    (tests/test_download_accounting.py). Shared by the sync round and the
     buffered cohort (federated/buffer.py)."""
-    W = stale_round.shape[0]
-    d_total = last_changed.shape[0]
-    order = jnp.argsort(stale_round)
-    sorted_stale = stale_round[order]                           # (W,)
-    # bucket[i] = #{w : sorted_stale[w] <= last_changed[i]}; for the
-    # participant of sort rank k, last_changed[i] < sorted_stale[k]
-    # iff bucket[i] <= k (ascending sort: every entry <= a value
-    # strictly below sorted_stale[k] sits at an index < k)
-    buckets = jnp.searchsorted(sorted_stale, last_changed,
-                               side="right")                    # (d,)
-    hist = jnp.zeros((W + 1,), jnp.int32).at[buckets].add(1)
-    below_sorted = jnp.cumsum(hist)[:W]   # #{i : lc[i] < sorted_stale[k]}
-    return jnp.zeros((W,), jnp.int32).at[order].set(
-        d_total - below_sorted)                                 # (W,)
+    def count(_, stale):
+        return None, jnp.sum(last_changed >= stale, dtype=jnp.int32)
+
+    return jax.lax.scan(count, None, stale_round,
+                        unroll=_COUNTS_PER_PASS)[1]                # (W,)
 
 
 def build_round_step(apply_loss: Callable, unflatten: Callable,
@@ -321,8 +318,8 @@ def build_round_step(apply_loss: Callable, unflatten: Callable,
             # of weights changed since each participant last pulled). ``>=``
             # because a weight updated at the END of the round a client last
             # pulled in was not seen by that client (last_changed init is -2 so
-            # a never-changed weight never counts). O(d + W log W) histogram
-            # formulation: see download_counts above. ---
+            # a never-changed weight never counts). Streamed comparison, one
+            # participant a step: see download_counts above. ---
             stale_round = state.client_last_round[ids]                  # (W,)
             counts = download_counts(state.last_changed, stale_round)   # (W,)
             download_floats = jnp.sum(

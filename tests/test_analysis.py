@@ -17,6 +17,7 @@ import numpy as np
 import pytest
 
 from commefficient_tpu import analysis as A
+from commefficient_tpu.federated.round import download_counts
 
 
 # --------------------------------------------------------------------------
@@ -109,18 +110,10 @@ def test_mutation_materialized_attention_scores_fails():
 
 
 def test_clean_program_passes():
-    """The histogram accounting formulation — the shape the contract
+    """The streamed-comparison accounting — the shape the contract
     demands — audits clean under the same dims."""
     d, w = 46, 3
-
-    def histogram_accounting(last_changed, stale):
-        order = jnp.sort(stale)
-        buckets = jnp.searchsorted(order, last_changed, side="right")
-        hist = jnp.zeros((w + 1,), jnp.int32).at[buckets].add(1)
-        tail = jnp.cumsum(hist[::-1])[::-1]
-        return tail[1:]
-
-    rep = A.audit(histogram_accounting, jnp.zeros((d,), jnp.int32),
+    rep = A.audit(download_counts, jnp.zeros((d,), jnp.int32),
                   jnp.zeros((w,), jnp.int32), dims={"W": w, "d": d})
     assert rep.ok, [str(v) for v in rep.violations]
 
