@@ -23,6 +23,7 @@ from __future__ import annotations
 import contextlib
 import os
 import shutil
+import sys
 
 import numpy as np
 
@@ -212,10 +213,27 @@ def run(cell, config, seed, seconds, trace, ctx):
     with instrumented(cv, probe, ctx["reference"], seed), \
             probe.compile_events():
         try:
-            cv.train(args, mesh=mesh)
+            _, last = cv.train(args, mesh=mesh)
         except StopWindow:
             probe.close_window()
         else:
-            raise RuntimeError("train() returned before the window closed: "
-                               "raise --num_epochs in the configuration")
+            ended_window(probe, last)
     return probe
+
+
+def ended_window(probe, last):
+    """``train`` returned by itself. A training that diverged inside the
+    window (the device guard's ``aborted``) closes the window there and goes
+    on to ``correct``, which counts it as failed; anything else has no
+    window to report and raises."""
+    if not last.get("aborted"):
+        raise RuntimeError("train() returned before the window closed: "
+                           "raise --num_epochs in the configuration")
+    what = (f"training diverged: loss {last['loss']} at round "
+            f"{probe.dispatched} (train() aborted on --nan_threshold)")
+    if probe.t_start is None:
+        raise RuntimeError(what + ", before the window opened after "
+                           f"{probe.warmup_rounds} warm-up rounds")
+    print(f"benchmark: {what}, {probe.window_rounds} rounds into the "
+          f"window; the run counts as failed", file=sys.stderr)
+    probe.close_window()

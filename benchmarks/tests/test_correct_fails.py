@@ -17,11 +17,6 @@ import pytest
 import run as harness
 
 
-@pytest.fixture(scope="module")
-def tiny():
-    return harness.load_cell(rehearsal="tiny")
-
-
 def devices():
     import jax
     return jax.devices()[:1]
