@@ -10,8 +10,10 @@ Contract preserved from the reference:
 * validation data is centralized (client_id == -1 downstream)
 
 Difference: instead of per-item ``__getitem__`` through a torch DataLoader,
-batches are fetched as whole per-client index arrays (``get_client_batch``) —
-the host side stays numpy and hands fixed-shape arrays to the device.
+batches are fetched as whole per-client index arrays (``get_flat_batch``) —
+the host side stays numpy and hands fixed-shape arrays to the device. A
+dataset whose rows and transform allow it also offers a ``round_builder``:
+a whole round's images in one native pass (``FedBatcher`` asks for it).
 """
 
 from __future__ import annotations
@@ -22,6 +24,8 @@ from typing import List, Optional, Tuple
 
 import numpy as np
 
+from commefficient_tpu import native
+from commefficient_tpu.data.transforms import PadCropTrain
 from commefficient_tpu.utils.tracing import count, span
 
 
@@ -67,6 +71,9 @@ class FedDataset:
             stats = json.load(f)
         self.images_per_client = np.array(stats["images_per_client"])
         self.num_val_images = stats["num_val_images"]
+        # each natural client's [start, end) of the flat index space
+        self._client_ends = np.cumsum(self.images_per_client)
+        self._client_starts = self._client_ends - self.images_per_client
 
     @property
     def num_clients(self) -> int:
@@ -104,10 +111,8 @@ class FedDataset:
         """Map global flat indices to (natural_client, idx_within) pairs."""
         if self.do_iid:
             flat_idxs = self.iid_shuffle[flat_idxs]
-        cumsum = np.cumsum(self.images_per_client)
-        client = np.searchsorted(cumsum, flat_idxs, side="right")
-        starts = np.hstack([[0], cumsum[:-1]])
-        return client, flat_idxs - starts[client]
+        client = np.searchsorted(self._client_ends, flat_idxs, side="right")
+        return client, flat_idxs - self._client_starts[client]
 
     def get_flat_batch(self, flat_idxs: np.ndarray) -> Tuple[np.ndarray, ...]:
         """Fetch arbitrary flat train indices (crossing natural clients)."""
@@ -128,6 +133,14 @@ class FedDataset:
             with span("data.augment"):
                 cols = self.transform(cols, self.rng)
         return tuple(cols)
+
+    def round_builder(self):
+        """What builds a whole round's columns at once (see
+        ``PadCropRound`` for the interface), or None where this dataset
+        has no such thing: the batcher then builds the round client by
+        client through ``get_flat_batch``. Either way the same batches and
+        the same draws from ``self.rng``."""
+        return None
 
     def get_val_batch(self, idxs: np.ndarray) -> Tuple[np.ndarray, ...]:
         cols = list(self._get_val_batch(np.asarray(idxs)))
@@ -209,6 +222,18 @@ class PreparedArrayDataset(FedDataset):
                        "num_val_images": len(test_y),
                        "version": self.version}, f)
 
+    def round_builder(self):
+        t = self.transform
+        if not (self.train and isinstance(t, PadCropTrain)
+                and native.lib() is not None):
+            return None
+        image = (t.size, t.size, len(t.mean))
+        if not all(a.dtype == np.uint8 and a.shape == (n,) + image
+                   and a.flags.c_contiguous for a, n in
+                   zip(self.client_datasets, self.images_per_client)):
+            return None
+        return PadCropRound(self)
+
     def _get_train_batch(self, client_id: int, idxs: np.ndarray):
         imgs = self.client_datasets[client_id][idxs]
         # target == natural client id == the class (ref fed_cifar.py:79-81)
@@ -217,3 +242,50 @@ class PreparedArrayDataset(FedDataset):
     def _get_val_batch(self, idxs: np.ndarray):
         return (self.test_images[idxs],
                 self.test_targets[idxs].astype(np.int32))
+
+
+class PadCropRound:
+    """A round of a ``PreparedArrayDataset`` under a ``PadCropTrain``
+    transform: every image read as uint8 from its client's array, where it
+    lies, and written once, normalized, cropped and flipped, at its place
+    in the round's image column. What ``get_flat_batch`` gives client by
+    client, bit for bit, with the same draws from ``dataset.rng``."""
+
+    def __init__(self, dataset):
+        self.dataset = dataset
+        t = dataset.transform
+        image = (t.size, t.size, len(t.mean))
+        #: (row shape, dtype) of the columns: the images, then the targets
+        self.specs = [(image, np.dtype(np.float32)), ((), np.dtype(np.int32))]
+        self._table = t.table()
+        self._row_bytes = int(np.prod(image))
+        self._base = np.array([a.ctypes.data for a in dataset.client_datasets],
+                              np.int64)
+
+    def draw(self, counts) -> np.ndarray:
+        """The (y, x, flip) rows of a round whose clients fetch ``counts``
+        images, drawn client by client as ``get_flat_batch`` draws them."""
+        ds = self.dataset
+        with span("data.augment"):
+            return np.concatenate(
+                [ds.transform.draw(ds.rng, int(n)) for n in counts])
+
+    def write(self, flat_idxs, params, slots, images):
+        """Image ``flat_idxs[i]`` under ``params[i]`` into image slot
+        ``slots[i]`` of ``images`` ((W, B, H, W, C) float32). Returns the
+        rows of the other columns: the targets."""
+        ds, t = self.dataset, self.dataset.transform
+        with span("data.fetch"):
+            clients, within = ds._flat_to_natural(np.asarray(flat_idxs))
+            # the kernel reads raw addresses, where fancy indexing raises
+            if len(clients) and (within.min() < 0
+                                 or clients.max() >= len(self._base)):
+                raise IndexError(f"flat index out of range for {len(ds)} "
+                                 f"train images")
+            src = self._base[clients] + within * self._row_bytes
+        count("data.rows", len(src))
+        with span("data.augment"):
+            native.pad_crop_round(src, slots, params, self._table, images,
+                                  t.padding, t.mode == "reflect", t.fill)
+        # target == natural client id == the class (ref fed_cifar.py:79-81)
+        return (clients.astype(np.int32),)

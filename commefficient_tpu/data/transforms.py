@@ -14,6 +14,14 @@ Two implementations per train pipeline:
   ``RandomState`` — randomness is sampled in Python and only deterministic
   pixel math moves to C++ — so they produce identical augmentations
   (cross-checked in tests/test_native.py).
+
+The augmentation stream is per fetched batch, i.e. per client of a round:
+each call draws its images' parameters, in order, from the dataset's one
+generator. ``PadCropTrain.draw`` takes a batch's draws as arrays (the same
+stream as the numpy stages' scalar draws), and a whole round built in one
+native pass (``data/fed_dataset.py::PadCropRound``) still draws client by
+client, so which path builds a batch never changes the stream, and a
+preempted run replays it (``FedBatcher.epoch(skip)``, docs/ROBUSTNESS.md).
 """
 
 from __future__ import annotations
@@ -196,49 +204,76 @@ def fused_rrc_train(mean, std, size: int, hflip_p: float = 0.5,
     return fn
 
 
-def fused_pad_crop_train(mean, std, size: int, padding: int,
-                         mode: str = "reflect", fill: float = 0.0,
-                         hflip_p: float = 0.5):
-    """normalize + random_crop + hflip with the geometric part as one
-    native pass (bit-identical to the numpy stages — it is pure copies)."""
-    aug = ([random_crop(size, padding, mode, fill)] +
-           ([random_hflip(hflip_p)] if hflip_p > 0 else []))
-    numpy_fn = compose(normalize(mean, std), *aug)
-    norm_fn = normalize(mean, std)
-    # NOTE: normalize runs first (matching the numpy pipeline and reference
-    # transforms.py:47), so a constant ``fill`` lands in the output
-    # verbatim, post-normalization — e.g. EMNIST's fill=1.0 means "1.0 in
-    # normalized space", not raw white
+class PadCropTrain:
+    """normalize + random_crop + hflip, split into a draw and an apply.
 
-    def fn(cols, rng):
+    ``draw(rng, n)`` takes a batch's (y, x, flip) from ``rng`` as arrays;
+    the stream is the numpy stages' own (two scalar ``randint``s an image,
+    then one ``rand(n)``), so the generator ends in the same state. Called
+    on a batch, the geometric part runs as one native pass over the
+    normalized floats (pure copies: bit-identical to the numpy stages),
+    or through the numpy stages where the library is absent. A dataset
+    that holds uint8 rows in memory can instead hand the draws, ``table()``
+    and the geometry to ``native.pad_crop_round``, which writes a whole
+    round's pixels once (``PreparedArrayDataset.round_builder``)."""
+
+    def __init__(self, mean, std, size: int, padding: int,
+                 mode: str = "reflect", fill: float = 0.0,
+                 hflip_p: float = 0.5):
+        if not 0 <= padding < size:
+            raise ValueError(f"padding {padding} must lie in [0, {size})")
+        self.mean, self.std = mean, std
+        self.size, self.padding = size, padding
+        self.mode, self.fill, self.hflip_p = mode, fill, hflip_p
+        aug = ([random_crop(size, padding, mode, fill)] +
+               ([random_hflip(hflip_p)] if hflip_p > 0 else []))
+        # NOTE: normalize runs first (matching the reference
+        # transforms.py:47), so a constant ``fill`` lands in the output
+        # verbatim, post-normalization -- e.g. EMNIST's fill=1.0 means
+        # "1.0 in normalized space", not raw white
+        self._normalize = normalize(mean, std)
+        self._numpy_fn = compose(self._normalize, *aug)
+
+    def draw(self, rng, n: int) -> np.ndarray:
+        """(n, 3) int32 rows of (y, x, flip) for one batch of ``n``
+        images. Per batch, never across batches: one draw over several
+        clients' images would interleave offsets and flips differently
+        and change the stream."""
+        params = np.empty((n, 3), np.int32)
+        params[:, :2] = rng.randint(0, 2 * self.padding + 1, size=(n, 2))
+        params[:, 2] = (rng.rand(n) < self.hflip_p) if self.hflip_p > 0 else 0
+        return params
+
+    def table(self) -> np.ndarray:
+        """(256, C) float32: what ``normalize`` makes of each uint8 value
+        in each channel, computed by ``normalize`` itself."""
+        values = np.arange(256, dtype=np.uint8)[:, None]
+        return self._normalize(
+            [np.broadcast_to(values, (256, len(self.mean)))], None)[0]
+
+    def __call__(self, cols, rng):
         img = cols[0]
         # the kernel (like the numpy stage, which writes into
         # empty_like(img)) only supports size == H == W; anything else
         # goes to the numpy path, which fails loudly on the mismatch
-        if (native.lib() is None or img.shape[1] != size
-                or img.shape[2] != size):
-            return numpy_fn(cols, rng)
-        cols = norm_fn(cols, rng)
-        img = cols[0]
-        B = img.shape[0]
-        params = np.empty((B, 3), np.int32)
-        for i in range(B):
-            params[i, 0] = rng.randint(0, 2 * padding + 1)
-            params[i, 1] = rng.randint(0, 2 * padding + 1)
-        params[:, 2] = (rng.rand(B) < hflip_p) if hflip_p > 0 else 0
-        cols[0] = native.pad_crop_batch(img, params, padding,
-                                        mode == "reflect", fill)
+        if (native.lib() is None or img.shape[1] != self.size
+                or img.shape[2] != self.size):
+            return self._numpy_fn(cols, rng)
+        cols = self._normalize(cols, rng)
+        cols[0] = native.pad_crop_batch(
+            cols[0], self.draw(rng, img.shape[0]), self.padding,
+            self.mode == "reflect", self.fill)
         return cols
-    return fn
 
 
-cifar10_train_transforms = fused_pad_crop_train(
+
+cifar10_train_transforms = PadCropTrain(
     CIFAR10_MEAN, CIFAR10_STD, 32, 4, "reflect")
 cifar10_test_transforms = normalize(CIFAR10_MEAN, CIFAR10_STD)
-cifar100_train_transforms = fused_pad_crop_train(
+cifar100_train_transforms = PadCropTrain(
     CIFAR100_MEAN, CIFAR100_STD, 32, 4, "reflect")
 cifar100_test_transforms = normalize(CIFAR100_MEAN, CIFAR100_STD)
-femnist_train_transforms = fused_pad_crop_train(
+femnist_train_transforms = PadCropTrain(
     FEMNIST_MEAN, FEMNIST_STD, 28, 2, "constant", fill=1.0, hflip_p=0.0)
 femnist_test_transforms = normalize(FEMNIST_MEAN, FEMNIST_STD)
 # stored uint8 @ 256 -> RandomResizedCrop(224)+flip (train) /

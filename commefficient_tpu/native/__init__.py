@@ -20,7 +20,7 @@ import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "fedio.cpp")
-_ABI = 1
+_ABI = 2
 
 _lock = threading.Lock()
 _cached = False
@@ -63,9 +63,12 @@ def _declare(h) -> None:
                                        ctypes.c_int, ctypes.c_int,
                                        ctypes.c_float, ctypes.c_int]
     h.fedio_pad_crop_batch.restype = None
-    h.fedio_gather_rows.argtypes = [
-        u8p, np.ctypeslib.ndpointer(np.int64, flags="C"), i64, i64, u8p,
-        ctypes.c_int]
+    i64p = np.ctypeslib.ndpointer(np.int64, flags="C")
+    h.fedio_pad_crop_round.argtypes = [i64p, i64p, i64, i64, i64, i64, i32p,
+                                       f32p, f32p, ctypes.c_int, ctypes.c_int,
+                                       ctypes.c_float, ctypes.c_int]
+    h.fedio_pad_crop_round.restype = None
+    h.fedio_gather_rows.argtypes = [u8p, i64p, i64, i64, u8p, ctypes.c_int]
     h.fedio_gather_rows.restype = None
     h.fedio_abi_version.restype = ctypes.c_int
 
@@ -125,6 +128,40 @@ def pad_crop_batch(src: np.ndarray, params: np.ndarray, pad: int,
     h.fedio_pad_crop_batch(src, B, H, W, C, params, out, pad,
                            int(reflect), float(fill), default_threads())
     return out
+
+
+def pad_crop_round(src_addr: np.ndarray, slots: np.ndarray,
+                   params: np.ndarray, table: np.ndarray, out: np.ndarray,
+                   pad: int, reflect: bool, fill: float) -> None:
+    """normalize + pad + crop + flip of a round's images in one pass, each
+    written once into ``out``; see fedio.cpp. ``src_addr[i]`` is the address
+    of image i's uint8 (H, W, C) block -- the caller keeps the arrays they
+    point into alive and has checked the rows -- and ``slots[i]`` its place
+    among the images of ``out`` (float32, (..., H, W, C), C-contiguous)."""
+    h = lib()
+    assert h is not None
+    H, W, C = out.shape[-3:]
+    n = len(src_addr)
+    if out.dtype != np.float32 or not out.flags.c_contiguous:
+        raise ValueError("pad_crop_round writes a C-contiguous float32 array")
+    if not (len(slots) == len(params) == n and params.shape == (n, 3)
+            and table.shape == (256, C)):
+        raise ValueError("pad_crop_round: one source, slot and (y, x, flip) "
+                         "an image, and a 256 x C table")
+    if n == 0:
+        return
+    if slots.min() < 0 or slots.max() >= out.size // (H * W * C):
+        raise IndexError(f"pad_crop_round: slot out of range for "
+                         f"{out.size // (H * W * C)} images")
+    if (not 0 <= pad < min(H, W) or params[:, :2].min() < 0
+            or params[:, :2].max() > 2 * pad):
+        raise ValueError(f"pad_crop_round: offsets outside [0, {2 * pad}]")
+    h.fedio_pad_crop_round(
+        np.ascontiguousarray(src_addr, np.int64),
+        np.ascontiguousarray(slots, np.int64), n, H, W, C,
+        np.ascontiguousarray(params, np.int32),
+        np.ascontiguousarray(table, np.float32), out, pad, int(reflect),
+        float(fill), default_threads())
 
 
 def gather_rows(src: np.ndarray, idx: np.ndarray) -> np.ndarray:

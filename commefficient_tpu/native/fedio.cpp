@@ -11,14 +11,17 @@
 //
 // Every kernel is a pure function: (uint8 source batch, per-image integer
 // params sampled in Python) -> float32 model-ready batch. Randomness stays
-// in Python (numpy RandomState) so the numpy and native pipelines consume
-// identical random sequences and can be cross-checked exactly.
+// in Python (numpy RandomState, drawn batch by batch, i.e. client by client)
+// so the numpy and native pipelines consume identical random sequences and
+// can be cross-checked exactly; fedio_pad_crop_round takes a whole round's
+// draws at once and changes nothing of that stream.
 //
 // Bilinear sampling matches data/transforms.py::_bilinear_resize
 // (half-pixel centers, edge clamp) so the two paths agree to float
 // rounding.
 //
-// Build: g++ -O3 -shared -fPIC (see native/build.py). No external deps.
+// Build: g++ -O3 -shared -fPIC, on first use, by native/__init__.py::_build
+// (the .so is keyed by this file's hash). No external deps.
 
 #include <algorithm>
 #include <atomic>
@@ -236,6 +239,68 @@ void pad_crop_one(int64_t b, void* vctx) {
   }
 }
 
+struct PadCropRoundCtx {
+  const int64_t* src;   // N addresses of uint8 H x W x C images
+  const int64_t* slot;  // N image slots of `out`
+  int64_t N, H, W, C;
+  const int32_t* params;  // N x 3: y, x, flip  (offsets into padded image)
+  const float* table;     // 256 x C: normalize(v) per channel
+  float* out;
+  int pad;
+  int reflect;
+  float fill;
+};
+
+// images a work item: one image is a microsecond, too short a turn at the
+// pool's shared counter
+constexpr int64_t kRoundChunk = 16;
+
+// normalize + pad_crop_one for a run of a round's images, each read as
+// uint8 where it lies and written once at its slot. CC is the channel
+// count when known at compile time (0: read it from the context).
+template <int CC>
+void pad_crop_round_chunk(int64_t chunk, void* vctx) {
+  const PadCropRoundCtx& c = *static_cast<PadCropRoundCtx*>(vctx);
+  const int64_t H = c.H, W = c.W, C = CC ? CC : c.C;
+  const int pad = c.pad;
+  std::vector<int> sxv(W);  // source column of each output column, -1 = fill
+  const int64_t end = std::min(c.N, (chunk + 1) * kRoundChunk);
+  for (int64_t b = chunk * kRoundChunk; b < end; ++b) {
+    const uint8_t* img = reinterpret_cast<const uint8_t*>(c.src[b]);
+    const int32_t* p = c.params + b * 3;
+    const int oy = p[0], ox = p[1], flip = p[2];
+    float* out = c.out + c.slot[b] * H * W * C;
+    for (int64_t j = 0; j < W; ++j) {
+      int sx = static_cast<int>(j) + ox - pad;
+      if (sx < 0 || sx >= W)
+        sx = !c.reflect ? -1 : (sx < 0 ? -sx : static_cast<int>(2 * W - 2) - sx);
+      sxv[flip ? (W - 1 - j) : j] = sx;
+    }
+    for (int64_t i = 0; i < H; ++i) {
+      int sy = static_cast<int>(i) + oy - pad;
+      float* orow = out + i * W * C;
+      if (sy < 0 || sy >= H) {
+        if (!c.reflect) {
+          std::fill(orow, orow + W * C, c.fill);
+          continue;
+        }
+        sy = sy < 0 ? -sy : static_cast<int>(2 * H - 2) - sy;
+      }
+      const uint8_t* srow = img + static_cast<int64_t>(sy) * W * C;
+      for (int64_t j = 0; j < W; ++j) {
+        const int sx = sxv[j];
+        float* o = orow + j * C;
+        if (sx < 0) {
+          for (int64_t k = 0; k < C; ++k) o[k] = c.fill;
+        } else {
+          const uint8_t* s = srow + static_cast<int64_t>(sx) * C;
+          for (int64_t k = 0; k < C; ++k) o[k] = c.table[s[k] * C + k];
+        }
+      }
+    }
+  }
+}
+
 struct GatherCtx {
   const uint8_t* src;
   const int64_t* idx;
@@ -275,6 +340,26 @@ void fedio_pad_crop_batch(const float* src, int64_t B, int64_t H, int64_t W,
   parallel_for(B, nthreads, pad_crop_one, &ctx);
 }
 
+// normalize + pad + crop(+flip) of a whole federated round in one pass:
+// image b is the uint8 H x W x C block at address src[b] (rows of the
+// per-client arrays, wherever they lie), and lands as float32 at image
+// slot slot[b] of `out`; every output pixel is written exactly once.
+// table: float32 256 x C, the normalized value of each uint8 value per
+// channel, so the result equals fedio_pad_crop_batch on normalized floats.
+void fedio_pad_crop_round(const int64_t* src, const int64_t* slot, int64_t N,
+                          int64_t H, int64_t W, int64_t C,
+                          const int32_t* params, const float* table,
+                          float* out, int pad, int reflect, float fill,
+                          int nthreads) {
+  PadCropRoundCtx ctx{src, slot, N, H, W, C, params, table, out,
+                      pad, reflect, fill};
+  parallel_for((N + kRoundChunk - 1) / kRoundChunk, nthreads,
+               C == 3 ? pad_crop_round_chunk<3>
+                      : C == 1 ? pad_crop_round_chunk<1>
+                               : pad_crop_round_chunk<0>,
+               &ctx);
+}
+
 // Threaded row gather: out[i] = src[idx[i]] for fixed-size rows. Used to
 // assemble padded round batches from per-client mmap'd arrays without
 // holding the GIL.
@@ -284,6 +369,6 @@ void fedio_gather_rows(const uint8_t* src, const int64_t* idx, int64_t n,
   parallel_for(n, nthreads, gather_one, &ctx);
 }
 
-int fedio_abi_version() { return 1; }
+int fedio_abi_version() { return 2; }
 
 }  // extern "C"

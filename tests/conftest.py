@@ -73,3 +73,42 @@ def pytest_configure(config):
 def pytest_sessionstart(session):
     assert jax.devices()[0].platform == "cpu", (
         "tests must run on CPU; got " + str(jax.devices()))
+
+
+@pytest.fixture
+def uint8_pool(tmp_path):
+    """Factory of train sets of random uint8 images in the prepared-array
+    layout (CIFAR's: one array a natural client, target = client):
+    ``make(sizes, image, transform, **FedDataset keywords)``. Same sizes
+    and image shape, same pixels."""
+    import numpy as np
+
+    from commefficient_tpu.data.fed_dataset import PreparedArrayDataset
+
+    def make(sizes, image=(32, 32, 3), transform=None, **kw):
+        class Pool(PreparedArrayDataset):
+            name = "pool"
+
+            def _make_xy(self):
+                rng = np.random.RandomState(len(sizes))
+                y = np.repeat(np.arange(len(sizes)), sizes)
+                x = rng.randint(0, 256, (len(y),) + image).astype(np.uint8)
+                return x, y, x[:2], y[:2], len(sizes)
+
+        name = "-".join(map(str, (*sizes, *image)))
+        return Pool(dataset_dir=str(tmp_path / name), train=True,
+                    transform=transform, **kw)
+    return make
+
+
+@pytest.fixture
+def same_rng_state():
+    """``same(rng_a, rng_b)``: two ``RandomState``s at the same point of
+    the same stream."""
+    import numpy as np
+
+    def same(rng_a, rng_b):
+        a, b = rng_a.get_state(), rng_b.get_state()
+        return (np.array_equal(a[1], b[1])
+                and (a[0], *a[2:]) == (b[0], *b[2:]))
+    return same

@@ -387,3 +387,237 @@ def test_synthetic_persona_cache_keyed_by_generation_settings(tmp_path):
     n_small = len(small)
     big = SyntheticPersona(num_clients_gen=8, **kw)
     assert len(big) > n_small
+
+
+# --- the round as the unit of work: one native pass, arrays written again --
+
+from commefficient_tpu import native  # noqa: E402
+from commefficient_tpu.data import batching  # noqa: E402
+from commefficient_tpu.data import transforms as T  # noqa: E402
+from commefficient_tpu.utils import tracing  # noqa: E402
+
+needs_native = pytest.mark.skipif(native.lib() is None,
+                                  reason="native fedio library unavailable")
+
+POOLS = {
+    # CIFAR-10's transform and shape; EMNIST's (constant fill 1.0, no flip)
+    "cifar": dict(image=(32, 32, 3), transform=T.cifar10_train_transforms),
+    "emnist": dict(image=(28, 28, 1), transform=T.femnist_train_transforms),
+}
+#: three natural clients split into six: 11, 12, 8, 9, 15 and 16 images, so
+#: under W = 4, B = 10 clients fetch fewer than B rows (8, 9, and the
+#: remainders 1, 2, 5, 6) and the last rounds have fewer than W clients
+SIZES = (23, 17, 31)
+
+
+def _copies(batcher, epochs=1, skip=0):
+    """Every round of ``epochs`` epochs, copied as it is yielded (so the
+    batcher is free to write its arrays again)."""
+    return [(ids.copy(), tuple(c.copy() for c in cols), mask.copy())
+            for _ in range(epochs) for ids, cols, mask in
+            batcher.epoch(skip=skip)]
+
+
+def _assert_same_rounds(got, want):
+    assert len(got) == len(want)
+    for (ia, ca, ma), (ib, cb, mb) in zip(got, want):
+        np.testing.assert_array_equal(ia, ib)
+        np.testing.assert_array_equal(ma, mb)
+        assert len(ca) == len(cb)
+        for x, y in zip(ca, cb):
+            assert x.dtype == y.dtype
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+@pytest.fixture
+def reference_rounds(monkeypatch):
+    """``rounds(dataset, W, B, epochs)``: the rounds as the numpy stages
+    build them client by client into new arrays (the library patched away,
+    nothing re-used), and the dataset left as it was after them."""
+    def rounds(dataset, W, B, epochs=1, skip=0, **kw):
+        with monkeypatch.context() as m:
+            m.setattr(native, "_handle", None)
+            m.setattr(native, "_cached", True)
+            m.setattr(batching, "_OWN_REFS", 0)
+            assert dataset.round_builder() is None
+            return _copies(FedBatcher(dataset, W, B, seed=1, **kw), epochs,
+                           skip)
+    return rounds
+
+
+@needs_native
+@pytest.mark.parametrize("local_batch_size", [10, -1])
+@pytest.mark.parametrize("do_iid", [False, True])
+@pytest.mark.parametrize("pool", sorted(POOLS))
+def test_round_in_one_pass_equals_the_numpy_stages_per_client(
+        uint8_pool, reference_rounds, same_rng_state, pool, do_iid,
+        local_batch_size):
+    """Two epochs three ways — one native pass a round; client by client
+    through ``get_flat_batch`` with the library; client by client through
+    the numpy stages — give the same images bit for bit, labels, ids and
+    mask, and leave ``dataset.rng`` in the same state. Clients with fewer
+    than B rows, short last rounds, whole-client mode (-1) and the iid
+    overlay are all in; the first two paths write their arrays again."""
+    make = lambda: uint8_pool(SIZES, num_clients=6, do_iid=do_iid, seed=3,
+                              **POOLS[pool])
+    ds_ref, ds_one, ds_per = make(), make(), make()
+    want = reference_rounds(ds_ref, 4, local_batch_size, epochs=2)
+    assert any(0 < m.sum(1).min() < m.shape[1] for _, _, m in want)
+    assert any(m.sum(1).min() == 0 for _, _, m in want)
+
+    tracing.reset()
+    assert ds_one.round_builder() is not None
+    got = _copies(FedBatcher(ds_one, 4, local_batch_size, seed=1), 2)
+    _assert_same_rounds(got, want)
+    assert same_rng_state(ds_one.rng, ds_ref.rng)
+    counters = tracing.snapshot()["counters"]
+    assert counters["data.rounds_one_pass"][0] == len(want)
+    assert "data.rounds_per_client" not in counters
+    assert counters["data.arrays_reused"][0] >= len(want) - 4
+
+    ds_per.round_builder = lambda: None
+    got = _copies(FedBatcher(ds_per, 4, local_batch_size, seed=1), 2)
+    _assert_same_rounds(got, want)
+    assert same_rng_state(ds_per.rng, ds_ref.rng)
+    assert tracing.snapshot()["counters"]["data.rounds_per_client"][
+        0] == len(want)
+
+
+@needs_native
+def test_no_native_env_takes_the_per_client_path(uint8_pool, monkeypatch):
+    """COMMEFFICIENT_NO_NATIVE=1 leaves no library, hence no round
+    builder."""
+    # both through monkeypatch: the loaded library is back afterwards
+    monkeypatch.setattr(native, "_handle", native._handle)
+    monkeypatch.setattr(native, "_cached", False)
+    monkeypatch.setenv("COMMEFFICIENT_NO_NATIVE", "1")
+    ds = uint8_pool(SIZES, num_clients=6, seed=3, **POOLS["cifar"])
+    assert native.lib() is None and ds.round_builder() is None
+    batcher = FedBatcher(ds, 4, 10, seed=1)
+    tracing.reset()
+    assert len(list(batcher.epoch())) > 0
+    counters = tracing.snapshot()["counters"]
+    assert "data.rounds_one_pass" not in counters
+    assert counters["data.rounds_per_client"][0] > 0
+
+
+@needs_native
+def test_rows_past_the_pad_size_are_drawn_and_dropped(
+        uint8_pool, reference_rounds, same_rng_state):
+    """A pad size under the local batch: both paths draw for every row a
+    client fetches and keep the first B."""
+    make = lambda: uint8_pool(SIZES, num_clients=6, seed=3, **POOLS["cifar"])
+    ds_ref, ds_one = make(), make()
+    want = reference_rounds(ds_ref, 4, 10, pad_size=6)
+    got = _copies(FedBatcher(ds_one, 4, 10, seed=1, pad_size=6))
+    assert got[0][1][0].shape[:2] == (4, 6)
+    _assert_same_rounds(got, want)
+    assert same_rng_state(ds_one.rng, ds_ref.rng)
+
+
+@needs_native
+@pytest.mark.parametrize("why", ["float rows", "another transform",
+                                 "another image size", "validation set"])
+def test_round_builder_is_offered_only_where_it_applies(uint8_pool, why):
+    kw = dict(POOLS["cifar"], num_clients=6, seed=3)
+    if why == "another transform":
+        kw["transform"] = T.normalize(T.CIFAR10_MEAN, T.CIFAR10_STD)
+    elif why == "another image size":
+        kw["image"] = (16, 16, 3)
+    ds = uint8_pool(SIZES, **kw)
+    if why == "float rows":
+        ds.client_datasets = [a.astype(np.float32)
+                              for a in ds.client_datasets]
+    elif why == "validation set":
+        ds.train = False
+    assert ds.round_builder() is None
+
+
+@needs_native
+def test_one_pass_skip_replays_identical_rounds(uint8_pool):
+    """``epoch(skip=k)`` on the one-pass path: rounds k.. of the whole
+    epoch, and the next epoch bit for bit (the draws were all made)."""
+    make = lambda: uint8_pool(SIZES, num_clients=6, seed=3, **POOLS["cifar"])
+    a, b = FedBatcher(make(), 4, 10, seed=1), FedBatcher(make(), 4, 10, seed=1)
+    whole, after = _copies(a), _copies(a)
+    _assert_same_rounds(_copies(b, skip=2), whole[2:])
+    _assert_same_rounds(_copies(b), after)
+
+
+def _twelve_rounds(uint8_pool):
+    """W = 2, B = 5 over 12 clients of 10 images: 12 rounds an epoch."""
+    return uint8_pool((40, 40, 40), num_clients=12, seed=3, **POOLS["cifar"])
+
+
+@needs_native
+def test_rounds_held_in_a_list_never_change(uint8_pool, reference_rounds):
+    want = reference_rounds(_twelve_rounds(uint8_pool), 2, 5)
+    held = list(FedBatcher(_twelve_rounds(uint8_pool), 2, 5, seed=1).epoch())
+    assert len(held) == 12
+    assert len({id(cols[0]) for _, cols, _ in held}) == 12
+    _assert_same_rounds(held, want)
+
+
+@needs_native
+def test_rounds_alive_on_the_device_never_change(uint8_pool,
+                                                 reference_rounds):
+    """Through ``device_prefetch(size=2)`` on the CPU backend, where a
+    device array may alias the numpy memory: the consumer keeps the last
+    four device batches and reads each when it lets it go."""
+    from collections import deque
+
+    from commefficient_tpu.data.prefetch import device_prefetch
+    want = reference_rounds(_twelve_rounds(uint8_pool), 2, 5, epochs=2)
+    batcher = FedBatcher(_twelve_rounds(uint8_pool), 2, 5, seed=1)
+    alive, seen = deque(), []
+    for _ in range(2):
+        for item in device_prefetch(batcher.epoch(), size=2):
+            alive.append(item)
+            if len(alive) > 4:
+                seen.append(alive.popleft())
+    seen.extend(alive)
+    _assert_same_rounds(seen, want)
+
+
+@needs_native
+@pytest.mark.parametrize("local_batch_size", [10, -1])
+@pytest.mark.parametrize("one_pass", [True, False])
+def test_rows_not_written_read_zero_after_reuse(uint8_pool, one_pass,
+                                                local_batch_size):
+    """An array that held a full round, written again by a short one:
+    rows past a client's n, absent workers and the mask are zero, as in a
+    new array."""
+    ds = uint8_pool(SIZES, num_clients=6, seed=3, **POOLS["cifar"])
+    if not one_pass:
+        ds.round_builder = lambda: None
+    batcher = FedBatcher(ds, 4, local_batch_size, seed=1)
+    first = last = None
+    for ids, cols, mask in batcher.epoch():
+        if first is None:
+            first = [id(c) for c in cols]
+            assert mask.sum(1).min() > 0            # every worker present
+        last = ids.copy(), [c.copy() for c in cols], mask.copy()
+    ids, cols, mask = last
+    assert len(batcher._kept) == 2                   # two in turn, written
+    assert first in [[id(c) for c in kept] for kept, _ in batcher._kept]
+    assert mask[-1].sum() == 0 and ids[-1] == 0      # a short last round
+    for c in cols:
+        assert not c[mask == 0].any()
+    assert cols[0][mask > 0].any()
+
+
+def test_token_columns_are_written_again(tmp_path):
+    """The re-use serves every dataset: int token columns of the persona
+    set, client by client."""
+    from commefficient_tpu.data.persona import SyntheticPersona
+    from commefficient_tpu.data.tokenizer import ByteTokenizer
+    make = lambda: SyntheticPersona(
+        tokenizer=ByteTokenizer(), num_candidates=2, max_history=2,
+        max_seq_len=32, personality_permutations=1, train=True,
+        dataset_dir=str(tmp_path / "sp"), seed=0, num_clients_gen=6)
+    batcher = FedBatcher(make(), 2, 2, seed=1)
+    got = _copies(batcher, epochs=2)
+    holder = FedBatcher(make(), 2, 2, seed=1)
+    held = list(holder.epoch()) + list(holder.epoch())
+    _assert_same_rounds(got, held)
+    assert len(batcher._kept) == 2 < len(got)

@@ -82,7 +82,7 @@ def test_pad_crop_bit_identical_to_numpy(mode, fill, hflip_p):
     if hflip_p > 0:
         aug.append(T.random_hflip(hflip_p))
     numpy_fn = T.compose(T.normalize(mean, std), *aug)
-    fused = T.fused_pad_crop_train(mean, std, 28, 2, mode, fill, hflip_p)
+    fused = T.PadCropTrain(mean, std, 28, 2, mode, fill, hflip_p)
     rng_a, rng_b = np.random.RandomState(9), np.random.RandomState(9)
     out_np = numpy_fn([imgs], rng_a)[0]
     out_nat = fused([imgs], rng_b)[0]
@@ -142,3 +142,107 @@ def test_cifar_train_pipeline_is_fused_and_matches():
     out_nat = T.cifar10_train_transforms([imgs, labels], rng_b)
     np.testing.assert_array_equal(out_nat[0], out_np[0])
     np.testing.assert_array_equal(out_nat[1], labels)
+
+
+ROUND_CASES = {
+    # CIFAR: reflect padding and flips; EMNIST: constant fill of 1.0 in
+    # normalized space, no flip (so no rand is drawn)
+    "cifar": dict(image=(32, 32, 3), mean=T.CIFAR10_MEAN, std=T.CIFAR10_STD,
+                  padding=4, mode="reflect", fill=0.0, hflip_p=0.5),
+    "emnist": dict(image=(28, 28, 1), mean=T.FEMNIST_MEAN,
+                   std=T.FEMNIST_STD, padding=2, mode="constant", fill=1.0,
+                   hflip_p=0.0),
+}
+
+
+def _numpy_stages(case):
+    aug = [T.random_crop(case["image"][0], case["padding"], case["mode"],
+                         case["fill"])]
+    if case["hflip_p"] > 0:
+        aug.append(T.random_hflip(case["hflip_p"]))
+    return T.compose(T.normalize(case["mean"], case["std"]), *aug)
+
+
+def _pad_crop_train(case):
+    return T.PadCropTrain(case["mean"], case["std"], case["image"][0],
+                          case["padding"], case["mode"], case["fill"],
+                          case["hflip_p"])
+
+
+@pytest.mark.parametrize("hflip_p", [0.5, 0.0])
+@pytest.mark.parametrize("n", [1, 7, 50])
+def test_array_draw_is_the_scalar_stream(n, hflip_p, same_rng_state):
+    """``PadCropTrain.draw`` against the numpy stages' own draws: two
+    scalar randints an image, then one rand(n) unless nothing flips."""
+    t = T.PadCropTrain(T.CIFAR10_MEAN, T.CIFAR10_STD, 32, 4,
+                       hflip_p=hflip_p)
+    rng_a, rng_b = np.random.RandomState(n), np.random.RandomState(n)
+    want = np.zeros((n, 3), np.int32)
+    for i in range(n):
+        want[i, 0] = rng_a.randint(0, 9)
+        want[i, 1] = rng_a.randint(0, 9)
+    if hflip_p > 0:
+        want[:, 2] = rng_a.rand(n) < hflip_p
+    got = t.draw(rng_b, n)
+    assert got.dtype == np.int32
+    np.testing.assert_array_equal(got, want)
+    assert same_rng_state(rng_a, rng_b)
+
+
+@pytest.mark.parametrize("case", sorted(ROUND_CASES))
+def test_pad_crop_round_is_the_numpy_stages_client_by_client(
+        case, same_rng_state):
+    """One pass over a round (uint8 rows where they lie, a 256 x C table,
+    scattered slots) against normalize + random_crop + random_hflip run on
+    each client's rows in turn: bit-equal, and the same generator state."""
+    case = ROUND_CASES[case]
+    image = case["image"]
+    pools = [np.random.RandomState(c).randint(0, 256, (n,) + image)
+             .astype(np.uint8) for c, n in enumerate((9, 4, 17))]
+    # (client, rows) of a round's three workers, and their (W=4, B=6) slots
+    picks = [(2, [16, 0, 3, 3, 8]), (0, [8, 1]), (1, [3, 2, 1, 0, 0, 2])]
+    W, B = 4, 6
+    slots = np.concatenate([w * B + np.arange(len(rows))
+                            for w, (_, rows) in enumerate(picks)])
+
+    rng_a, rng_b = np.random.RandomState(5), np.random.RandomState(5)
+    stages = _numpy_stages(case)
+    want = np.zeros((W, B) + image, np.float32)
+    for w, (c, rows) in enumerate(picks):
+        want[w, :len(rows)] = stages([pools[c][rows]], rng_a)[0]
+
+    t = _pad_crop_train(case)
+    params = np.concatenate([t.draw(rng_b, len(rows)) for _, rows in picks])
+    src = np.concatenate([pools[c].ctypes.data
+                          + np.asarray(rows) * int(np.prod(image))
+                          for c, rows in picks])
+    got = np.zeros((W, B) + image, np.float32)
+    native.pad_crop_round(src, slots, params, t.table(), got, t.padding,
+                          t.mode == "reflect", t.fill)
+    np.testing.assert_array_equal(got, want)
+    assert same_rng_state(rng_a, rng_b)
+    if case["hflip_p"] == 0:
+        assert not params[:, 2].any()
+
+
+def test_pad_crop_round_guards():
+    """The C side writes and reads raw addresses: slots and offsets are
+    checked before the call."""
+    t = _pad_crop_train(ROUND_CASES["emnist"])
+    img = np.zeros((1, 28, 28, 1), np.uint8)
+    src = np.array([img.ctypes.data], np.int64)
+    out = np.zeros((2, 28, 28, 1), np.float32)
+    ok = np.array([[0, 4, 0]], np.int32)
+    native.pad_crop_round(src, np.array([1]), ok, t.table(), out, 2, False,
+                          1.0)
+    for slot in (2, -1):
+        with pytest.raises(IndexError):
+            native.pad_crop_round(src, np.array([slot]), ok, t.table(), out,
+                                  2, False, 1.0)
+    with pytest.raises(ValueError):
+        native.pad_crop_round(src, np.array([0]),
+                              np.array([[5, 0, 0]], np.int32), t.table(),
+                              out, 2, False, 1.0)
+    with pytest.raises(ValueError):
+        native.pad_crop_round(src, np.array([0]), ok, t.table(),
+                              out.astype(np.float64), 2, False, 1.0)

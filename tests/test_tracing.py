@@ -357,3 +357,43 @@ def test_two_round_train_leaves_the_data_and_round_totals(monkeypatch):
     last = snap["open"]["spans"]
     assert last["round.dispatch"][1] == 1 and last["round.sync"][1] == 2
     assert snap["counters"]["compile.programs"][0] >= 1
+
+
+@pytest.mark.parametrize("one_pass", [True, False])
+def test_batcher_counts_its_paths_and_its_arrays(uint8_pool, one_pass):
+    """12 rounds of W = 2 clients x B = 5 images, the consumer holding one
+    round at a time: which path built each round, how often an array was
+    written again, and the spans of a round built in one pass."""
+    from commefficient_tpu import native
+    from commefficient_tpu.data import FedBatcher
+    from commefficient_tpu.data.transforms import cifar10_train_transforms
+    if one_pass and native.lib() is None:
+        pytest.skip("native fedio library unavailable")
+    ds = uint8_pool((40, 40, 40), num_clients=12, seed=3,
+                    transform=cifar10_train_transforms)
+    if not one_pass:
+        ds.round_builder = lambda: None
+    batcher = FedBatcher(ds, 2, 5, seed=1)
+    for _ in batcher.epoch():
+        pass
+    snap = tracing.snapshot()
+    counters = {k: v[0] for k, v in snap["counters"].items()}
+    ran, other = (("data.rounds_one_pass", "data.rounds_per_client")
+                  if one_pass else
+                  ("data.rounds_per_client", "data.rounds_one_pass"))
+    assert counters[ran] == 12 and other not in counters
+    # the round being built and the one the consumer holds: two arrays
+    assert counters["data.arrays_new"] == 2
+    assert counters["data.arrays_reused"] == 10
+    assert counters["data.rows"] == 120
+    spans = snap["open"]["spans"]
+    assert spans["data.sample"][1] == 13             # the step that ends it
+    per_round = 1 if one_pass else 2                 # once a round / client
+    assert spans["data.fetch"][1] == 12 * per_round
+    assert spans["data.augment"][1] == 24            # draws + pass / clients
+    # skipped rounds draw and build nothing
+    for _ in batcher.epoch(skip=3):
+        pass
+    counters = {k: v[0] for k, v in tracing.snapshot()["counters"].items()}
+    assert counters[ran] == 12 + 9
+    assert counters["data.arrays_new"] == 2
