@@ -185,7 +185,7 @@ class FedLearner:
         self._eval = build_eval_step(loss_val or loss_train, unflatten)
         # stashed (post-padding) for subclasses that build additional
         # jitted programs over the same loss/parameterization
-        # (federated/buffer.BufferedFedLearner, bench.py A/B rebuilds)
+        # (federated/buffer.BufferedFedLearner)
         self._loss_train = loss_train
         self._round_unflatten = round_unflatten
         self._trainable_mask = trainable_mask

@@ -227,29 +227,24 @@ class SketchedCodec:
     # device (the contract is bounded divergence, not bitwise identity)
     wire_lossless = True  # the wire format IS the arena format (tables)
 
-    def __init__(self, d: int, r: int, c: int, k: int, seed: int,
-                 scheme: str = "global"):
+    def __init__(self, d: int, r: int, c: int, k: int, seed: int):
         from commefficient_tpu.ops.countsketch import CountSketch
-        # scheme is now a MEASURED choice, not an asserted one. 'global'
-        # (default, trajectory-preserving): classic per-coordinate
-        # hashing, table exactly (r, c) with no lane-tile padding.
-        # 'tiled': lane-tiled layout (c padded to a 128 multiple) whose
-        # encode/decode can dispatch the batched Pallas kernels — the
-        # encode here is W vmapped sketches, exactly the shape round 8
-        # put on the 2-D grid kernel. Whether the tiled layout pays at
-        # the codec's small-c operating point is the
-        # `client_store_sketched_codec` BENCH_r08 A/B row's question
-        # (refutation budgeted: per-client tables are small and gathered
-        # W at a time, so the answer may well be 'no' — then it lands in
-        # ROOFLINE.md as the measured answer and 'global' stays).
+        # 'global' scheme (trajectory-preserving): classic per-coordinate
+        # hashing, table exactly (r, c) with no lane-tile padding. The
+        # lane-tiled layout, whose W vmapped sketches could dispatch the
+        # batched Pallas kernels, was never measured at the codec's
+        # small-c operating point (per-client tables are small and
+        # gathered W at a time); it comes back with the cell that
+        # measures it.
         self.cs = CountSketch(d=int(d), c=int(c), r=int(r),
-                              seed=int(seed) ^ 0xC11E57, scheme=scheme)
+                              seed=int(seed) ^ 0xC11E57, scheme="global")
         self.d = int(d)
         self.k = int(min(k, d))
 
     def encode_rows(self, rows: jax.Array) -> dict:
         # (W, r, c_eff); use_kernel opts into the batched Pallas sketch
-        # kernel where eligible (tiled scheme on TPU) — no-op for global
+        # kernel where eligible (tiled scheme on TPU) — a no-op for the
+        # codec's 'global' scheme
         return {"table": jax.vmap(
             lambda v: self.cs.sketch_vec(v, use_kernel=True))(rows)}
 

@@ -76,7 +76,7 @@ class GPT2Config:
         #   'output' — always output dropout (the old blockwise behavior);
         #   'kernel' — require the in-kernel path; raises when training
         #              with dropout>0 on an ineligible backend/shape
-        #              (bench uses this so an A/B can't silently mislabel).
+        #              (so that an A/B can't silently mislabel an arm).
         # Irrelevant for attn_impl='full' (XLA prob dropout) and 'ring'
         # (output dropout, documented divergence).
         self.attn_dropout = "auto"

@@ -93,7 +93,7 @@ def full_attention(q, k, v, *, causal: bool = True,
         # correction below is an identity — skipping it drops a
         # (B,H,T,T) compare-reduce and a (B,T,H,D) select from the
         # trace. (This function is the ops-level correctness reference
-        # used by the tests/seq paths; the GPT2 'full' bench path is the
+        # used by the tests/seq paths; the GPT2 'full' path is the
         # inline attention in models/gpt2.py.)
         return out
     # fully-masked queries emit 0 (softmax of an all-masked row would
@@ -292,7 +292,7 @@ def blockwise_attention(q, k, v, *, causal: bool = True,
     over key/value blocks with the same online softmax. ``use_kernel``
     forces the choice (None = auto); ``block_size`` applies to the scan
     path only — the kernel uses its swept defaults unless
-    ``block_q``/``block_k`` override them (the bench's T=256 sweep).
+    ``block_q``/``block_k`` override them (tests/test_flash_attention.py).
 
     ``dropout_rate > 0`` applies reference-parity Bernoulli dropout to
     the attention probabilities INSIDE the kernel, seeded from

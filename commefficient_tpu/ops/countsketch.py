@@ -301,12 +301,12 @@ class CountSketch:
         turn ``pl.program_id(0)`` into the batch index (review r4: that
         silently corrupts the tiling and the sketch accumulator's step-0
         init, and is the hazard that kept the per-worker vmap paths off
-        the kernel until round 8). So the vmapped call sites — the
-        per-worker transmit (federated/client.py) and the sketched client
-        codec (federated/client_store.py) — now get the kernel too; the
+        the kernel until round 8). So the vmapped call site — the
+        per-worker transmit (federated/client.py) — gets the kernel too
+        (the sketched client codec keeps the 'global' scheme); the
         XLA fallback remains for NESTED vmap, over-budget shapes, and
         non-TPU backends. ``sketch_kernels.force_dispatch`` overrides the
-        backend gate for audits/benches (kernel mode runs the Pallas
+        backend gate for audits and tests (kernel mode runs the Pallas
         interpreter off-TPU)."""
         if not use_kernel:
             return False

@@ -15,7 +15,7 @@ so forward and backward masks agree exactly. The forward becomes a pure
 elementwise op XLA can fuse into the surrounding matmul epilogues.
 
 What the round-4 on-chip probes established about the BIT-GENERATION cost
-(the dominant term at the federated GPT2 bench shape, where the attention
+(the dominant term at the federated GPT2 round's shape, where the attention
 probability masks alone are 604M draws per forward pass):
 
 * threefry bernoulli ~16 ms/pass on-chip; rbg (hardware RngBitGenerator)
@@ -91,7 +91,7 @@ masked_dropout.defvjp(_fwd, _bwd)
 # Hardware-RNG Pallas path
 #
 # Even with the recompute formulation the XLA cost of dropout is dominated
-# by BIT GENERATION, not HBM traffic: at the federated GPT2 bench shape the
+# by BIT GENERATION, not HBM traffic: at the federated GPT2 round's shape the
 # attention-probability masks alone are 604M draws per forward pass, and
 # jax.random generation measures 22-31 ms per pass on-chip for every
 # generator/width combination (threefry/rbg x f32/u8/u16 — round-4 probe;
@@ -203,7 +203,7 @@ class FusedDropout(nn.Module):
 
     ``impl='tpu_bits'`` swaps in the hardware-RNG Pallas kernel (same
     distribution, different realized bits; not vmap-safe — the GPT2 config
-    plumbs this only into fused-round/bench paths)."""
+    plumbs this only into fused-round paths)."""
 
     rate: float
     impl: str = "xla"

@@ -31,7 +31,7 @@ any backend; this module is the TPU-native kernel for the same math:
   rowsum(dO*O) = rowsum((P*M) * (dO V^T)) algebraically.
 
 Numerics: scores, running max and denominator are f32 regardless of the
-input dtype (bf16 in the GPT2 bench); p and the p@v / ds@k matmuls run in
+input dtype (bf16 in the GPT2 round); p and the p@v / ds@k matmuls run in
 the input dtype on the MXU with f32 accumulation
 (``preferred_element_type``), matching ``ops.attention``'s convention.
 The dropout mask/scale is applied to p in f32 before the cast.
@@ -70,9 +70,9 @@ _NEG = -1e30          # matches ops.attention: exp(_NEG - m) == 0, no NaNs
 # shapes): large q blocks amortize per-grid-step overhead and k/v
 # refetch; fwd+bwd 8.3ms vs 25.9ms for the lax.scan formulation (3.1x).
 # At short T both clamp to a single (T, T) tile (see tile() below), so
-# the federated bench shape T=256 runs one 256x256 score block per
-# (b*h) — the T=256 block-size sweep lives in bench.py
-# (flash_attn_t256_parity_dropout_kernel_ab) and adjudicates on-chip.
+# the federated GPT2 round's T=256 runs one 256x256 score block per
+# (b*h); the smaller block sizes of that shape are traced, not timed, by
+# tests/test_flash_attention.py::test_fwd_bwd_traces_at_the_round_shape.
 DEFAULT_BLOCK_Q = 2048
 DEFAULT_BLOCK_K = 512
 

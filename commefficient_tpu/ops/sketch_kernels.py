@@ -82,7 +82,7 @@ def force_dispatch(mode):
     this is how the ``sketch_batched`` graft-audit target traces the
     production kernel dispatch without a chip. ``mode="fallback"`` forces
     the XLA formulation everywhere — the audit's mutation, and the B side
-    of the per-worker bench A/B. ``mode=None`` restores backend-based
+    of the forced-dispatch parity tests. ``mode=None`` restores backend-based
     dispatch.
 
     Clears the jit caches on entry AND exit: the override changes what a

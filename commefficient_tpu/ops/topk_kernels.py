@@ -63,7 +63,7 @@ own k on-kernel in one pass, with static-max-k fallbacks reproducing
 the incumbent two-stage masking bitwise.
 
 Dispatch mirrors ops/sketch_kernels: ``force_dispatch`` ("kernel" /
-"fallback") overrides the backend gate for audits and A/B benches, the
+"fallback") overrides the backend gate for audits and parity tests, the
 ``custom_vmap`` guards dispatch the purpose-built batched kernels under
 vmap (never JAX's default grid-prepending rule), and every entry has a
 bitwise XLA fallback. ``approx_recall`` refuses the kernel by contract:
@@ -117,7 +117,7 @@ def topk_kernel_ok(approx_recall=None) -> bool:
     exact selection to bit-agree with. Otherwise
     ``force_dispatch("kernel"/"fallback")`` overrides the backend gate
     (audits trace the kernel program on CPU via the interpreter; the
-    bench A/B and the audit mutation arm force the incumbent chain)."""
+    parity tests and the audit mutation arm force the incumbent chain)."""
     if approx_recall:
         return False
     forced = forced_dispatch()

@@ -95,7 +95,7 @@ class DecodeEngine:
             if leaves and isinstance(leaves[0], jax.Array):
                 from commefficient_tpu.parallel.tp import shard_params_tp
                 params = shard_params_tp(params, mesh, tp_axis)
-            # else: abstract params (bench --dry-run eval_shape path) —
+            # else: abstract params (an eval_shape trace of the engine) —
             # placement is moot, the _constrain annotations still trace
         self.params = params
         self.max_len = int(max_len) if max_len else int(cfg.n_positions)

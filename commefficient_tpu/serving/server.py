@@ -673,7 +673,7 @@ class ContinuousBatchingServer:
         # multi-host axes: TP degree, prefill/decode split, and per-shard
         # routing — admitted/spilled per slot pool, plus the store's own
         # shard read/write counters when a personalization index is
-        # attached, so bench rows can report routing skew directly
+        # attached, so a caller can read routing skew directly
         s["swaps_done"] = self.swaps_done
         s["dirty_swaps"] = self.dirty_swaps
         s["tp"] = self.engine.tp

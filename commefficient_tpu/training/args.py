@@ -272,7 +272,7 @@ def build_parser(default_lr: float = 0.4) -> argparse.ArgumentParser:
                         "to output dropout otherwise; 'output' forces the "
                         "pre-kernel output-dropout behavior; 'kernel' "
                         "requires the in-kernel path and errors when "
-                        "ineligible (bench/A-B use)")
+                        "ineligible (A/B use)")
     p.add_argument("--fused_ce", choices=("auto", "on", "off"),
                    default="auto",
                    help="vocab-chunked fused LM-head CE (ops/fused_ce.py): "

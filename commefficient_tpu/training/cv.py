@@ -198,7 +198,7 @@ def train(args, mesh=None, max_rounds=None, log=True):
             epoch_metrics = []
             # one-round software pipeline (RoundPipeline): metric sync
             # overlaps the next round's device compute, so the loop runs
-            # at device throughput (bench.py's round_throughput_ms). The
+            # at device throughput (PERF.md section 5: samples_per_s). The
             # host notices a NaN (ref cv_train.py:110-112) one round late,
             # but the in-round device guard (round.py) makes the breaching
             # round and everything after it a state no-op, so the lag

@@ -214,14 +214,6 @@ def snapshot() -> dict:
                 "counters": {k: list(v) for k, v in _REC.counters.items()}}
 
 
-def span_seconds(name: str) -> float:
-    """Seconds spent in ``name`` over every round still in the ring and the
-    open one (take it before and after a stretch of work)."""
-    with _REC.lock:
-        return sum(r["spans"][name][0] for r in (*_REC.ring, _REC.round)
-                   if name in r["spans"]) / 1e9
-
-
 def write(directory: str) -> str:
     os.makedirs(directory, exist_ok=True)
     path = os.path.join(directory, "spans.json")

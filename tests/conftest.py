@@ -63,6 +63,63 @@ def serving_tiny_engine():
     return tok, model, params, engine
 
 
+@pytest.fixture(scope="session")
+def gpt2_small_shapes():
+    """GPT2-small as served (124 M parameters, vocabulary 50 262, bf16
+    compute, a 128-token prompt + 64 new tokens) as SHAPES, for the
+    serving trace gates: ``.engine(method)`` is a ``DecodeEngine`` over
+    the ``jax.eval_shape`` of the model's init (no weight is ever made),
+    ``.abstract_params(model)`` that init for another model (a drafter),
+    ``.P`` / ``.N`` the prompt length and the new tokens."""
+    import types
+
+    import jax.numpy as jnp
+
+    from commefficient_tpu.models.gpt2 import GPT2Config, GPT2DoubleHeads
+    from commefficient_tpu.serving import DecodeEngine
+    P, N = 128, 64
+    cfg = GPT2Config.small(vocab_size=50262)
+    cfg.n_positions = max(cfg.n_positions, P + N)
+    cfg.dropout = 0.0
+    cfg.dtype = "bfloat16"
+    model = GPT2DoubleHeads(cfg)
+
+    def abstract_params(m):
+        z = jnp.zeros((1, 1, 8), jnp.int32)
+        return jax.eval_shape(
+            lambda r: m.init(r, z, z, jnp.zeros((1, 1), jnp.int32),
+                             train=False), jax.random.PRNGKey(0))["params"]
+
+    params = abstract_params(model)
+    return types.SimpleNamespace(
+        P=P, N=N, abstract_params=abstract_params,
+        engine=lambda method="greedy": DecodeEngine(
+            model, params, eos_id=cfg.vocab_size - 1, max_len=P + N,
+            method=method))
+
+
+@pytest.fixture(scope="session")
+def paged_shapes():
+    """``make(engine, slots, prefill_len, page_size=16, kv_quant="none")``
+    -> (pager, pools, page table, (slots,) int32, (slots,) bool): the
+    host pager of a paged server at that size and the shapes of what its
+    paged step takes, the pools through ``jax.eval_shape``."""
+    import jax.numpy as jnp
+
+    from commefficient_tpu.serving import PagedKVCache
+
+    def make(engine, slots, prefill_len, page_size=16, kv_quant="none"):
+        pager = PagedKVCache(slots=slots, max_len=engine.max_len,
+                             prefill_len=prefill_len, page_size=page_size)
+        pools = jax.eval_shape(lambda: engine.init_paged_pools(
+            pager.num_pages, page_size, kv_quant=kv_quant))
+        return (pager, pools,
+                jax.ShapeDtypeStruct((slots, pager.max_pages), jnp.int32),
+                jax.ShapeDtypeStruct((slots,), jnp.int32),
+                jax.ShapeDtypeStruct((slots,), jnp.bool_))
+    return make
+
+
 def pytest_configure(config):
     config.addinivalue_line(
         "markers",
@@ -112,3 +169,37 @@ def same_rng_state():
         return (np.array_equal(a[1], b[1])
                 and (a[0], *a[2:]) == (b[0], *b[2:]))
     return same
+
+
+@pytest.fixture
+def trace_round():
+    """``trace(learner, ids, batch, mask, scan_rounds=None)``: the
+    learner's jitted round (with its gathered rows where client state is
+    offloaded) and, with ``scan_rounds=K``, the K-round scan dispatch,
+    through ``jax.eval_shape`` with the argument plumbing of
+    ``train_round_async`` / ``train_rounds_scan``: nothing compiles or
+    runs, a drifted signature, shape or dtype raises. Returns the
+    (state, metrics) shapes of the round, then of the scan."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    def trace(learner, ids, batch, mask, scan_rounds=None):
+        ids = np.asarray(ids)
+        ids_d = jnp.asarray(ids, jnp.int32)
+        cols = tuple(jnp.asarray(t) for t in batch)
+        m = jnp.asarray(mask, jnp.float32)
+        lr = jnp.float32(learner.lr_at(0.0))
+        rng = jax.random.PRNGKey(0)
+        rows = ((learner._offload_pipe.gather(ids.astype(np.int64)),)
+                if learner._offload else ())
+        out = jax.eval_shape(learner._round, learner.state, *rows, ids_d,
+                             cols, m, lr, rng)
+        if not scan_rounds:
+            return out
+        K = scan_rounds
+        stack = lambda a: jnp.broadcast_to(a, (K,) + a.shape)  # noqa: E731
+        return out, jax.eval_shape(
+            learner._rounds_scan_fn(), learner.state, stack(ids_d),
+            tuple(stack(c) for c in cols), stack(m),
+            jnp.zeros((K,), jnp.float32), jnp.stack([rng] * K))
+    return trace

@@ -387,3 +387,29 @@ def test_fault_model_1m_order_independent():
     np.testing.assert_array_equal(s1[perm], s2)
     np.testing.assert_array_equal(a1[perm], a2)
     np.testing.assert_array_equal(l1[perm], l2)
+
+
+@pytest.mark.parametrize("num_clients", [120, 1_000_000])
+def test_sparse_arena_is_o_nk_and_round_traces_at_scale(trace_round,
+                                                        num_clients):
+    """The docs/SCALING.md memory model, O(num_clients * k + W * d): the
+    sparse host arena's bytes track n * k (8 bytes an (idx, val) entry a
+    field, three fields at most; anything near n * d * 4 means a dense
+    arena came back), while the traced offload round's row input stays
+    (W, d) whatever n is."""
+    Wc, B, F, k = 8, 16, 8, 32
+    model = TinyMLP(num_classes=10, hidden=32)          # d = 618
+    cfg = FedConfig(mode="local_topk", k=k, error_type="local",
+                    local_momentum=0.9, virtual_momentum=0, num_workers=Wc,
+                    num_clients=num_clients, lr_scale=0.1,
+                    client_state="sparse", client_state_offload=True)
+    ln = FedLearner(model, cfg, make_cv_loss(model), None,
+                    jax.random.PRNGKey(0), np.zeros((1, F), np.float32))
+    assert ln.host_store.nbytes() <= 24 * num_clients * k
+    # scattered ids, the way sampling walks the arena
+    ids = np.random.RandomState(0).choice(num_clients, Wc, replace=False)
+    _, out_rows, _ = trace_round(
+        ln, ids, (np.zeros((Wc, B, F), np.float32),
+                  np.zeros((Wc, B), np.int32)), np.ones((Wc, B), np.float32))
+    assert {leaf.shape for leaf in jax.tree.leaves(out_rows)} == {
+        (Wc, ln.cfg.grad_dim)}

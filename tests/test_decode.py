@@ -265,3 +265,38 @@ def test_checkpoint_finetune_serve_e2e(tiny, tmp_path):
                                 max_seq_len=64, max_reply_len=6)
     assert isinstance(reply, list) and len(reply) <= 6
     assert all(isinstance(t, int) for t in reply)
+
+
+@pytest.mark.parametrize("program", ["prefill", "generate", "uncached"])
+def test_decode_programs_trace_at_gpt2_small(gpt2_small_shapes, program):
+    """The serving path's signature gate at published widths: the prefill
+    that fills the cache, the whole-reply program (prefill + the jitted
+    scan of cached steps) and the uncached full-window forward it stands
+    in for, each traced at batch 1 with a 128-token prompt and 64 new
+    tokens. Nothing compiles."""
+    P, N = gpt2_small_shapes.P, gpt2_small_shapes.N
+    engine = gpt2_small_shapes.engine()
+    model, params = engine.model, engine.params
+    B, V = 1, model.config.vocab_size
+    ids = jax.ShapeDtypeStruct((B, P), jnp.int32)
+    vec = jax.ShapeDtypeStruct((B,), jnp.int32)
+    if program == "prefill":
+        cache = jax.eval_shape(lambda: engine.init_cache(B))
+        logits, filled = jax.eval_shape(engine._prefill_raw, params, cache,
+                                        ids, ids, vec)
+        assert logits.shape == (B, V)
+        assert jax.tree.structure(filled) == jax.tree.structure(cache)
+        assert [x.shape for x in jax.tree.leaves(filled)] == [
+            x.shape for x in jax.tree.leaves(cache)]
+    elif program == "generate":
+        toks = jax.eval_shape(
+            lambda *a: engine._generate_raw(*a, max_new=N),
+            params, ids, ids, vec, vec, jax.random.PRNGKey(0))
+        assert (toks.shape, toks.dtype) == ((B, N), jnp.int32)
+    else:
+        lm, _ = jax.eval_shape(
+            lambda p: model.apply(
+                {"params": p}, jnp.zeros((B, 1, P + N), jnp.int32),
+                jnp.zeros((B, 1, P + N), jnp.int32),
+                jnp.zeros((B, 1), jnp.int32), train=False), params)
+        assert lm.shape == (B, 1, P + N, V)

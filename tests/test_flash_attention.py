@@ -317,3 +317,53 @@ def test_bf16_multiblock_grads_finite():
     grads = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
     for g in grads:
         assert bool(jnp.all(jnp.isfinite(g.astype(jnp.float32))))
+
+
+# the GPT2-small federated round's attention: W*B*C = 64 rows, 12 heads of 64
+_ROUND_ROWS, _ROUND_HEADS, _ROUND_HEAD_DIM = 64, 12, 64
+
+
+@pytest.mark.parametrize("T,variant", [
+    (256, (256, 256)), (256, (256, 128)), (256, (128, 256)),
+    (256, (128, 128)), (256, "no_dropout"), (256, "xla"),
+    (512, (512, 512)), (512, (256, 256)), (512, "no_dropout"),
+    (512, "xla")], ids=str)
+def test_fwd_bwd_traces_at_the_round_shape(T, variant):
+    """Forward and backward of the kernel with in-kernel dropout at each
+    (block_q, block_k) a GPT2 round may pick at T = 256 / 512, of the
+    kernel without dropout, and of the XLA formulation it replaces
+    (materialized scores, causal bias, rbg dropout on the probabilities:
+    models/gpt2.py's 'full' branch), traced at the round's bf16 shape.
+    Nothing compiles; a block size the kernel refuses, a drifted
+    signature or a gate that hands the work to the other formulation
+    fails here."""
+    from commefficient_tpu.ops.dropout import masked_dropout
+    R, H, D = _ROUND_ROWS, _ROUND_HEADS, _ROUND_HEAD_DIM
+    key = jax.random.PRNGKey(0)
+    rbg_key = jax.random.wrap_key_data(
+        jnp.arange(4, dtype=jnp.uint32), impl="rbg")
+
+    def xla_full(q, k, v):
+        att = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(D)
+        causal = jnp.tril(jnp.ones((T, T), bool))
+        att = att + jnp.where(causal, 0.0,
+                              jnp.finfo(att.dtype).min)[None, None]
+        att = masked_dropout(jax.nn.softmax(att, axis=-1), rbg_key, 0.1)
+        return jnp.einsum("bhqk,bkhd->bqhd", att, v)
+
+    if variant == "xla":
+        attn = xla_full
+    elif variant == "no_dropout":
+        attn = lambda q, k, v: flash_attention(  # noqa: E731
+            q, k, v, block_q=256, block_k=256)
+    else:
+        attn = lambda q, k, v: flash_attention(  # noqa: E731
+            q, k, v, block_q=variant[0], block_k=variant[1],
+            dropout_rate=0.1, dropout_key=key)
+    grad = jax.grad(lambda q, k, v: jnp.sum(
+        attn(q, k, v).astype(jnp.float32) ** 2), argnums=(0, 1, 2))
+    x = jax.ShapeDtypeStruct((R, T, H, D), jnp.bfloat16)
+    closed = jax.make_jaxpr(grad)(x, x, x)
+    assert [(a.shape, a.dtype) for a in closed.out_avals] == [
+        ((R, T, H, D), jnp.bfloat16)] * 3
+    assert ("pallas_call" in str(closed)) == (variant != "xla")

@@ -140,9 +140,10 @@ def test_unknown_model_raises():
            "(measured b1=0.5398 vs the b0*0.5=0.5287 bar); the bar "
            "holds on real accelerator bf16")
 def test_resnet9_bf16_converges_like_f32():
-    # the bench's headline CIFAR metric now runs dtype="bfloat16"
-    # (bench.py): convs/matmuls in bf16, params/logits f32. Convergence
-    # must be preserved — train the same tiny problem both ways.
+    # the benchmark's configuration runs dtype="bfloat16"
+    # (benchmarks/configs/resnet9-cifar10.json): convs/matmuls in bf16,
+    # params/logits f32. Convergence must be preserved — train the same
+    # tiny problem both ways.
     from commefficient_tpu.config import FedConfig
     from commefficient_tpu.federated.api import FedLearner
     from commefficient_tpu.federated.losses import make_cv_loss

@@ -211,3 +211,26 @@ def test_offload_checkpoint_roundtrip(tmp_path):
     ln3 = make_learner(False, **cfg_kw)
     with pytest.raises(ValueError, match="mismatch"):
         load_checkpoint(fn, ln3)
+
+
+def test_offload_round_traces_at_resnet9_scale(trace_round):
+    """The offload round's signature at a model's size: ResNet-9
+    (d = 6.57 M, bf16 compute) under local_topk with local momentum and
+    local error, the sampled clients' rows gathered from the host arena
+    and handed to the round as its second argument. Traced, not run."""
+    from commefficient_tpu.models import ResNet9
+    Wr, B, N = 4, 16, 12
+    model = ResNet9(num_classes=10, dtype="bfloat16")
+    cfg = FedConfig(mode="local_topk", k=50_000, error_type="local",
+                    local_momentum=0.9, virtual_momentum=0, num_workers=Wr,
+                    num_clients=N, lr_scale=0.1, client_state_offload=True)
+    images = np.zeros((Wr, B, 32, 32, 3), np.float32)
+    ln = FedLearner(model, cfg, make_cv_loss(model), None,
+                    jax.random.PRNGKey(0), images[0][:1])
+    assert ln._offload
+    state, out_rows, metrics = trace_round(
+        ln, np.arange(Wr), (images, np.zeros((Wr, B), np.int32)),
+        np.ones((Wr, B), np.float32))
+    assert state.weights.shape == (ln.cfg.grad_dim,)
+    d = ln.cfg.grad_dim
+    assert {leaf.shape for leaf in jax.tree.leaves(out_rows)} == {(Wr, d)}

@@ -209,6 +209,40 @@ def test_drain_leftovers_resubmitted_verbatim(own_engine):
     srv.swap_base_params(old_params)
 
 
+def test_swap_opens_a_new_acceptance_window(own_engine):
+    """A hot swap moves the target and not the drafter, so acceptance
+    drifts with the size of the move: the ``*_since_swap`` counters of
+    ``stats()`` start again at every swap (what OnlineLoop logs as the
+    drift), while the lifetime totals run on."""
+    tok, engine = own_engine
+    old_params = engine.params
+    srv = ContinuousBatchingServer(engine, slots=2, prefill_len=32,
+                                   kv_cache="paged", speculate_k=2)
+    coord = HotSwapCoordinator(srv)
+
+    def serve():
+        for ids, types in _prompts(tok, 3):
+            srv.submit(ids, types, types[-1], 8)
+        srv.run()
+        return srv.stats()
+
+    st = serve()
+    assert st["drafted_since_swap"] == st["drafted"] > 0
+    assert st["acceptance_rate_since_swap"] == 1.0     # self-drafting
+    coord.swap(_perturb(old_params))
+    st = srv.stats()
+    assert st["drafted_since_swap"] == st["accepted_since_swap"] == 0
+    assert st["acceptance_rate_since_swap"] is None
+    lifetime = st["drafted"]
+    assert lifetime > 0
+    st = serve()
+    assert 0 < st["drafted_since_swap"] == st["drafted"] - lifetime
+    # the drafter kept the old weights: the new target refuses some drafts
+    assert st["acceptance_rate_since_swap"] < 1.0
+    srv.drain()
+    srv.swap_base_params(old_params)
+
+
 # ---------------------------------------------------------------------------
 # graft audit: the online_loop target (pass at head, fail on mutation)
 # ---------------------------------------------------------------------------

@@ -266,6 +266,37 @@ def test_personalization_from_checkpoint_gate(tiny):
     assert isinstance(idx, PersonalizationIndex)
 
 
+@pytest.mark.parametrize("program", ["pack", "step"])
+def test_paged_programs_trace_at_gpt2_small(gpt2_small_shapes, paged_shapes,
+                                            program):
+    """KV-pool and page-table signature gate at published widths, 8
+    slots of 128 + 64 tokens in pages of 16: the pack of a prefilled B=1
+    row into pool pages and the page-table-traced paged step. The pools
+    stay (num_pages, page_size, H, hd) end to end. Nothing compiles."""
+    import jax.numpy as jnp
+    engine, P, B = gpt2_small_shapes.engine(), gpt2_small_shapes.P, 8
+    params, cfg = engine.params, engine.model.config
+    pager, pools, pt, vec, done = paged_shapes(engine, B, P)
+    if program == "pack":
+        ids1 = jax.ShapeDtypeStruct((1, P), jnp.int32)
+        _, row_cache = jax.eval_shape(
+            engine._prefill_raw, params,
+            jax.eval_shape(lambda: engine.init_cache(1)), ids1, ids1,
+            jax.ShapeDtypeStruct((1,), jnp.int32))
+        new_pools = jax.eval_shape(
+            engine._paged_insert_raw, pools, row_cache,
+            jax.ShapeDtypeStruct((pager.prefill_pages,), jnp.int32))
+    else:
+        new_pools, nxt, pos, _, new_done = jax.eval_shape(
+            engine._paged_step_raw, params, pools, pt, vec, vec, vec,
+            jax.random.PRNGKey(0), done)
+        assert (nxt.shape, pos.shape, new_done.shape) == ((B,),) * 3
+    page = (pager.num_pages, 16, cfg.n_head, cfg.n_embd // cfg.n_head)
+    assert len(new_pools) == cfg.n_layer
+    assert {x.shape for x in jax.tree.leaves(new_pools)} == {page}
+    assert all(x.dtype == cfg.jnp_dtype for x in jax.tree.leaves(new_pools))
+
+
 @pytest.mark.audit
 def test_decode_paged_audit_passes_at_head():
     from commefficient_tpu.analysis.targets import decode_paged_target

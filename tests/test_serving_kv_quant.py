@@ -308,6 +308,39 @@ def test_checkpoint_roundtrip_ignores_kv_quant(tiny, tmp_path):
         assert (a == np.asarray(b)).all()
 
 
+@pytest.mark.parametrize("contract", ["footprint", "capacity"])
+def test_int8_paged_step_contracts_at_gpt2_small(gpt2_small_shapes,
+                                                 paged_shapes, contract):
+    """``--kv_quant int8`` at published widths (8 slots of 128 + 64
+    tokens, pages of 16). footprint: the footprint rule of the
+    decode_paged_quant audit over the traced int8 paged step's jaxpr
+    finds no f32 value of the pool's (num_pages, page_size, H, hd) shape:
+    dequantization touches gathered pages only. capacity: the same KV
+    bytes hold at least three times the pages of an f32 pool, scale rows
+    counted. Nothing compiles."""
+    from commefficient_tpu.analysis import FootprintRule, ShapePattern, walk
+    engine = gpt2_small_shapes.engine()
+    cfg = engine.model.config
+    hd = cfg.n_embd // cfg.n_head
+    pager, pools, pt, vec, done = paged_shapes(
+        engine, 8, gpt2_small_shapes.P, kv_quant="int8")
+    if contract == "capacity":
+        assert kvq.capacity_multiplier_vs_f32(
+            pager.num_pages, 16, cfg.n_head, hd, cfg.n_layer,
+            "int8") >= 3.0
+        return
+    sites, stats = walk(jax.make_jaxpr(engine._paged_step_raw)(
+        engine.params, pools, pt, vec, vec, vec, jax.random.PRNGKey(0), done))
+    f32pool = ShapePattern(
+        ("num_pages", "page_size", "H", "hd"),
+        label="f32 materialization of the quantized KV pool",
+        allow_primitives=frozenset(), dtype="float32")
+    rep = FootprintRule((f32pool,)).check(
+        sites, stats, {"num_pages": pager.num_pages, "page_size": 16,
+                       "H": cfg.n_head, "hd": hd})
+    assert rep.ok, [str(v) for v in rep.violations]
+
+
 # ---------------------------------------------------------------- audit
 
 

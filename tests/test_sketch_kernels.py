@@ -203,8 +203,8 @@ def test_sketch_vec_use_kernel_safe_under_round_style_vmap():
 def test_force_dispatch_routes_public_api_to_kernel_on_cpu():
     """force_dispatch('kernel') overrides the backend gate so the public
     CountSketch entry points dispatch the (interpreted) kernels on CPU —
-    the mechanism the sketch_batched graft-audit target and the bench A/B
-    rows stand on — and 'fallback' forces them off everywhere. Both
+    the mechanism the sketch_batched graft-audit target stands on — and
+    'fallback' forces them off everywhere. Both
     bitwise; dispatch asserted via the jaxpr."""
     from commefficient_tpu.ops.sketch_kernels import force_dispatch
     d = 1_500

@@ -387,6 +387,52 @@ def test_speculation_from_checkpoint_gate():
                                            speculate_k=0) == 0
 
 
+@pytest.mark.parametrize("program", ["draft", "paged_verify"])
+@pytest.mark.parametrize("method,B,gamma", [("greedy", 1, 8),
+                                            ("topk", 8, 4)])
+def test_speculative_programs_trace_at_gpt2_small(
+        gpt2_small_shapes, paged_shapes, method, B, gamma, program):
+    """Drafter-cache and verify-window signature gate at published
+    widths: a GPT2-small target, a ``GPT2Config.tiny``-class drafter on
+    its vocabulary, pages of 16. Greedy: the gamma-draft program gives
+    (B, gamma) tokens and the paged multi-token verify (B, gamma + 1).
+    Under ``method='topk'`` the stochastic twins: the rng-threaded draft
+    also returns the drafter's full (B, gamma, V) distributions, which
+    the residual-rule verify takes. Nothing compiles."""
+    import jax.numpy as jnp
+    engine, P, N = (gpt2_small_shapes.engine(method), gpt2_small_shapes.P,
+                    gpt2_small_shapes.N)
+    V = engine.model.config.vocab_size
+    dcfg = GPT2Config.tiny(vocab_size=V)
+    dcfg.n_positions = max(dcfg.n_positions, P + N)
+    dcfg.dtype = "bfloat16"
+    drafter = GPT2DoubleHeads(dcfg)
+    dparams = gpt2_small_shapes.abstract_params(drafter)
+    spec = SpeculativeDecoder(engine, gamma=gamma, slots=B,
+                              drafter_model=drafter, drafter_params=dparams)
+    assert spec.stochastic == (method == "topk")
+    _, pools, pt, vec, done = paged_shapes(engine, B, P)
+    key = jax.random.PRNGKey(0)
+    drafts = jax.ShapeDtypeStruct((B, gamma), jnp.int32)
+    dprobs = jax.ShapeDtypeStruct((B, gamma, V), jnp.float32)
+    if program == "draft":
+        raw = spec._draft_stoch_raw if spec.stochastic else spec._draft_raw
+        out = jax.eval_shape(raw, dparams, spec.dcache, vec, vec, vec, vec,
+                             vec, *([key] if spec.stochastic else []))
+        want = (drafts, dprobs) if spec.stochastic else (drafts,)
+        assert [(o.shape, o.dtype) for o in out[1:1 + len(want)]] == [
+            (w.shape, w.dtype) for w in want]
+        return
+    if spec.stochastic:
+        out = jax.eval_shape(spec._paged_verify_stoch_raw, engine.params,
+                             pools, pt, vec, vec, vec, drafts, dprobs, done,
+                             key)
+    else:
+        out = jax.eval_shape(spec._paged_verify_raw, engine.params, pools,
+                             pt, vec, vec, vec, drafts, done)
+    assert out[1].shape == (B, gamma + 1)          # emitted
+
+
 @pytest.mark.audit
 def test_decode_speculative_audit_passes_at_head():
     from commefficient_tpu.analysis.targets import decode_speculative_target

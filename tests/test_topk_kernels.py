@@ -242,7 +242,7 @@ def test_approx_recall_refuses_the_kernel():
 
 def test_topk_public_api_dispatches_kernel_under_force():
     """ops.topk.topk / topk_values_indices route through the streaming
-    kernel when forced (the audit/bench mechanism) — bitwise, with the
+    kernel when forced (the audits' mechanism) — bitwise, with the
     pallas_call visible in the jaxpr — and approx_recall keeps the
     incumbent approx path even when forced."""
     d, k = 20_000, 50
