@@ -119,26 +119,37 @@ def _sketched(sketched_grad, state, cfg, lr, sketch: CountSketch):
     # 'virtual' accumulates; 'none' recovers straight from the momentum table
     # (sketch+'local' is rejected by FedConfig.validate)
     err = state.Verror + v if cfg.error_type == "virtual" else v
-    # fused unsketch + exact top-k where the kernels dispatch (the (d,)
-    # estimate vector never materializes — ops/topk_kernels); otherwise
-    # the incumbent chain: estimate-all routed through the batch-guard
-    # dispatch at batch 1 so it compiles the SAME 2-D grid kernel the
-    # vmapped client.py/client_store.py paths run — one resident
-    # estimate program instead of a 1-D grid twin (bitwise-identical
-    # either way, tests/test_sketch_kernels.py, test_topk_kernels.py)
-    if cfg.server_fused != "off":
-        vals, idxs = sketch.unsketch_values_indices(
-            err, cfg.k, cfg.topk_approx_recall or None, use_kernel=True)
+    # One algorithm (recover the top-k, re-sketch the update, mask the state
+    # on the re-sketch's nonzero buckets), two ways to make the re-sketch,
+    # chosen by what is known at trace time.
+    approx = cfg.topk_approx_recall or None
+    if cfg.server_fused != "off" and sketch._fused_unsketch_ok(approx, True):
+        # The kernels dispatch: unsketch_select_pallas writes the DENSE
+        # masked update (the (d,) estimate vector never materializes —
+        # ops/topk_kernels), so that output is the update, and its
+        # re-sketch is one more pass of the dense sketch kernel (the
+        # program round.py's aggregate-side sketch already compiles).
+        # Compacting it to (vals, idxs) for sketch_sparse would cost a
+        # d-long cumsum + scatter: 38 ms a round at d=6.5M/k=50k on a v5e
+        # against 4.5 ms for the kernel's dense pass (PERF.md, PR 34).
+        update = sketch.unsketch(err, cfg.k, None, use_kernel=True)
+        sketched_update = sketch.sketch_vec_batched(update, use_kernel=True)
     else:
+        # lax.top_k hands over (vals, idxs) for nothing, so re-sketching
+        # only the k nonzeros (O(r*k), CountSketch.sketch_sparse) beats a
+        # dense XLA sketch of the update (O(r*d)). estimate-all is routed
+        # through the batch-guard dispatch at batch 1 so it compiles the
+        # SAME 2-D grid kernel the vmapped client.py/client_store.py paths
+        # run — one resident estimate program instead of a 1-D grid twin.
         vals, idxs = topk_values_indices(
-            sketch.estimates_batched(err, use_kernel=True),
-            cfg.k, cfg.topk_approx_recall or None, use_kernel=False)
-    update = jnp.zeros((cfg.grad_dim,)).at[idxs].set(vals)
-    # the update's footprint *in sketch space*: re-sketching only the k
-    # nonzeros matches sketching the dense update (up to float summation
-    # order) and is ~130x cheaper at the default d=6.5M/k=50k
-    # (see CountSketch.sketch_sparse)
-    sketched_update = sketch.sketch_sparse(vals, idxs)
+            sketch.estimates_batched(err, use_kernel=True), cfg.k, approx,
+            use_kernel=None if cfg.server_fused != "off" else False)
+        update = jnp.zeros((cfg.grad_dim,)).at[idxs].set(vals)
+        sketched_update = sketch.sketch_sparse(vals, idxs)
+    # the update's footprint *in sketch space*: only `!= 0` is read, so
+    # the two re-sketches agree wherever their bucket sums differ by float
+    # summation order (tests/test_server_fused.py pins update, Verror and
+    # Vvelocity bitwise across the arms)
     support = sketched_update != 0
     if cfg.error_type == "virtual":
         err = jnp.where(support, 0.0, err)

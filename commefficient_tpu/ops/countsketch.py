@@ -410,10 +410,16 @@ class CountSketch:
         Equivalent to ``sketch_vec`` of the dense vector (the d-k zeros
         contribute 0.0 to every bucket) up to float32 summation order in
         buckets where several nonzeros collide, at O(r*k) instead of
-        O(r*d) — the win that makes re-sketching a top-k update ~free
-        (measured 330ms -> <5ms at d=6.5M, k=50k on a TPU chip). Works for
-        both schemes: ``_row_hashes`` yields the same flat buckets the
-        dense paths use."""
+        O(r*d). The right re-sketch wherever (values, indices) come for
+        nothing (``lax.top_k``'s return) and the dense sketch would be
+        XLA's: an older tree read 330 ms -> <5 ms for that pair at
+        d=6.5M, k=50k on a TPU chip. Where the Pallas kernels dispatch
+        the order turns round: the dense kernel pass takes 4.5 ms at that
+        shape, and compacting a dense top-k to (values, indices) first
+        cost 38 ms, so federated/server._sketched re-sketches the dense
+        update there (PERF.md, PR 34). Works for both schemes:
+        ``_row_hashes`` yields the same flat buckets the dense paths
+        use."""
         idx = indices.astype(jnp.int32)
 
         def one_row(row):
@@ -521,7 +527,9 @@ class CountSketch:
         estimates feed the streaming radix top-k directly from the
         VMEM-resident table, and the (d,) estimate vector never exists
         (ops/topk_kernels.unsketch_select_pallas — bitwise-identical to
-        the estimates -> topk chain below). ``approx_recall`` selects
+        the estimates -> topk chain below). That dense output IS the
+        server's update on the fused arm (federated/server._sketched):
+        nothing compacts it to (values, indices). ``approx_recall`` selects
         with ``lax.approx_max_k`` instead of the exact sort (see
         ops/topk.py; 5.4x at d=124M, k=50k) and refuses the fusion."""
         from commefficient_tpu.ops.topk import topk
@@ -531,26 +539,6 @@ class CountSketch:
             masked, _ = unsketch_select_pallas(self, table, k=k)
             return masked
         return topk(self.estimates(table, use_kernel), k, approx_recall)
-
-    @partial(jax.jit, static_argnums=(0, 2, 3, 4))
-    def unsketch_values_indices(self, table: jax.Array, k: int,
-                                approx_recall=None,
-                                use_kernel: bool = False):
-        """(values, indices) of the recovered top-k, in the exact stable
-        ``lax.top_k`` return order — the O(k) twin of ``unsketch`` for
-        callers that re-sketch or transmit the recovery
-        (federated/server._sketched) instead of densifying it."""
-        from commefficient_tpu.ops.topk import topk_values_indices
-        if self._fused_unsketch_ok(approx_recall, use_kernel):
-            from commefficient_tpu.ops.topk_kernels import (
-                unsketch_select_pallas, values_indices_from_mask)
-            masked, mask = unsketch_select_pallas(self, table, k=k)
-            return values_indices_from_mask(masked, mask, k)
-        # incumbent chain verbatim (the server call site's): the batched
-        # estimate entry so TPU compiles the SAME 2-D grid kernel the
-        # vmapped client paths run — one resident estimate program
-        return topk_values_indices(
-            self.estimates_batched(table, use_kernel), k, approx_recall)
 
     @partial(jax.jit, static_argnums=0)
     def l2estimate(self, table: jax.Array) -> jax.Array:

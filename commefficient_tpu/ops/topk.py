@@ -111,7 +111,10 @@ def topk_values_indices(vec: jax.Array, k: int,
     The sparse twin of ``topk``: same support, same selection (one
     implementation, both dispatch modes), but handing back the k-sized
     arrays lets callers re-sketch or transmit the update at O(k) instead
-    of O(d) (server._sketched and the sparse client codec share this)."""
+    of O(d) (server._sketched, where the fused unsketch does not
+    dispatch). Under a dispatched kernel the k-sized form costs a d-long
+    compaction (topk_kernels.values_indices_from_mask); ``lax.top_k``
+    hands it over for nothing."""
     tk = _kernels()
     kernel = use_kernel is not False and tk.topk_kernel_ok(approx_recall)
     if vec.ndim == 1:

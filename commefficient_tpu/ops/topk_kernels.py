@@ -66,7 +66,11 @@ Dispatch mirrors ops/sketch_kernels: ``force_dispatch`` ("kernel" /
 "fallback") overrides the backend gate for audits and parity tests, the
 ``custom_vmap`` guards dispatch the purpose-built batched kernels under
 vmap (never JAX's default grid-prepending rule), and every entry has a
-bitwise XLA fallback. ``approx_recall`` refuses the kernel by contract:
+bitwise XLA fallback. The select pass's outputs are dense; a caller
+that needs the k-long ``(values, indices)`` form pays
+:func:`values_indices_from_mask`'s d-long compaction for it
+(``ops.topk.topk_values_indices`` only — the server rules take the dense
+outputs as they come). ``approx_recall`` refuses the kernel by contract:
 ``lax.approx_max_k`` is already TPU-native and intentionally inexact,
 so there is nothing to bit-agree with (callers gate on
 :func:`topk_kernel_ok`).
@@ -673,7 +677,14 @@ def values_indices_from_mask(masked, mask, k):
     see bitwise-identical operand order. Unselected slots (when fewer
     than k entries are selected, impossible for exact k) pad with
     index 0 / value ``masked[0]``-free zeros exactly like the scatter
-    default."""
+    default.
+
+    The cost is the compaction, not the k-long sort: the cumsum and the
+    scatter are d long (38 ms a round at d=6.5M, k=50k on a v5e; PERF.md,
+    PR 34). Not on the sketch server's path (federated/server._sketched
+    takes ``unsketch_select_pallas``'s dense output as the update); here
+    for ``ops.topk.topk_values_indices`` under a dispatched kernel, whose
+    callers want the k-long form."""
     d = masked.shape[0]
     sel = mask != 0
     pos = jnp.cumsum(mask) - 1

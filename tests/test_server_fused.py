@@ -168,3 +168,120 @@ def test_het_k_round_trajectory_bitwise(force):
                           server_fused="auto", force="fallback")
         for a, b in zip(got[:3], ref[:3]):
             np.testing.assert_array_equal(a, b)
+
+
+def _sketch_server_jaxpr(arm, d=3000, k=7):
+    """Walk the traced sketch-mode ``server_update`` of one arm:
+    (pallas_call names, jitted callees' names, [(primitive, operand
+    size)] of every cumsum / sort / top_k / scatter)."""
+    from commefficient_tpu.analysis.walker import walk
+    from commefficient_tpu.federated.server import (init_server_opt_state,
+                                                    make_sketch,
+                                                    server_update)
+
+    cfg = FedConfig(**dict(MODE_CFGS["sketch"], k=k),
+                    server_fused="off" if arm == "off" else "auto"
+                    ).finalize(d)
+    sketch = make_sketch(cfg)
+
+    def fn(g, st):
+        return server_update(g, st, cfg, 0.1, sketch=sketch)
+
+    ctx = (contextlib.nullcontext() if arm == "off"
+           else force_dispatch(arm))
+    with ctx:
+        jaxpr = jax.make_jaxpr(fn)(jnp.zeros(cfg.transmit_shape),
+                                   init_server_opt_state(cfg))
+    sites, _ = walk(jaxpr)
+    kernels, callees, movers = [], [], []
+    for site in sites:
+        prim, params = site.primitive, site.eqn.params
+        if prim == "pallas_call":
+            kernels.append(params["name"])
+        elif prim in ("pjit", "jit"):
+            callees.append(params["name"])
+        elif (prim in ("cumsum", "sort", "top_k")
+              or prim.startswith("scatter")):
+            movers.append((prim, max(
+                int(np.prod(v.aval.shape)) for v in site.eqn.invars
+                if hasattr(v.aval, "shape"))))
+    return kernels, callees, movers
+
+
+@pytest.mark.parametrize("arm", ["kernel", "fallback", "off"])
+def test_sketch_server_update_arms_in_jaxpr(arm):
+    """Where the fused unsketch dispatches, the sketch server takes the
+    select kernel's dense output as the update and one more pass of the
+    dense sketch kernel as its support: no d-long cumsum / sort /
+    scatter (the compaction to (vals, idxs) and the scatter back are
+    gone), no sparse re-sketch.  Everywhere else no kernel runs and the
+    ``lax.top_k`` -> scatter -> ``sketch_sparse`` chain stands."""
+    d = 3000
+    kernels, callees, movers = _sketch_server_jaxpr(arm, d=d)
+    d_long = [(p, n) for p, n in movers if n >= d]
+    if arm == "kernel":
+        # the radix loop's body and the final count are one eqn each
+        assert sorted(kernels) == ["radix_count_pallas",
+                                   "radix_count_pallas",
+                                   "sketch_vec_pallas",
+                                   "unsketch_select_pallas"], kernels
+        assert not d_long, d_long
+        assert "sketch_sparse" not in callees
+    else:
+        assert not kernels, kernels
+        assert "sketch_sparse" in callees
+        assert ("top_k", d) in d_long, movers
+        assert any(p.startswith("scatter") for p, _ in d_long), movers
+
+
+def _planted_table(cs, rng):
+    """A table whose estimates hold fewer than k nonzeros, many of equal
+    magnitude: the top-k then fills up with zero estimates in index
+    order, about half of them -0.0 (a negative sign times 0.0)."""
+    vec = np.zeros(cs.d, np.float32)
+    at = rng.choice(cs.d, 2000, replace=False)
+    vec[at] = rng.choice(np.float32([0.5, -0.5, 1.25, -1.25, 3.0]), 2000)
+    return cs.sketch_vec(jnp.asarray(vec))
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2, "planted"])
+def test_dense_resketch_support_equals_sparse_at_cell_density(seed):
+    """The fused arm's two substitutions at the sketch cell's density
+    (k/c = 0.1, r = 5), through the kernels themselves: the select
+    kernel's dense output is bitwise ``zeros(d).at[idxs].set(vals)``,
+    and the dense re-sketch's nonzero buckets are ``sketch_sparse``'s,
+    bucket for bucket (only ``!= 0`` is read; the sums may differ in
+    their last bits where nonzeros collide)."""
+    from commefficient_tpu.federated.server import make_sketch
+    from commefficient_tpu.ops.topk import topk_values_indices
+
+    d, c, r, k = 656_864, 50_000, 5, 5_000
+    cs = make_sketch(FedConfig(mode="sketch", error_type="virtual", k=k,
+                               num_rows=r, num_cols=c).finalize(d))
+    planted = seed == "planted"
+    rng = np.random.RandomState(7 if planted else seed)
+    if planted:
+        table = _planted_table(cs, rng)
+    else:
+        table = cs.sketch_vec(jnp.asarray(rng.randn(d).astype(np.float32)))
+
+    vals, idxs = topk_values_indices(cs.estimates(table), k,
+                                     use_kernel=False)
+    ref_update = np.asarray(jnp.zeros((d,)).at[idxs].set(vals))
+    ref_support = np.asarray(cs.sketch_sparse(vals, idxs) != 0)
+    with force_dispatch("kernel"):
+        update = cs.unsketch(table, k, None, use_kernel=True)
+        support = np.asarray(
+            cs.sketch_vec_batched(update, use_kernel=True) != 0)
+    update = np.asarray(update)
+
+    np.testing.assert_array_equal(update.view(np.uint32),
+                                  ref_update.view(np.uint32))
+    np.testing.assert_array_equal(support, ref_support)
+    assert ref_support.any() and not ref_support.all()
+    if planted:
+        picked = np.asarray(vals)
+        assert (picked == 0).any() and np.signbit(picked[picked == 0]).any()
+        assert np.unique(np.abs(picked[picked != 0])).size < 100
+    else:
+        assert np.count_nonzero(update) == k
