@@ -4,6 +4,7 @@ from commefficient_tpu.data.emnist import FedEMNIST
 from commefficient_tpu.data.imagenet import FedImageNet
 from commefficient_tpu.data.synthetic import SyntheticCV
 from commefficient_tpu.data.offline import FedDigits, FedPatches32
+from commefficient_tpu.data.tokens import FedTokens
 from commefficient_tpu.data.sampler import FedSampler
 from commefficient_tpu.data.batching import FedBatcher, val_batches
 
@@ -17,6 +18,6 @@ fed_datasets = {
     "Patches32": FedPatches32,
 }
 
-__all__ = ["FedDataset", "FedCIFAR10", "FedCIFAR100", "FedEMNIST",
+__all__ = ["FedDataset", "FedTokens", "FedCIFAR10", "FedCIFAR100", "FedEMNIST",
            "FedImageNet", "SyntheticCV", "FedDigits", "FedPatches32",
            "FedSampler", "FedBatcher", "val_batches", "fed_datasets"]

@@ -176,6 +176,63 @@ def make_gpt2_val_loss(model):
     return apply_loss
 
 
+#: the per-example metric rows of ``make_lm_loss``'s training loss, as the
+#: counters ``training/gpt2.py`` adds their round totals to
+LM_TRAIN_COUNTERS = ("moe.assignments_held", "moe.assignments_fullest",
+                     "moe.dropped")
+
+
+def make_lm_loss(model, train: bool, chunk: int = 8192):
+    """Next-token cross-entropy of a language model with no other head
+    (``models/nemotron_h.py``): ``model.apply`` returns final hidden states,
+    the untied head is ``params['lm_head_embedding']`` (V, C) and is applied
+    vocabulary-chunk by chunk (``ops/fused_ce.py``; the logits are never
+    whole). Batch: ``(tokens (B, T), labels (B, T))``, labels already the
+    next token, -1 ignored. Per-example loss: the mean over a sequence's
+    labelled positions.
+
+    Metric rows. Training: ``LM_TRAIN_COUNTERS`` — what the expert layers
+    sow a token (``ops/moe.py``), summed by sequence and over the layers, so
+    they ride to the host with the loss. Validation: the rows
+    ``make_gpt2_val_loss`` gives ([0, nll token-sum, labelled tokens])."""
+    from commefficient_tpu.ops.fused_ce import lm_head_nll
+    from commefficient_tpu.utils.tracing import layer
+    cd = model.config.jnp_dtype
+
+    def sown(inter, key, B):
+        leaves = [leaf for path, leaf in
+                  jax.tree_util.tree_flatten_with_path(inter)[0]
+                  if any(getattr(p, "key", None) == key for p in path)]
+        return sum((leaf.reshape(B, -1).sum(axis=-1) for leaf in leaves),
+                   jnp.zeros((B,), jnp.float32))
+
+    def apply_loss(params, batch, rng, train_flag):
+        tokens, labels = batch
+        B = tokens.shape[0]
+        hidden, inter = model.apply({"params": params}, tokens,
+                                    mutable=["intermediates"])
+        valid = labels >= 0
+        with layer("lm_head"):
+            wte = params["lm_head_embedding"]
+            nll = lm_head_nll(hidden.reshape(-1, hidden.shape[-1]), wte,
+                              jnp.where(valid, labels, 0).reshape(-1),
+                              min(chunk, wte.shape[0]), cd)
+        nll_sum = jnp.sum(jnp.where(valid, nll.reshape(labels.shape), 0.0),
+                          axis=-1)
+        count = jnp.sum(valid, axis=-1).astype(jnp.float32)
+        loss = nll_sum / jnp.maximum(count, 1.0)
+        if not train:
+            return loss, jnp.stack([jnp.zeros_like(loss), nll_sum, count])
+        inter = inter.get("intermediates", {})
+        return loss, jnp.stack([sown(inter, "moe_held", B),
+                                sown(inter, "moe_fullest", B),
+                                sown(inter, "moe_dropped", B)])
+
+    if train:
+        apply_loss.counters = LM_TRAIN_COUNTERS
+    return apply_loss
+
+
 def make_regression_loss(model):
     """Squared error, for the golden-value toy problems."""
 

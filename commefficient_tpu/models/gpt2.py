@@ -58,11 +58,11 @@ class GPT2Config:
         # trades ~1/3 more FLOPs for O(n_layer) less activation memory —
         # the standard TPU lever for long-context training
         self.remat = remat
-        # >0 replaces every block's MLP with a Switch-style MoE of this
-        # many experts (ops/moe.py); stacked expert weights are the
+        # >0 replaces every block's MLP with a Switch-routed (top-1, no
+        # capacity, nothing dropped) MoE of this many experts
+        # (ops/moe.py); stacked expert weights are the
         # expert-parallel axis. 0 = dense MLP (reference parity).
         self.moe_experts = 0
-        self.moe_capacity_factor = 1.25
         # 'xla' (portable recompute-in-backward masked_dropout) or
         # 'tpu_bits' (hardware-RNG Pallas kernel, ops/dropout.py — same
         # Bernoulli distribution, ~8x cheaper bit generation on-chip; not
@@ -328,7 +328,6 @@ class Block(nn.Module):
     attn_block_size: int = 512
     seq_axis: str = "seq"
     moe_experts: int = 0
-    moe_capacity_factor: float = 1.25
     post_ln: bool = False    # GPT-1 places LN after the residual add
     dropout_impl: str = "xla"
     attn_dropout: str = "auto"
@@ -337,8 +336,7 @@ class Block(nn.Module):
         if self.moe_experts > 0:
             from commefficient_tpu.ops.moe import MoEFFN
             return MoEFFN(self.moe_experts, 4 * h.shape[-1],
-                          self.moe_capacity_factor, self.dtype,
-                          name="moe")(h)
+                          dtype=self.dtype, name="moe")(h)
         m = nn.Dense(4 * h.shape[-1], dtype=self.dtype,
                      kernel_init=nn.initializers.normal(0.02))(h)
         m = nn.gelu(m)
@@ -425,7 +423,7 @@ class GPT2DoubleHeads(nn.Module):
                                  "call with train=False")
             if cfg.moe_experts > 0:
                 raise ValueError("KV-cache decoding does not support MoE "
-                                 "blocks yet (capacity routing at T=1)")
+                                 "blocks yet")
         B, C, T = input_ids.shape
         ids = input_ids.reshape(B * C, T)
         types = token_type_ids.reshape(B * C, T)
@@ -464,8 +462,7 @@ class GPT2DoubleHeads(nn.Module):
         for i in range(cfg.n_layer):
             blk = block_cls(cfg.n_head, cfg.dropout, cfg.jnp_dtype,
                             cfg.attn_impl, cfg.attn_block_size,
-                            cfg.seq_axis, cfg.moe_experts,
-                            cfg.moe_capacity_factor, post_ln,
+                            cfg.seq_axis, cfg.moe_experts, post_ln,
                             cfg.dropout_impl,
                             getattr(cfg, "attn_dropout", "auto"))
             if cache is None:

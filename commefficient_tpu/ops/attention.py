@@ -30,6 +30,10 @@ The scan and ring formulations still do not compose with prob-dropout
 O(T^2)); callers that need dropout off-kernel apply output dropout
 instead (models/gpt2.py's fallback).
 
+* ``grouped_query_attention`` — the training path with fewer key/value
+  heads than query heads (32 over 2, say): the shared heads repeated, then
+  one of the two above.
+
 * ``decode_attention`` — the inference mode: one (or a few) query rows
   against a cached (B, S, H, D) key/value array with per-row global
   positions. O(S) per generated token; the KV-cached serving path
@@ -101,6 +105,28 @@ def full_attention(q, k, v, *, causal: bool = True,
     # online-softmax impls use
     any_valid = jnp.any(s > _NEG / 2, axis=-1)            # (B, H, Tq)
     return jnp.where(any_valid.transpose(0, 2, 1)[..., None], out, 0.0)
+
+
+#: sequences from this length on take the blockwise (flash) path
+_BLOCKWISE_FROM = 1024
+
+
+def grouped_query_attention(q, k, v, *, causal: bool = True) -> jax.Array:
+    """Training-path attention with fewer key/value heads than query heads:
+    q (B, T, H, D), k/v (B, T, Hkv, D), H a multiple of Hkv; query head h
+    reads key/value head h // (H // Hkv). The shared heads are repeated to H
+    (autodiff sums their gradients back over the group) and handed to
+    ``full_attention`` below ``_BLOCKWISE_FROM`` positions, else to
+    ``blockwise_attention`` (the fused kernel on a TPU)."""
+    H, Hkv = q.shape[2], k.shape[2]
+    if H % Hkv:
+        raise ValueError(f"{H} query heads over {Hkv} key/value heads")
+    if H != Hkv:
+        k = jnp.repeat(k, H // Hkv, axis=2)
+        v = jnp.repeat(v, H // Hkv, axis=2)
+    if q.shape[1] < _BLOCKWISE_FROM:
+        return full_attention(q, k, v, causal=causal)
+    return blockwise_attention(q, k, v, causal=causal)
 
 
 def decode_attention(q, k, v, q_pos, *,

@@ -25,13 +25,9 @@ and tests; the double-heads MC pick is intentionally out of scope (the
 reference's PersonaChat MC task uses short sequences where PP is
 pointless; PP targets deep-trunk LM work).
 
-MoE blocks compose with the pipeline, with one semantic note: MoE
-capacity is applied per dispatch group, and under PP the group is one
-MICROBATCH (mb*T tokens) instead of the whole batch — tokens drop at
-different capacity boundaries than an unpipelined forward. Outputs are
-identical whenever capacity is non-binding (tested); under binding
-capacity this is the same group-dependence every microbatched Switch
-implementation has.
+MoE blocks compose with the pipeline: the expert layer (ops/moe.py) has
+no capacity and drops nothing, so a token's output does not depend on which
+microbatch it rode in, and outputs equal the unpipelined forward (tested).
 """
 
 from __future__ import annotations
@@ -117,7 +113,7 @@ def gpt2_pp_lm_apply(mesh, model, params, input_ids, token_type_ids,
     post_ln = cfg.arch == "openai-gpt"
     block_key = (cfg.n_head, cfg.jnp_dtype, cfg.attn_impl,
                  cfg.attn_block_size, cfg.seq_axis, cfg.moe_experts,
-                 cfg.moe_capacity_factor, cfg.remat,
+                 cfg.remat,
                  cfg.dropout if dropout_on else 0.0, post_ln)
     pipe = _build_pipe(mesh, axis_name, block_key, S, per_stage,
                        B_local, T, n_micro, mb, dp_axis)
@@ -144,13 +140,13 @@ def _build_pipe(mesh, axis_name, block_key, S, per_stage, B, T, n_micro,
     loop's every step) reuse the compiled program. Cache key = everything
     the trace depends on; jax.Mesh is hashable."""
     (n_head, dt, attn_impl, attn_block_size, seq_axis,
-     moe_experts, moe_cap, remat, dropout, post_ln) = block_key
+     moe_experts, remat, dropout, post_ln) = block_key
     # blockwise (flash) attention, MoE, and the GPT-1 post-LN arch compose
     # with PP (note: MoE aux-loss intermediates are discarded in the
     # pipe); dropout is live when the caller plumbed rngs (key
     # decorrelated per stage/tick/layer)
     block = Block(n_head, dropout, dt, attn_impl, attn_block_size, seq_axis,
-                  moe_experts, moe_cap, post_ln)
+                  moe_experts, post_ln)
 
     def apply_layer(layer_params, h, layer_rngs):
         return block.apply({"params": layer_params}, h, dropout > 0,

@@ -31,7 +31,7 @@ from commefficient_tpu.training.args import (args_to_config, build_parser,
                                              resolve_fused_ce)
 from commefficient_tpu.utils.logging import TableLogger, Timer
 from commefficient_tpu.utils.schedules import gpt2_lr_schedule
-from commefficient_tpu.utils.tracing import compile_counters, span
+from commefficient_tpu.utils.tracing import compile_counters, count, span
 
 
 def save_pretrained(log_dir: str, learner, gpt2_config: GPT2Config,
@@ -63,18 +63,9 @@ def make_persona(args, tokenizer, train: bool):
     return SyntheticPersona(**kw)
 
 
-def train(args, mesh=None, max_rounds=None, log=True):
-    from commefficient_tpu.federated.api import set_transfer_guard
-    set_transfer_guard(getattr(args, "transfer_guard", "disallow"))
-    compile_counters()
-    with span("setup.data"):
-        tokenizer = get_tokenizer(args.model_checkpoint)
-        train_set = make_persona(args, tokenizer, train=True)
-        val_set = make_persona(args, tokenizer, train=False)
-    args.num_clients = train_set.num_clients
-    from commefficient_tpu.parallel.mesh import padded_num_clients
-    num_clients = padded_num_clients(args.num_clients, mesh)
-
+def _gpt2_model(args, mesh, cfg, tokenizer, sample, log):
+    """GPT2 double-heads (any size, optionally MoE blocks) with the losses
+    and parameter layouts its mesh axes ask for."""
     if args.model == "gpt2":
         gcfg = GPT2Config.small(vocab_size=tokenizer.vocab_size)
     elif args.model == "openai-gpt":
@@ -102,8 +93,6 @@ def train(args, mesh=None, max_rounds=None, log=True):
     # and mesh (args.resolve_fused_ce); legacy --fused_lm_head forces on
     gcfg.fused_lm_head = resolve_fused_ce(args, mesh)
     gcfg.moe_experts = int(getattr(args, "moe_experts", 0) or 0)
-    gcfg.moe_capacity_factor = float(getattr(args, "moe_capacity_factor",
-                                             1.25))
     seq_n = (mesh.shape["seq"]
              if mesh is not None and "seq" in mesh.axis_names else 1)
     if seq_n > 1:
@@ -131,17 +120,6 @@ def train(args, mesh=None, max_rounds=None, log=True):
         icfg.attn_impl = "full"
         init_model = GPT2DoubleHeads(icfg)
 
-    batcher = FedBatcher(train_set, args.num_workers, args.local_batch_size,
-                         seed=args.seed)
-    spe = batcher.steps_per_epoch()
-    total_steps = max(1, int(args.num_epochs * spe))
-    sched = gpt2_lr_schedule(args.lr_scale, total_steps)
-
-    # init shapes straight from the dataset — materializing a batcher round
-    # here would advance the sampler RNG and change epoch 1's sampling
-    sample = tuple(c[:1] for c in train_set.get_flat_batch(np.arange(1)))
-    cfg = args_to_config(args, num_clients=num_clients,
-                         max_seq_len=args.max_seq_len)
     stage_n = (mesh.shape["stage"]
                if mesh is not None and "stage" in mesh.axis_names else 1)
     expert_n = (mesh.shape["expert"]
@@ -227,16 +205,6 @@ def train(args, mesh=None, max_rounds=None, log=True):
             moe_aux_weight=getattr(args, "moe_aux_weight", 1e-2))
         loss_val = make_gpt2_val_loss(model)
 
-    class _Wrap:
-        """Adapter: FedLearner inits via module.init(rng, x, train=...);
-        GPT2 takes three arrays."""
-
-        def init(self, rng, sample_in, train):
-            return init_model.init(rng, *sample_in, train=train)
-
-        def apply(self, *a, **k):
-            return init_model.apply(*a, **k)
-
     sample_in = (sample[0], sample[4], sample[1])
     init_params = None
     if args.model in ("gpt2", "openai-gpt"):
@@ -291,10 +259,55 @@ def train(args, mesh=None, max_rounds=None, log=True):
             print(f"--mesh model={mesh.shape['model']}: TP-sharding GPT2 "
                   "params inside the federated round")
 
-    # --server_mode buffered swaps in the FedBuff event-loop learner
-    # (federated/buffer.py; mesh-native — under --mesh clients=N its
-    # programs shard like the sync round, with the slot buffer
-    # partitioned over the axis)
+    return dict(
+        init_model=init_model, loss_tr=loss_tr, loss_val=loss_val,
+        sample_in=sample_in, init_params=init_params,
+        param_specs=param_specs, gcfg=gcfg,
+        init=lambda rng, *xs: init_model.init(rng, *xs, train=False))
+
+
+def _nemotron_model(args, mesh, cfg, sample):
+    """The hybrid Mamba-2 / sparse-expert / attention language model
+    (``models/nemotron_h.py``): published widths, or the tests' tiny
+    preset, cut by ``--layer_pattern``, ``--experts_held``,
+    ``--vocab_rows``. Next-token loss only; runs the fused federated
+    round on one device or over ``--mesh clients=``."""
+    from commefficient_tpu.federated.losses import make_lm_loss
+    from commefficient_tpu.models.nemotron_h import (NemotronH,
+                                                     NemotronHConfig)
+    if mesh is not None and set(mesh.axis_names) - {"clients"}:
+        raise ValueError(f"--model {args.model} composes with --mesh "
+                         f"clients= only (got axes {mesh.axis_names})")
+    kw = {"compute_dtype": getattr(args, "compute_dtype", "float32")}
+    if args.layer_pattern:
+        kw["pattern"] = args.layer_pattern
+    if args.vocab_rows:
+        kw["vocab_rows"] = args.vocab_rows
+    base = (NemotronHConfig.tiny if args.model.endswith("-tiny")
+            else NemotronHConfig)(**kw)
+    if args.experts_held is not None:
+        import dataclasses
+        if not 0 < args.experts_held <= base.n_routed_experts:
+            raise ValueError(f"--experts_held {args.experts_held} of "
+                             f"{base.n_routed_experts} routed experts")
+        base = dataclasses.replace(
+            base, experts_held=tuple(range(args.experts_held)))
+    model = NemotronH(base)
+    return dict(
+        init_model=model, loss_tr=make_lm_loss(model, train=True),
+        loss_val=make_lm_loss(model, train=False), sample_in=(sample[0],),
+        init_params=None, param_specs=None, gcfg=base,
+        # jitted: an eager init of the published widths would dispatch
+        # every layer's ops one by one
+        init=jax.jit(lambda rng, ids: model.init(rng, ids)))
+
+
+def build_learner(args, cfg, built, sched, mesh=None):
+    """The learner of a model ``_gpt2_model`` / ``_nemotron_model`` built
+    (``--server_mode buffered`` swaps in the FedBuff event-loop learner,
+    federated/buffer.py; mesh-native — under --mesh clients=N its programs
+    shard like the sync round, with the slot buffer partitioned over the
+    axis)."""
     from commefficient_tpu.training.args import learner_factory
     learner_cls, learner_extra = learner_factory(args, cfg.num_clients)
     if learner_cls is not FedLearner and (getattr(args, "scan_rounds", 1)
@@ -302,12 +315,71 @@ def train(args, mesh=None, max_rounds=None, log=True):
         raise ValueError("--scan_rounds > 1 is a sync-mode optimization; "
                          "the buffered server dispatches cohorts through "
                          "a host event loop")
+
+    class _Wrap:
+        """Adapter: FedLearner inits via module.init(rng, x, train=...);
+        these models take a tuple of arrays."""
+
+        def init(self, rng, sample_in, train):
+            return built["init"](rng, *sample_in)
+
+        def apply(self, *a, **k):
+            return built["init_model"].apply(*a, **k)
+
+    return learner_cls(_Wrap(), cfg, built["loss_tr"], built["loss_val"],
+                       jax.random.PRNGKey(args.seed), built["sample_in"],
+                       lr_schedule=sched, mesh=mesh,
+                       init_params=built["init_params"],
+                       param_specs=built["param_specs"], **learner_extra)
+
+
+def train(args, mesh=None, max_rounds=None, log=True):
+    from commefficient_tpu.federated.api import set_transfer_guard
+    set_transfer_guard(getattr(args, "transfer_guard", "disallow"))
+    compile_counters()
+    lm_only = args.model.startswith("nemotron_h")
+    if lm_only != (args.dataset_name == "TOKENS"):
+        raise ValueError(
+            "--dataset_name TOKENS (packed next-token sequences) goes with "
+            "--model nemotron_h / nemotron_h-tiny, the PersonaChat sets "
+            f"with the GPT2 double-heads models; got --model {args.model} "
+            f"--dataset_name {args.dataset_name}")
+    with span("setup.data"):
+        if lm_only:
+            from commefficient_tpu.data.tokens import FedTokens
+            tokenizer = None
+            train_set, val_set = (
+                FedTokens(args.dataset_dir, do_iid=args.do_iid,
+                          num_clients=args.num_clients, train=tr,
+                          seed=args.seed, max_seq_len=args.max_seq_len)
+                for tr in (True, False))
+        else:
+            tokenizer = get_tokenizer(args.model_checkpoint)
+            train_set = make_persona(args, tokenizer, train=True)
+            val_set = make_persona(args, tokenizer, train=False)
+    args.num_clients = train_set.num_clients
+    from commefficient_tpu.parallel.mesh import padded_num_clients
+    num_clients = padded_num_clients(args.num_clients, mesh)
+
+    batcher = FedBatcher(train_set, args.num_workers, args.local_batch_size,
+                         seed=args.seed)
+    spe = batcher.steps_per_epoch()
+    total_steps = max(1, int(args.num_epochs * spe))
+    sched = gpt2_lr_schedule(args.lr_scale, total_steps)
+
+    # init shapes straight from the dataset — materializing a batcher round
+    # here would advance the sampler RNG and change epoch 1's sampling
+    sample = tuple(c[:1] for c in train_set.get_flat_batch(np.arange(1)))
+    cfg = args_to_config(args, num_clients=num_clients,
+                         max_seq_len=args.max_seq_len)
+    if lm_only:
+        built = _nemotron_model(args, mesh, cfg, sample)
+    else:
+        built = _gpt2_model(args, mesh, cfg, tokenizer, sample, log)
+    init_model, gcfg, loss_tr = (built["init_model"], built["gcfg"],
+                                 built["loss_tr"])
     with span("setup.learner"):
-        learner = learner_cls(_Wrap(), cfg, loss_tr, loss_val,
-                              jax.random.PRNGKey(args.seed), sample_in,
-                              lr_schedule=sched, mesh=mesh,
-                              init_params=init_params,
-                              param_specs=param_specs, **learner_extra)
+        learner = build_learner(args, cfg, built, sched, mesh)
 
     # periodic crash-consistent checkpoints + resume (training/preempt.py;
     # this entrypoint never materialized a probe round, so the restored
@@ -319,6 +391,7 @@ def train(args, mesh=None, max_rounds=None, log=True):
     start_epoch = cursor["epoch"] if cursor else 0
     skip0 = cursor["rounds_in_epoch"] if cursor else 0
 
+    counters = getattr(loss_tr, "counters", ())
     table = TableLogger() if log else None
     writer = None
     if getattr(args, "use_tensorboard", False):
@@ -364,6 +437,10 @@ def train(args, mesh=None, max_rounds=None, log=True):
                     return False
                 out = o
                 losses.append(o["loss"])
+                # what the loss counted on the device (per-example metric
+                # rows, fetched with the loss), as the round's growth
+                for name, mean in zip(counters, o["metrics"]):
+                    count(name, float(mean) * o["num_datapoints"])
                 # device guard verdict (round.py): covers NaN and the
                 # nan_threshold breach; a later pipelined round's loss can
                 # look finite again because the guard froze the weights
@@ -396,7 +473,7 @@ def train(args, mesh=None, max_rounds=None, log=True):
                     if check_all(out_w):
                         print("NaN loss; aborting")
                         learner.flush_offload()
-                        return learner, {"aborted": True}
+                        return learner, {"aborted": True, "loss": out["loss"]}
                 else:
                     raw = learner.train_round_async(
                         ids, cols, mask, epoch_frac=total_rounds,
@@ -406,7 +483,7 @@ def train(args, mesh=None, max_rounds=None, log=True):
                     if check(pipe.push(raw)):
                         print("NaN loss; aborting")
                         learner.flush_offload()
-                        return learner, {"aborted": True}
+                        return learner, {"aborted": True, "loss": out["loss"]}
                 at_boundary = (args.do_test or nxt is None
                                or (max_rounds and total_rounds >= max_rounds))
                 if guard.triggered or ckpt.due(total_rounds):
@@ -420,7 +497,7 @@ def train(args, mesh=None, max_rounds=None, log=True):
                                 else check(pipe.flush())):
                             print("NaN loss; aborting")
                             learner.flush_offload()
-                            return learner, {"aborted": True}
+                            return learner, {"aborted": True, "loss": out["loss"]}
                         learner.flush_offload()
                         ckpt.save(epoch, rounds_in_epoch, total_rounds,
                                   in_epoch=True)
@@ -436,7 +513,7 @@ def train(args, mesh=None, max_rounds=None, log=True):
             if (check_all(window.flush()) if window is not None
                     else check(pipe.flush())):
                 print("NaN loss; aborting")
-                return learner, {"aborted": True}  # flushed above
+                return learner, {"aborted": True, "loss": out["loss"]}  # flushed above
             train_time = timer()
             val = learner.evaluate(val_batches(val_set,
                                                args.valid_batch_size))
@@ -457,7 +534,8 @@ def train(args, mesh=None, max_rounds=None, log=True):
                 # ppl is only comparable across runs with the same
                 # tokenizer; the vocab column pins that identity
                 "ppl": float(np.exp(min(nll_tok, 20.0))),
-                "vocab": tokenizer.vocab_size,
+                "vocab": (gcfg.vocab_rows if lm_only
+                          else tokenizer.vocab_size),
                 "mc_acc": float(val["metrics"][0]),
                 "time": train_time,
                 "down (MiB)": learner.total_download_bytes / 2**20,
@@ -489,7 +567,7 @@ def train(args, mesh=None, max_rounds=None, log=True):
         learner.flush_faults()
         row["sim_time"] = learner.sim_time
 
-    if log and not args.do_test:
+    if log and not args.do_test and not lm_only:
         gen_model = init_model
         if gcfg.fused_lm_head:
             # generation needs real logits; params are identical, so
@@ -499,7 +577,10 @@ def train(args, mesh=None, max_rounds=None, log=True):
             ncfg.fused_lm_head = False
             gen_model = GPT2DoubleHeads(ncfg)
         _print_sample(args, gen_model, learner, tokenizer, val_set)
-    if args.do_checkpoint:
+    if args.do_checkpoint and lm_only:
+        from commefficient_tpu.utils.checkpoint import save_checkpoint
+        save_checkpoint(args.checkpoint_path, learner, args.model)
+    elif args.do_checkpoint:
         save_pretrained(args.checkpoint_path, learner, gcfg, tokenizer)
     return learner, row
 
@@ -543,14 +624,27 @@ def build_gpt2_parser():
                              "(gpt2-small d=124M needs the 50,262-row "
                              "table); the extra rows are simply never hit")
     parser.add_argument("--moe_experts", type=int, default=0,
-                        help="Switch-MoE FFN blocks with this many experts "
-                             "(ops/moe.py); 0 = dense MLP. With --mesh "
+                        help="GPT2: Switch-routed (top-1, no capacity, "
+                             "nothing dropped) MoE FFN blocks with this "
+                             "many experts (ops/moe.py); 0 = dense MLP. "
+                             "With --mesh "
                              "...,expert=E the stacked expert weights "
                              "shard over the expert axis")
-    parser.add_argument("--moe_capacity_factor", type=float, default=1.25)
     parser.add_argument("--moe_aux_weight", type=float, default=1e-2,
                         help="weight of the Switch load-balancing aux "
                              "loss added to the training objective")
+    parser.add_argument("--layer_pattern", default=None,
+                        help="nemotron_h: the layers held, one character "
+                             "each (M Mamba-2, E sparse experts, * "
+                             "attention), e.g. a run of the published "
+                             "pattern; default: all of it")
+    parser.add_argument("--experts_held", type=int, default=None,
+                        help="nemotron_h: hold routed experts 0..N-1 of "
+                             "each E layer (a chip's share; the router "
+                             "keeps its width); default: all")
+    parser.add_argument("--vocab_rows", type=int, default=None,
+                        help="nemotron_h: rows of the vocabulary held "
+                             "(ids, logits and loss are over them)")
     parser.add_argument("--pp_microbatches", type=int, default=0,
                         help="GPipe microbatches per pipeline shard for "
                              "--mesh ...,stage=S (parallel/pp.py); 0 = "
@@ -564,9 +658,11 @@ def build_gpt2_parser():
     for a in parser._actions:  # NLP model/dataset names join the CV choices
         if a.dest == "model":
             a.choices = sorted(set(a.choices) |
-                               {"gpt2", "gpt2-tiny", "openai-gpt"})
+                               {"gpt2", "gpt2-tiny", "openai-gpt",
+                                "nemotron_h", "nemotron_h-tiny"})
         if a.dest == "dataset_name":
-            a.choices = sorted(set(a.choices) | {"SyntheticPersona"})
+            a.choices = sorted(set(a.choices) | {"SyntheticPersona",
+                                                 "TOKENS"})
     parser.set_defaults(dataset_name="SyntheticPersona", model="gpt2-tiny",
                         local_batch_size=4, valid_batch_size=4,
                         num_workers=2)

@@ -12,6 +12,8 @@ Always on, bounded, in memory; no flag, no environment variable.
   last change, and the growth of each inside the current round.
 * ``phase(name)`` is the scope for code inside ``jit``; ``op_phases`` reads
   the scopes back from a compiled program, one phase per instruction.
+  ``layer(name)`` is a second level beneath it, for the parts of a model
+  (``layer:ssm_scan`` inside ``phase:client_grad``); ``op_layers`` reads it.
 * ``compile_counters()`` feeds ``compile.*`` from JAX's own monitoring events.
 * ``snapshot()`` is all of it as plain data; ``write(dir)`` leaves it as
   ``spans.json`` (``utils.logging.profile_ctx`` does, beside the device
@@ -32,6 +34,7 @@ import jax
 RING_ROUNDS = 4096
 SPAN_PREFIX = "fed:"
 PHASE_PREFIX = "phase:"
+LAYER_PREFIX = "layer:"
 #: the five phases of the federated round, in program order
 PHASES = ("download_accounting", "client_grad", "reduce", "compress",
           "server_update")
@@ -160,6 +163,14 @@ def phase(name: str):
     return jax.named_scope(PHASE_PREFIX + name)
 
 
+def layer(name: str):
+    """Scope for a part of a model, inside the loss and so inside a
+    ``phase``: a prefix of its own, because an instruction's phase is its
+    innermost ``phase:`` scope and the round's phases have to keep adding
+    up to the round."""
+    return jax.named_scope(LAYER_PREFIX + name)
+
+
 def compile_counters() -> None:
     """Feed ``compile.trace_s``/``lower_s``/``backend_s``/``programs`` and
     ``compile.cache_hits``/``cache_misses`` from ``jax.monitoring``, from
@@ -233,6 +244,7 @@ def reset() -> None:
 _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?(%[^\s(]+)\s*\(.*\{\s*$")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _PHASE = re.compile(re.escape(PHASE_PREFIX) + r"([A-Za-z0-9_]+)")
+_LAYER = re.compile(re.escape(LAYER_PREFIX) + r"([A-Za-z0-9_]+)")
 _CALLED = re.compile(
     r"(?:calls|body|condition|to_apply|branch_computations|"
     r"called_computations|true_computation|false_computation)="
@@ -289,6 +301,23 @@ def op_phases(compiled) -> dict:
     carry; the phase of the ``while``/``call``/``fusion`` that holds its
     computation; for bare data movement (a copy, a layout change), the phase
     of what it reads, else of what reads it; else ``"other"``."""
+    return _op_scopes(compiled, _PHASE)
+
+
+def op_layers(compiled) -> dict:
+    """``{instruction_key: layer}`` by the innermost ``layer:`` scope, as
+    ``op_phases`` by phase, but an instruction outside every layer (the
+    sketch, the server update, the loss's own sums) stays ``"other"``
+    whatever its neighbours carry."""
+    return _op_scopes(compiled, _LAYER)
+
+
+def _op_scopes(compiled, scope) -> dict:
+    # the two rules that are the phases' alone: a collective inside the
+    # client gradient is the reduce, and bare data movement takes its
+    # neighbours' phase (every instruction belongs to some phase; most
+    # belong to no layer)
+    phase_scopes = scope is _PHASE
     text = compiled if isinstance(compiled, str) else compiled.as_text()
     own, comp_of, callees, operands = {}, {}, {}, {}   # by instruction key
     holder, members, by_name = {}, {}, {}     # by computation / %name
@@ -308,9 +337,10 @@ def op_phases(compiled) -> dict:
         by_name[key.partition(" = ")[0]] = key
         operands[key] = re.findall(r"%[^\s,(){}]+", key.partition(" = ")[2])
         names = _OP_NAME.search(line)
-        found = _PHASE.findall(names.group(1)) if names else []
+        found = scope.findall(names.group(1)) if names else []
         own[key] = found[-1] if found else None
-        if own[key] == "client_grad" and _COLLECTIVE.search(line):
+        if (phase_scopes and own[key] == "client_grad"
+                and _COLLECTIVE.search(line)):
             own[key] = "reduce"
         callees[key] = [c for group in _CALLED.findall(line)
                         for c in re.findall(r"%[^\s,{}]+", group)]
@@ -344,8 +374,7 @@ def op_phases(compiled) -> dict:
                 users.setdefault(by_name[name], []).append(key)
     for _ in range(4):                        # data movement: a few hops
         for key in [k for k, p in phases.items() if p is None]:
-            phases[key] = (
-                held(key)
-                or most(phases.get(by_name.get(n)) for n in operands[key])
-                or most(phases[u] for u in users.get(key, ())))
+            phases[key] = held(key) or (phase_scopes and (
+                most(phases.get(by_name.get(n)) for n in operands[key])
+                or most(phases[u] for u in users.get(key, ())))) or None
     return {key: p or OTHER for key, p in phases.items()}

@@ -29,6 +29,29 @@ def test_blockwise_matches_full(causal, T, block):
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("T", [24, 1024])     # full, then blockwise
+def test_grouped_query_attention_matches_repeated_heads(T):
+    from commefficient_tpu.ops.attention import grouped_query_attention
+    k0, k1, k2 = jax.random.split(jax.random.PRNGKey(T), 3)
+    q = jax.random.normal(k0, (2, T, 4, 8))
+    k = jax.random.normal(k1, (2, T, 2, 8))
+    v = jax.random.normal(k2, (2, T, 2, 8))
+
+    def want(q, k, v):
+        return full_attention(q, jnp.repeat(k, 2, axis=2),
+                              jnp.repeat(v, 2, axis=2), causal=True)
+
+    np.testing.assert_allclose(grouped_query_attention(q, k, v),
+                               want(q, k, v), rtol=2e-5, atol=2e-5)
+    g0 = jax.grad(lambda *a: jnp.sum(grouped_query_attention(*a) ** 2),
+                  argnums=(1, 2))(q, k, v)
+    g1 = jax.grad(lambda *a: jnp.sum(want(*a) ** 2), argnums=(1, 2))(q, k, v)
+    for a, b in zip(g0, g1):      # the shared heads' gradients are summed
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4)
+    with pytest.raises(ValueError, match="query heads"):
+        grouped_query_attention(q[:, :, :3], k, v)
+
+
 def test_blockwise_kv_mask_and_padding():
     rng = np.random.RandomState(1)
     q, k, v = _qkv(rng, 2, 40, 2, 8)

@@ -23,6 +23,7 @@ from commefficient_tpu.ops.countsketch import CountSketch
 
 D_RESNET9 = 6_568_640          # ResNet-9, the paper's flagship
 D_GPT2 = 124_440_576           # GPT2-small double-heads
+D_NEMOTRON_CUT = 666_962_944   # benchmarks/configs/nemotron3-nano-30b-a3b.json
 ROWS, COLS, K = 5, 500_000, 50_000   # reference sketch (utils.py:142-145)
 
 
@@ -113,6 +114,56 @@ def test_flash_attention_fwd_bwd_dropout(one_chip):
     text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), one_chip,
                           qkv, qkv, qkv)
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("kernel", ["sketch_vec", "unsketch_select"])
+def test_sketch_kernels_at_the_hybrid_models_cut(one_chip, kernel):
+    """The same two kernels at a d 101 times ResNet-9's (the cell
+    nemotron3-nano-30b-a3b.sketch): the grid grows with d, the table does
+    not."""
+    cs = _sketch(D_NEMOTRON_CUT)
+    if kernel == "sketch_vec":
+        text = _compiled_text(
+            lambda v: sketch_kernels.sketch_vec_pallas(cs, v), one_chip,
+            ((cs.d,), jnp.float32))
+    else:
+        text = _compiled_text(
+            lambda t: topk_kernels.unsketch_select_pallas(cs, t, k=K),
+            one_chip, _table(cs))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("kind", ["M", "E", "*"])
+def test_hybrid_model_layer_fwd_bwd_at_published_widths(one_chip,
+                                                        monkeypatch, kind):
+    """One layer of each kind of models/nemotron_h.py at the published
+    widths and the cell's 8 x 2048 tokens, bfloat16 operands, forward and
+    backward through its rematerialisation: the grouped expert product
+    (``lax.ragged_dot``: a Mosaic kernel on the chip), the chunked scan,
+    grouped-query flash attention."""
+    import dataclasses
+
+    from commefficient_tpu.models.nemotron_h import Block, NemotronHConfig
+    # attention asks the backend whether its kernel can run; this compile is
+    # for the chip, so steer it here (never through an option of the program)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(NemotronHConfig(), pattern=kind,
+                              experts_held=tuple(range(8)),
+                              compute_dtype="bfloat16")
+    block = Block(cfg, kind)
+    x = jax.ShapeDtypeStruct((8, 2048, cfg.hidden_size), jnp.float32,
+                             sharding=one_chip)
+    params = jax.eval_shape(block.init, jax.random.PRNGKey(0), x)["params"]
+    params = jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=one_chip), params)
+
+    def loss(p, x):
+        return jnp.sum(jax.checkpoint(
+            lambda p, x: block.apply({"params": p}, x))(p, x) ** 2)
+
+    compiled = jax.jit(jax.grad(loss)).lower(params, x).compile()
+    assert ("tpu_custom_call" in compiled.as_text()) == (kind != "M")
+    assert compiled.memory_analysis().temp_size_in_bytes < 6e9
 
 
 @pytest.mark.parametrize("op", ["sketch_vec", "estimates"])

@@ -366,7 +366,7 @@ def test_gpt2_ep_federated_round_matches_unsharded(tmp_path):
             ["--mode", "uncompressed", "--error_type", "none",
              "--virtual_momentum", "0.9", "--num_workers", "4",
              "--local_batch_size", "2", "--max_seq_len", "32",
-             "--moe_experts", "4", "--moe_capacity_factor", "100",
+             "--moe_experts", "4",
              "--dataset_name", "SyntheticPersona",
              "--dataset_dir", str(tmp_path / "d"),
              "--synthetic_personas", "8", "--synthetic_dialogs", "2",

@@ -257,6 +257,57 @@ def test_op_phases_rules_on_a_hand_made_module(key):
     assert tracing.op_phases(HLO)[key] == HLO_WANT[key]
 
 
+HLO_LAYERS = """HloModule toy
+
+%fused_computation.2 (p: f32[8]) -> f32[8] {
+  %p = f32[8]{0} parameter(0)
+  ROOT %e = f32[8]{0} exponential(%p), metadata={op_name="jit(f)/phase:client_grad/checkpoint/layer:ssm_scan/exp"}
+}
+
+ENTRY %main (a: f32[8]) -> f32[8] {
+  %a = f32[8]{0} parameter(0)
+  %fusion.2 = f32[8]{0} fusion(%a), kind=kLoop, calls=%fused_computation.2
+  %copy.2 = f32[8]{0} copy(%fusion.2)
+  %d = f32[8]{0} dot(%copy.2, %a), metadata={op_name="jit(f)/phase:client_grad/transpose(jvp(layer:moe_route/layer:moe_experts))/dot"}
+  ROOT %s = f32[8]{0} custom-call(%d), custom_call_target="tpu_custom_call", metadata={op_name="jit(f)/phase:compress/sketch_vec_pallas"}
+}
+"""
+
+HLO_LAYERS_WANT = {
+    "%e = f32[8]{0}(%p)": "ssm_scan",
+    "%fusion.2 = f32[8]{0}(%a)": "ssm_scan",          # what it calls
+    "%copy.2 = f32[8]{0}(%fusion.2)": "other",        # no neighbours' rule
+    "%d = f32[8]{0}(%copy.2, %a)": "moe_experts",     # the innermost scope
+    "%s = f32[8]{0}(%d)": "other",                    # outside the model
+}
+
+
+@pytest.mark.parametrize("key", sorted(HLO_LAYERS_WANT))
+def test_op_layers_rules_on_a_hand_made_module(key):
+    assert tracing.op_layers(HLO_LAYERS)[key] == HLO_LAYERS_WANT[key]
+    # the phases of the same module are as before, layers or not
+    assert tracing.op_phases(HLO_LAYERS)[key] in ("client_grad", "compress")
+
+
+def test_op_layers_finds_every_part_of_the_hybrid_model():
+    from commefficient_tpu.federated.losses import make_lm_loss
+    from commefficient_tpu.models.nemotron_h import (NemotronH,
+                                                     NemotronHConfig)
+    model = NemotronH(NemotronHConfig.tiny())
+    ids = jnp.zeros((2, 16), jnp.int32)
+    params = model.init(jax.random.PRNGKey(0), ids)["params"]
+    loss = make_lm_loss(model, train=True)
+
+    def total(p):
+        return jnp.sum(loss(p, (ids, ids), None, True)[0])
+
+    with tracing.phase("client_grad"):
+        compiled = jax.jit(jax.grad(total)).lower(params).compile()
+    layers = set(tracing.op_layers(compiled).values())
+    assert {"ssm_scan", "ssm_proj", "moe_route", "moe_experts", "moe_shared",
+            "attn", "lm_head"} <= layers
+
+
 @pytest.mark.parametrize("printed, key", [
     # the profiler's print of an instruction and as_text's give one key
     ("%copy.21 = f32[100,50]{1,0:T(8,128)S(1)} copy(f32[100,50]"
