@@ -303,10 +303,12 @@ def sketch_batched_target(mutate: bool = False) -> AuditTarget:
 #: max_live_d budgets per mode, measured on the fused program at HEAD —
 #: zero slack, so re-materializing even one stage of the incumbent
 #: d-vector chain fails. The mutated arms' counts sit strictly above
-#: (18 and 190 vs these 13 and 8 at d=1000, k=5). The sketch arm's 8
+#: (18 and 190 vs these 13 and 5 at d=1000, k=5). The sketch arm's 5
 #: holds no compaction of the select kernel's dense output to
-#: (vals, idxs) and no scatter back: that output is the update.
-_FUSED_SERVER_BUDGETS = {"true_topk": 13, "sketch": 8}
+#: (vals, idxs), no scatter back (that output is the update) and, since
+#: the select pass writes over the one buffer of estimates, no
+#: selection mask.
+_FUSED_SERVER_BUDGETS = {"true_topk": 13, "sketch": 5}
 
 
 def server_update_fused_target(mode: str = "true_topk",

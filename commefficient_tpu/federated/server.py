@@ -125,8 +125,8 @@ def _sketched(sketched_grad, state, cfg, lr, sketch: CountSketch):
     approx = cfg.topk_approx_recall or None
     if cfg.server_fused != "off" and sketch._fused_unsketch_ok(approx, True):
         # The kernels dispatch: unsketch_select_pallas writes the DENSE
-        # masked update (the (d,) estimate vector never materializes —
-        # ops/topk_kernels), so that output is the update, and its
+        # masked update over the buffer its one estimates pass filled
+        # (ops/topk_kernels), so that output is the update, and its
         # re-sketch is one more pass of the dense sketch kernel (the
         # program round.py's aggregate-side sketch already compiles).
         # Compacting it to (vals, idxs) for sketch_sparse would cost a

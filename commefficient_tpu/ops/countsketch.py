@@ -512,9 +512,9 @@ class CountSketch:
             table[None])[0]
 
     def _fused_unsketch_ok(self, approx_recall, use_kernel: bool) -> bool:
-        """Gate for the fused unsketch+top-k kernel (ops/topk_kernels):
-        both the sketch kernel (the estimate stream runs in-VMEM from the
-        table) and the top-k kernel (exact selection only) must dispatch."""
+        """Gate for the fused unsketch+top-k kernels (ops/topk_kernels):
+        both the sketch kernel (its estimates pass writes the stream) and
+        the top-k kernel (exact selection only) must dispatch."""
         from commefficient_tpu.ops.topk_kernels import topk_kernel_ok
         return self._kernel_ok(use_kernel) and topk_kernel_ok(approx_recall)
 
@@ -523,21 +523,21 @@ class CountSketch:
                  approx_recall=None, use_kernel: bool = False) -> jax.Array:
         """Recover the top-k coordinates (dense d-vector, zeros elsewhere).
 
-        With the kernels dispatched this is ONE fused pass: per-tile
-        estimates feed the streaming radix top-k directly from the
-        VMEM-resident table, and the (d,) estimate vector never exists
+        With the kernels dispatched the estimates are written once, by
+        the estimates kernel, into one d-long buffer; the streaming radix
+        top-k counts over it and its select pass overwrites it in place
         (ops/topk_kernels.unsketch_select_pallas — bitwise-identical to
-        the estimates -> topk chain below). That dense output IS the
-        server's update on the fused arm (federated/server._sketched):
-        nothing compacts it to (values, indices). ``approx_recall`` selects
-        with ``lax.approx_max_k`` instead of the exact sort (see
-        ops/topk.py; 5.4x at d=124M, k=50k) and refuses the fusion."""
+        the estimates -> topk chain below, no sort, no selection mask).
+        That dense output IS the server's update on the fused arm
+        (federated/server._sketched): nothing compacts it to (values,
+        indices). ``approx_recall`` selects with ``lax.approx_max_k``
+        instead of the exact sort (see ops/topk.py; 5.4x at d=124M,
+        k=50k) and refuses the fusion."""
         from commefficient_tpu.ops.topk import topk
         if self._fused_unsketch_ok(approx_recall, use_kernel):
             from commefficient_tpu.ops.topk_kernels import \
                 unsketch_select_pallas
-            masked, _ = unsketch_select_pallas(self, table, k=k)
-            return masked
+            return unsketch_select_pallas(self, table, k=k)
         return topk(self.estimates(table, use_kernel), k, approx_recall)
 
     @partial(jax.jit, static_argnums=0)

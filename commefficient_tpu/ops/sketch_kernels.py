@@ -229,6 +229,31 @@ def _estimates_kernel(table_ref, out_ref, win, *, coeffs, nwindows, r,
         out_ref[:, :] = _median(per_row)
 
 
+def _estimates_tiles(cs, table, interp):
+    """One pass of the estimates kernel over ``table``: every coordinate's
+    estimate in the tiled layout the streaming kernels read,
+    ``(n_tiles * TILE_BLOCKS, LANES)`` float32. Rows past ``cs.d`` hold
+    the estimates of block ids no coordinate has (finite garbage):
+    ``estimates_pallas`` slices them off, and
+    ``topk_kernels.unsketch_select_pallas`` streams the array as it is
+    (its score bits send lanes ``>= d`` to the sentinel)."""
+    n_tiles = -(-cs.nblocks // TILE_BLOCKS)
+    return pl.pallas_call(
+        partial(_estimates_kernel, coeffs=cs.coeffs,
+                nwindows=cs.nwindows, r=cs.r, batched=False),
+        grid=(n_tiles,),
+        in_specs=[pl.BlockSpec((cs.r, cs.c_eff), lambda i: (0, 0),
+                               memory_space=pltpu.VMEM)],
+        out_specs=pl.BlockSpec((TILE_BLOCKS, LANES), lambda i: (i, 0),
+                               memory_space=pltpu.VMEM),
+        out_shape=jax.ShapeDtypeStruct((n_tiles * TILE_BLOCKS, LANES),
+                                       jnp.float32),
+        scratch_shapes=[pltpu.VMEM((cs.r, TILE_BLOCKS, LANES),
+                                   jnp.float32)],
+        interpret=interp, name="estimates_pallas",
+    )(table)
+
+
 @partial(jax.jit, static_argnames=("cs", "interpret"))
 def estimates_pallas(cs, table, interpret: bool = False):
     """All-coordinate estimates for a tiled-scheme CountSketch ``cs``.
@@ -242,21 +267,7 @@ def estimates_pallas(cs, table, interpret: bool = False):
     n_tiles = -(-cs.nblocks // TILE_BLOCKS)
 
     def kernel_call(tab):
-        out = pl.pallas_call(
-            partial(_estimates_kernel, coeffs=cs.coeffs,
-                    nwindows=cs.nwindows, r=cs.r, batched=False),
-            grid=(n_tiles,),
-            in_specs=[pl.BlockSpec((cs.r, cs.c_eff), lambda i: (0, 0),
-                                   memory_space=pltpu.VMEM)],
-            out_specs=pl.BlockSpec((TILE_BLOCKS, LANES), lambda i: (i, 0),
-                                   memory_space=pltpu.VMEM),
-            out_shape=jax.ShapeDtypeStruct((n_tiles * TILE_BLOCKS, LANES),
-                                           jnp.float32),
-            scratch_shapes=[pltpu.VMEM((cs.r, TILE_BLOCKS, LANES),
-                                       jnp.float32)],
-            interpret=interp, name="estimates_pallas",
-        )(tab)
-        return out.reshape(-1)[:cs.d]
+        return _estimates_tiles(cs, tab, interp).reshape(-1)[:cs.d]
 
     def batched_call(tabs):
         B = tabs.shape[0]

@@ -27,10 +27,11 @@ with two streaming passes over 8,192-element tiles:
   ``n_take`` ties in flat-index order — a running tie count carried in
   SMEM across grid steps, with the within-tile exclusive rank computed
   by two strict-lower-triangular matmuls (exact: 0/1 operands, counts
-  < 2^24) — and writes ONLY the outputs the round keeps. Three source
+  < 2^24) — and writes ONLY the outputs the round keeps. Two source
   modes are baked in statically:
 
-  - ``plain``    — the stream is the vector itself (ops/topk.py);
+  - ``plain``    — the stream is the vector itself (ops/topk.py, and
+    the sketch server's estimate vector, below);
   - ``resid``    — the true_topk server epilogue: the momentum read
     ``v = g + rho*vvel`` / ``err = verr + v`` runs ONCE in the XLA
     wrapper (recomputing a mul-then-add inside the kernel is not
@@ -39,11 +40,18 @@ with two streaming passes over 8,192-element tiles:
     fuse everything downstream: the masked update AND both
     error-feedback residuals ``where(support, 0, err)`` /
     ``where(support, 0, v)`` emit tile-by-tile, with no sort, no
-    scatter mask and no post-momentum d-vector;
-  - ``est``      — the stream is the CountSketch estimate, computed
-    in-VMEM per tile exactly as ops/sketch_kernels._estimates_kernel
-    (same imported hash/butterfly/median helpers), so unsketch + top-k
-    is one pass over the table with no (d,) estimate vector at all.
+    scatter mask and no post-momentum d-vector.
+
+**Unsketch + top-k** (:func:`unsketch_select_pallas`, the sketch
+server's update) is the ``plain`` program over ONE d-long buffer: a
+single pass of ops/sketch_kernels' estimates kernel writes every
+coordinate's estimate in the tiled layout (five hashes, five window
+gathers, a butterfly and a median a coordinate: 4.6 ms at d = 6.57 M on a
+v5e, sixteen times a plain count pass), the nine counts stream that
+buffer, and the select pass overwrites it in place with the masked
+update (``input_output_aliases``: it reads tile i and writes tile i).
+Recomputing the estimates per tile inside each pass instead costs ten
+estimate passes a round (PERF.md, PR 36).
 
 **Tie-break bit-agreement.** ``jax.lax.top_k`` is stable: equal scores
 are taken in ascending index order. Selecting ties in flat-index order
@@ -86,18 +94,14 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# the SAME dispatch machinery and in-kernel hash/median helpers the
-# sketch kernels use — imported, not copied, so the bit-identity
-# contract between the est-mode stream and CountSketch.estimates is
-# drift-proof by construction
-from commefficient_tpu.ops.sketch_kernels import (LANES, TILE_BLOCKS, _U,
-                                                  _block_hash,
-                                                  _butterfly_xor,
-                                                  _interpret, _signs,
+# the SAME dispatch machinery the sketch kernels use, and their one
+# estimates pass (unsketch_select_pallas streams what it writes)
+from commefficient_tpu.ops.sketch_kernels import (LANES, TILE_BLOCKS,
+                                                  _estimates_tiles,
+                                                  _interpret,
                                                   force_dispatch,
                                                   forced_dispatch,
                                                   kernel_supported)
-from commefficient_tpu.ops.countsketch import _median_small as _median
 
 __all__ = ["topk_kernel_ok", "topk_select_pallas", "fused_true_topk_pallas",
            "unsketch_select_pallas", "values_indices_from_mask",
@@ -148,41 +152,10 @@ def _masked_bits(x, i0, n):
     return jnp.where(idx < n, bits, _SENTINEL)
 
 
-def _est_tile(table_ref, win, i0, *, coeffs, nwindows, r):
-    """One tile of CountSketch estimates, term-for-term the phase-1/2
-    body of sketch_kernels._estimates_kernel (scalar window gathers into
-    the ``win`` scratch, then vectorized butterfly + sign + median) —
-    bit-identical to ``CountSketch.estimates`` per coordinate."""
-    def body(i, carry):
-        blk = _U(i0) * _U(TILE_BLOCKS) + _U(i)
-        for row in range(r):
-            mb, _ = _block_hash(coeffs[row], blk)
-            base = (mb % _U(nwindows)).astype(jnp.int32)
-            win[row, i, :] = table_ref[row, pl.ds(base * LANES, LANES)]
-        return carry
-
-    jax.lax.fori_loop(0, TILE_BLOCKS, body, 0)
-
-    blk_vec = (_U(i0) * _U(TILE_BLOCKS)
-               + jax.lax.broadcasted_iota(_U, (TILE_BLOCKS, LANES), 0))
-    lane = jax.lax.broadcasted_iota(_U, (TILE_BLOCKS, LANES), 1)
-    idx = blk_vec * _U(LANES) + lane
-    per_row = []
-    for row in range(r):
-        _, lanemask = _block_hash(coeffs[row], blk_vec)
-        per_row.append(_butterfly_xor(win[row], lanemask)
-                       * _signs(coeffs[row], idx))
-    return _median(per_row)
-
-
-def _source_tile(refs, i0, *, src, coeffs, nwindows, r, batched, win):
+def _source_tile(refs, *, src, batched):
     """The value stream for one tile, per source mode. Returns
     (selection values, extra outputs-to-mask) — for true_topk the extras
     are (v,) so the epilogue can emit the velocity residual too."""
-    if src == "est":
-        (table_ref,) = refs
-        return _est_tile(table_ref, win, i0, coeffs=coeffs,
-                         nwindows=nwindows, r=r), ()
     if src == "resid":
         # the true_topk epilogue streams (err, v) — computed ONCE by the
         # XLA wrapper with the incumbent's exact multi-use expression
@@ -204,18 +177,10 @@ def _source_tile(refs, i0, *, src, coeffs, nwindows, r, batched, win):
 # pass 1 — counting kernel (one call per radix round)
 # --------------------------------------------------------------------------
 
-def _count_kernel(*refs, n, src, coeffs, nwindows, r, batched):
-    if src == "est":
-        table_ref, cand_ref, out_ref, win = refs
-        srcs = (table_ref,)
-    else:
-        vec_ref, cand_ref, out_ref = refs
-        srcs, win = (vec_ref,), None
+def _count_kernel(vec_ref, cand_ref, out_ref, *, n, batched):
     i0 = pl.program_id(1) if batched else pl.program_id(0)
 
-    vals, _ = _source_tile(srcs, i0, src=src, coeffs=coeffs,
-                           nwindows=nwindows, r=r, batched=batched, win=win)
-    bits = _masked_bits(vals, i0, n)
+    bits = _masked_bits(vec_ref[0] if batched else vec_ref[...], i0, n)
 
     # counts accumulate in SMEM across the sequential grid; zero them as
     # each (batch row's) first tile comes in
@@ -230,15 +195,13 @@ def _count_kernel(*refs, n, src, coeffs, nwindows, r, batched):
                                                 .astype(jnp.int32))
 
 
-def _count_call(streams, cands, *, n, n_tiles, interp, src,
-                cs=None, batched=False):
-    kern = partial(_count_kernel, n=n, src=src,
-                   coeffs=None if cs is None else cs.coeffs,
-                   nwindows=0 if cs is None else cs.nwindows,
-                   r=0 if cs is None else cs.r, batched=batched)
+def _count_call(vec, cands, *, n, n_tiles, interp, batched=False):
+    """Counts of ``bits >= cand`` for 16 candidates over the tiled ``vec``
+    (the vector itself, the true_topk error, the sketch server's
+    estimates)."""
+    kern = partial(_count_kernel, n=n, batched=batched)
     cand_smem = dict(memory_space=pltpu.SMEM)
     if batched:
-        assert src == "plain", "only the plain stream has a batched grid"
         B = cands.shape[0]
         return pl.pallas_call(
             kern, grid=(B, n_tiles),
@@ -250,25 +213,17 @@ def _count_call(streams, cands, *, n, n_tiles, interp, src,
             out_specs=pl.BlockSpec((1, _NIBBLES), lambda b, i: (b, 0),
                                    **cand_smem),
             out_shape=jax.ShapeDtypeStruct((B, _NIBBLES), jnp.int32),
-            interpret=interp, name=COUNT_KERNEL_NAME)(*streams, cands)
-    if src == "est":
-        in_specs = [pl.BlockSpec((cs.r, cs.c_eff), lambda i: (0, 0),
-                                 memory_space=pltpu.VMEM)]
-        scratch = [pltpu.VMEM((cs.r, TILE_BLOCKS, LANES), jnp.float32)]
-    else:
-        in_specs = [pl.BlockSpec((TILE_BLOCKS, LANES), lambda i: (i, 0),
-                                 memory_space=pltpu.VMEM)] * len(streams)
-        scratch = []
-    in_specs.append(pl.BlockSpec((1, _NIBBLES), lambda i: (0, 0),
-                                 **cand_smem))
+            interpret=interp, name=COUNT_KERNEL_NAME)(vec, cands)
     out = pl.pallas_call(
         kern, grid=(n_tiles,),
-        in_specs=in_specs,
+        in_specs=[pl.BlockSpec((TILE_BLOCKS, LANES), lambda i: (i, 0),
+                               memory_space=pltpu.VMEM),
+                  pl.BlockSpec((1, _NIBBLES), lambda i: (0, 0),
+                               **cand_smem)],
         out_specs=pl.BlockSpec((1, _NIBBLES), lambda i: (0, 0), **cand_smem),
         out_shape=jax.ShapeDtypeStruct((1, _NIBBLES), jnp.int32),
-        scratch_shapes=scratch,
         interpret=interp,
-        name=COUNT_KERNEL_NAME)(*streams, cands.reshape(1, _NIBBLES))
+        name=COUNT_KERNEL_NAME)(vec, cands.reshape(1, _NIBBLES))
     return out.reshape(_NIBBLES)
 
 
@@ -360,26 +315,20 @@ def _tile_select(bits, t, ntake, carry, i0):
     return gt | (eq & (rank < ntake))
 
 
-def _select_kernel(*refs, n, src, coeffs, nwindows, r, batched,
-                   with_mask):
-    if src == "est":
-        table_ref, t_ref, take_ref, out_ref, mask_ref, carry, win = refs
-        srcs = (table_ref,)
-    elif src == "resid":
+def _select_kernel(*refs, n, src, batched, with_mask):
+    if src == "resid":
         (err_ref, v_ref, t_ref, take_ref,
          upd_ref, nv_ref, ne_ref, carry) = refs
-        srcs, win = (err_ref, v_ref), None
+        srcs = (err_ref, v_ref)
     elif with_mask:
         vec_ref, t_ref, take_ref, out_ref, mask_ref, carry = refs
-        srcs, win = (vec_ref,), None
+        srcs = (vec_ref,)
     else:
         vec_ref, t_ref, take_ref, out_ref, carry = refs
-        srcs, win = (vec_ref,), None
+        srcs = (vec_ref,)
     i0 = pl.program_id(1) if batched else pl.program_id(0)
 
-    vals, extras = _source_tile(srcs, i0, src=src, coeffs=coeffs,
-                                nwindows=nwindows, r=r, batched=batched,
-                                win=win)
+    vals, extras = _source_tile(srcs, src=src, batched=batched)
     bits = _masked_bits(vals, i0, n)
     sel = _tile_select(bits, t_ref[0, 0], take_ref[0, 0], carry, i0)
 
@@ -402,20 +351,20 @@ def _select_kernel(*refs, n, src, coeffs, nwindows, r, batched,
         store(ne_ref, jnp.where(supp, 0.0, err))
     else:
         store(out_ref, jnp.where(sel, vals, 0.0))
-        if src == "est" or with_mask:
+        if with_mask:
             store(mask_ref, sel.astype(jnp.int32))
 
 
 def _select_call(streams, t, take, *, n, n_tiles, interp, src, name,
-                 cs=None, batched=False, with_mask=False):
-    kern = partial(_select_kernel, n=n, src=src,
-                   coeffs=None if cs is None else cs.coeffs,
-                   nwindows=0 if cs is None else cs.nwindows,
-                   r=0 if cs is None else cs.r, batched=batched,
+                 batched=False, with_mask=False, in_place=False):
+    """``in_place`` (unbatched grid only) gives the first stream's buffer
+    to the first output — the kernel reads tile i before it writes tile
+    i, and no tile is read twice: for a caller whose stream is a
+    temporary of its own."""
+    kern = partial(_select_kernel, n=n, src=src, batched=batched,
                    with_mask=with_mask)
     rows = n_tiles * TILE_BLOCKS
-    n_out = 3 if src == "resid" else (2 if src == "est" or with_mask
-                                      else 1)
+    n_out = 3 if src == "resid" else (2 if with_mask else 1)
     out_dtypes = ([jnp.float32] * 3 if src == "resid"
                   else [jnp.float32, jnp.int32][:n_out])
     smem = dict(memory_space=pltpu.SMEM)
@@ -438,21 +387,14 @@ def _select_call(streams, t, take, *, n, n_tiles, interp, src, name,
     tile = pl.BlockSpec((TILE_BLOCKS, LANES), lambda i: (i, 0),
                         memory_space=pltpu.VMEM)
     scalar = pl.BlockSpec((1, 1), lambda i: (0, 0), **smem)
-    if src == "est":
-        in_specs = [pl.BlockSpec((cs.r, cs.c_eff), lambda i: (0, 0),
-                                 memory_space=pltpu.VMEM)]
-        scratch = [pltpu.SMEM((1, 1), jnp.int32),
-                   pltpu.VMEM((cs.r, TILE_BLOCKS, LANES), jnp.float32)]
-    else:
-        in_specs = [tile] * len(streams)
-        scratch = [pltpu.SMEM((1, 1), jnp.int32)]
     outs = pl.pallas_call(
         kern, grid=(n_tiles,),
-        in_specs=in_specs + [scalar, scalar],
+        in_specs=[tile] * len(streams) + [scalar, scalar],
         out_specs=[tile] * n_out,
         out_shape=[jax.ShapeDtypeStruct((rows, LANES), dt)
                    for dt in out_dtypes],
-        scratch_shapes=scratch,
+        scratch_shapes=[pltpu.SMEM((1, 1), jnp.int32)],
+        input_output_aliases={0: 0} if in_place else {},
         interpret=interp,
         name=name)(*streams, t.reshape(1, 1), take.reshape(1, 1))
     return tuple(o.reshape(-1)[:n] for o in outs)
@@ -567,8 +509,8 @@ def topk_select_pallas(vec, kk, *, k, with_mask=False, interpret=False):
         vp = jnp.pad(v, (0, n_tiles * TILE_N - n)).reshape(
             n_tiles * TILE_BLOCKS, LANES)
         t, ntake = _radix_threshold(
-            lambda cands: _count_call((vp,), cands, n=n, n_tiles=n_tiles,
-                                      interp=interp, src="plain"), kk_)
+            lambda cands: _count_call(vp, cands, n=n, n_tiles=n_tiles,
+                                      interp=interp), kk_)
         outs = _select_call((vp,), t, ntake, n=n, n_tiles=n_tiles,
                             interp=interp, src="plain", with_mask=with_mask,
                             name="topk_select_pallas")
@@ -583,9 +525,8 @@ def topk_select_pallas(vec, kk, *, k, with_mask=False, interpret=False):
         vp = jnp.pad(vs, ((0, 0), (0, n_tiles * TILE_N - n))).reshape(
             B, n_tiles * TILE_BLOCKS, LANES)
         t, ntake = _radix_threshold_batched(
-            lambda cands: _count_call((vp,), cands, n=n, n_tiles=n_tiles,
-                                      interp=interp, src="plain",
-                                      batched=True), kks)
+            lambda cands: _count_call(vp, cands, n=n, n_tiles=n_tiles,
+                                      interp=interp, batched=True), kks)
         outs = _select_call((vp,), t, ntake, n=n, n_tiles=n_tiles,
                             interp=interp, src="plain", batched=True,
                             with_mask=with_mask, name="topk_select_pallas")
@@ -626,8 +567,8 @@ def fused_true_topk_pallas(gradient, vvelocity, verror, *, k, rho,
 
         errp, vp = pad(err), pad(v)
         t, ntake = _radix_threshold(
-            lambda cands: _count_call((errp,), cands, n=n, n_tiles=n_tiles,
-                                      interp=interp, src="plain"),
+            lambda cands: _count_call(errp, cands, n=n, n_tiles=n_tiles,
+                                      interp=interp),
             jnp.int32(k))
         return _select_call((errp, vp), t, ntake, n=n, n_tiles=n_tiles,
                             interp=interp, src="resid",
@@ -637,32 +578,39 @@ def fused_true_topk_pallas(gradient, vvelocity, verror, *, k, rho,
                                                  verror)
 
 
-@partial(jax.jit, static_argnames=("cs", "k", "interpret"))
-def unsketch_select_pallas(cs, table, *, k, interpret=False):
-    """Fused unsketch + exact top-k for a tiled CountSketch ``cs``:
-    per-tile estimates (bit-identical to ``cs.estimates``) feed the
-    radix threshold and the select epilogue directly from the
-    VMEM-resident table — the (d,) estimate vector never exists.
-    Returns ``(masked_estimates, int32 selection mask)``; requires
-    ``sketch_kernels.kernel_supported(cs)`` (callers gate). Any vmapped
-    call maps the bitwise XLA chain."""
+@partial(jax.jit, static_argnames=("cs", "k", "with_mask", "interpret"))
+def unsketch_select_pallas(cs, table, *, k, with_mask=False,
+                           interpret=False):
+    """Fused unsketch + exact top-k for a tiled CountSketch ``cs``: one
+    pass of the estimates kernel writes all d estimates (bit-identical to
+    ``cs.estimates``) into one d-long buffer in the tiled layout, the
+    radix threshold's nine counts stream it, and the select pass
+    overwrites it in place with the masked estimates — the update; one
+    d-long buffer lives through the call. ``with_mask`` also returns the
+    int32 selection mask, a second d-long output, for a caller that
+    compacts to (values, indices); the sketch server does not ask.
+    Requires ``sketch_kernels.kernel_supported(cs)`` (callers gate). Any
+    vmapped call maps the bitwise XLA chain."""
     assert kernel_supported(cs), "unsketch kernel needs a supported sketch"
     interp = _interpret(interpret)
     n = cs.d
     n_tiles = -(-cs.nblocks // TILE_BLOCKS)
 
     def kernel_call(tab):
+        # padded as the kernel wrote it: no slice to d and no pad between
+        # the passes (_masked_bits sends lanes >= d to the sentinel)
+        est = _estimates_tiles(cs, tab, interp)
         t, ntake = _radix_threshold(
-            lambda cands: _count_call((tab,), cands, n=n, n_tiles=n_tiles,
-                                      interp=interp, src="est", cs=cs),
-            jnp.int32(k))
-        return _select_call((tab,), t, ntake, n=n, n_tiles=n_tiles,
-                            interp=interp, src="est", cs=cs,
-                            name="unsketch_select_pallas")
+            lambda cands: _count_call(est, cands, n=n, n_tiles=n_tiles,
+                                      interp=interp), jnp.int32(k))
+        outs = _select_call((est,), t, ntake, n=n, n_tiles=n_tiles,
+                            interp=interp, src="plain", with_mask=with_mask,
+                            in_place=True, name="unsketch_select_pallas")
+        return outs if with_mask else outs[0]
 
     def fallback(tab):
         est = cs.estimates(tab, use_kernel=False)
-        return _mask_fallback(est, jnp.int32(k), k, with_mask=True)
+        return _mask_fallback(est, jnp.int32(k), k, with_mask=with_mask)
 
     return _guard_fallback_only(kernel_call, fallback)(table)
 

@@ -203,3 +203,20 @@ def trace_round():
             tuple(stack(c) for c in cols), stack(m),
             jnp.zeros((K,), jnp.float32), jnp.stack([rng] * K))
     return trace
+
+
+@pytest.fixture
+def planted_table():
+    """``plant(cs, rng, n=2000)``: a table whose estimates hold fewer than
+    k nonzeros, many of equal magnitude: the top-k then fills up with zero
+    estimates in index order, about half of them -0.0 (a negative sign
+    times 0.0)."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    def plant(cs, rng, n=2000):
+        vec = np.zeros(cs.d, np.float32)
+        at = rng.choice(cs.d, n, replace=False)
+        vec[at] = rng.choice(np.float32([0.5, -0.5, 1.25, -1.25, 3.0]), n)
+        return cs.sketch_vec(jnp.asarray(vec))
+    return plant

@@ -99,15 +99,17 @@ def test_sketch_batched_audit_fails_under_forced_fallback():
 
 
 @pytest.mark.parametrize("idx,mode,kernels", [(0, "true_topk", 3),
-                                              (1, "sketch", 4)])
+                                              (1, "sketch", 5)])
 def test_server_update_fused_audit_passes_with_retrace(audited, idx, mode,
                                                        kernels):
     """The ISSUE-20 fused server update: the streaming radix/select
     pallas_calls are in the traced program, no top_k/sort runs over the
     d-stream, the live-(d,) output count sits at the fused budget, and
     the compile cache stays at 1 across drives under
-    force_dispatch('kernel'). Sketch mode holds one kernel more: the
-    dense re-sketch of the update (sketch_vec_pallas)."""
+    force_dispatch('kernel'). Sketch mode holds two kernels more: the
+    one estimates pass the counts and the select stream
+    (estimates_pallas), and the dense re-sketch of the update
+    (sketch_vec_pallas)."""
     rep = audited("server_update_fused", idx, with_retrace=True)
     assert rep.target == f"server_update_fused/{mode}"
     assert rep.ok, rep.format()

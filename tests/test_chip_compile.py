@@ -52,10 +52,14 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compiled_text(fn, one_chip, *shapes_dtypes):
+def _compiled(fn, one_chip, *shapes_dtypes):
     args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
             for shape, dtype in shapes_dtypes]
-    return jax.jit(fn).lower(*args).compile().as_text()
+    return jax.jit(fn).lower(*args).compile()
+
+
+def _compiled_text(fn, one_chip, *shapes_dtypes):
+    return _compiled(fn, one_chip, *shapes_dtypes).as_text()
 
 
 def _sketch(d):
@@ -83,11 +87,23 @@ def test_estimates_resnet9(one_chip):
 @pytest.mark.parametrize("d", [D_RESNET9, D_GPT2],
                          ids=["resnet9", "gpt2_small"])
 def test_unsketch_select(one_chip, d):
+    from commefficient_tpu.utils import tracing
     cs = _sketch(d)
-    text = _compiled_text(
-        lambda t: topk_kernels.unsketch_select_pallas(cs, t, k=K),
-        one_chip, _table(cs))
-    assert "tpu_custom_call" in text
+
+    def in_the_servers_phase(t):
+        with tracing.phase("server_update"):
+            return topk_kernels.unsketch_select_pallas(cs, t, k=K)
+
+    compiled = _compiled(in_the_servers_phase, one_chip, _table(cs))
+    text = compiled.as_text()
+    # one estimates pass, the loop's count, the final count, the select:
+    # under these names a device trace is read, in this phase
+    for name in ("estimates_pallas", "radix_count_pallas",
+                 "unsketch_select_pallas"):
+        assert f"%{name}" in text, name
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    assert {phase for key, phase in tracing.op_phases(compiled).items()
+            if key.startswith("%estimates_pallas")} == {"server_update"}
 
 
 @pytest.mark.parametrize("d", [D_RESNET9, D_GPT2],
@@ -127,9 +143,18 @@ def test_sketch_kernels_at_the_hybrid_models_cut(one_chip, kernel):
             lambda v: sketch_kernels.sketch_vec_pallas(cs, v), one_chip,
             ((cs.d,), jnp.float32))
     else:
-        text = _compiled_text(
+        compiled = _compiled(
             lambda t: topk_kernels.unsketch_select_pallas(cs, t, k=K),
             one_chip, _table(cs))
+        text = compiled.as_text()
+        # the call's temporaries: the estimates in the tiled layout, once
+        # (the select pass writes over them; the output is their slice to
+        # d). The parent of PR 36 held two d-long outputs of its select
+        # pass here: 5 335 809 024 bytes.
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        one_buffer = -(-cs.d // topk_kernels.TILE_N) * topk_kernels.TILE_N * 4
+        assert temp <= 5_335_809_024
+        assert one_buffer <= temp < one_buffer + (1 << 20)
     assert "tpu_custom_call" in text
 
 

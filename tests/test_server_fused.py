@@ -172,8 +172,8 @@ def test_het_k_round_trajectory_bitwise(force):
 
 def _sketch_server_jaxpr(arm, d=3000, k=7):
     """Walk the traced sketch-mode ``server_update`` of one arm:
-    (pallas_call names, jitted callees' names, [(primitive, operand
-    size)] of every cumsum / sort / top_k / scatter)."""
+    (pallas_call equations, jitted callees' names, [(primitive, operand
+    size)] of every cumsum / sort / top_k / scatter, the table's shape)."""
     from commefficient_tpu.analysis.walker import walk
     from commefficient_tpu.federated.server import (init_server_opt_state,
                                                     make_sketch,
@@ -197,7 +197,7 @@ def _sketch_server_jaxpr(arm, d=3000, k=7):
     for site in sites:
         prim, params = site.primitive, site.eqn.params
         if prim == "pallas_call":
-            kernels.append(params["name"])
+            kernels.append(site.eqn)
         elif prim in ("pjit", "jit"):
             callees.append(params["name"])
         elif (prim in ("cumsum", "sort", "top_k")
@@ -205,47 +205,56 @@ def _sketch_server_jaxpr(arm, d=3000, k=7):
             movers.append((prim, max(
                 int(np.prod(v.aval.shape)) for v in site.eqn.invars
                 if hasattr(v.aval, "shape"))))
-    return kernels, callees, movers
+    return kernels, callees, movers, cfg.transmit_shape
 
 
 @pytest.mark.parametrize("arm", ["kernel", "fallback", "off"])
 def test_sketch_server_update_arms_in_jaxpr(arm):
-    """Where the fused unsketch dispatches, the sketch server takes the
-    select kernel's dense output as the update and one more pass of the
-    dense sketch kernel as its support: no d-long cumsum / sort /
-    scatter (the compaction to (vals, idxs) and the scatter back are
-    gone), no sparse re-sketch.  Everywhere else no kernel runs and the
-    ``lax.top_k`` -> scatter -> ``sketch_sparse`` chain stands."""
+    """Where the fused unsketch dispatches, the sketch server estimates
+    the d coordinates once, counts and selects over that one buffer,
+    takes the select kernel's dense output as the update and one more
+    pass of the dense sketch kernel as its support: the table feeds one
+    kernel, nothing pads or slices the estimates on their way to the
+    select (which overwrites them and writes no mask), and there is no
+    d-long cumsum / sort / scatter and no sparse re-sketch.  Everywhere
+    else no kernel runs and the ``lax.top_k`` -> scatter ->
+    ``sketch_sparse`` chain stands."""
     d = 3000
-    kernels, callees, movers = _sketch_server_jaxpr(arm, d=d)
+    kernels, callees, movers, table_shape = _sketch_server_jaxpr(arm, d=d)
     d_long = [(p, n) for p, n in movers if n >= d]
-    if arm == "kernel":
-        # the radix loop's body and the final count are one eqn each
-        assert sorted(kernels) == ["radix_count_pallas",
-                                   "radix_count_pallas",
-                                   "sketch_vec_pallas",
-                                   "unsketch_select_pallas"], kernels
-        assert not d_long, d_long
-        assert "sketch_sparse" not in callees
-    else:
+    if arm != "kernel":
         assert not kernels, kernels
         assert "sketch_sparse" in callees
         assert ("top_k", d) in d_long, movers
         assert any(p.startswith("scatter") for p, _ in d_long), movers
-
-
-def _planted_table(cs, rng):
-    """A table whose estimates hold fewer than k nonzeros, many of equal
-    magnitude: the top-k then fills up with zero estimates in index
-    order, about half of them -0.0 (a negative sign times 0.0)."""
-    vec = np.zeros(cs.d, np.float32)
-    at = rng.choice(cs.d, 2000, replace=False)
-    vec[at] = rng.choice(np.float32([0.5, -0.5, 1.25, -1.25, 3.0]), 2000)
-    return cs.sketch_vec(jnp.asarray(vec))
+        return
+    names = [e.params["name"] for e in kernels]
+    # the radix loop's body and the final count are one eqn each
+    assert sorted(names) == ["estimates_pallas", "radix_count_pallas",
+                             "radix_count_pallas", "sketch_vec_pallas",
+                             "unsketch_select_pallas"], names
+    assert not d_long, d_long
+    assert "sketch_sparse" not in callees
+    reads_table = [e.params["name"] for e in kernels
+                   if any(v.aval.shape == table_shape for v in e.invars)]
+    assert reads_table == ["estimates_pallas"]
+    by_name = {e.params["name"]: e for e in kernels}
+    (est,) = by_name["estimates_pallas"].outvars
+    assert est.aval.size >= d and est.aval.dtype == jnp.float32
+    # the very array the estimates pass wrote: no pad, no slice between
+    select = by_name["unsketch_select_pallas"]
+    assert select.invars[0] is est
+    assert [v.aval.shape for v in select.outvars] == [est.aval.shape]
+    assert tuple(select.params["input_output_aliases"]) == ((0, 0),)
+    counts = [e for e in kernels
+              if e.params["name"] == "radix_count_pallas"]
+    assert sum(e.invars[0] is est for e in counts) == 1   # the final count
+    assert all(e.invars[0].aval.shape == est.aval.shape for e in counts)
 
 
 @pytest.mark.parametrize("seed", [0, 1, 2, "planted"])
-def test_dense_resketch_support_equals_sparse_at_cell_density(seed):
+def test_dense_resketch_support_equals_sparse_at_cell_density(seed,
+                                                             planted_table):
     """The fused arm's two substitutions at the sketch cell's density
     (k/c = 0.1, r = 5), through the kernels themselves: the select
     kernel's dense output is bitwise ``zeros(d).at[idxs].set(vals)``,
@@ -261,7 +270,7 @@ def test_dense_resketch_support_equals_sparse_at_cell_density(seed):
     planted = seed == "planted"
     rng = np.random.RandomState(7 if planted else seed)
     if planted:
-        table = _planted_table(cs, rng)
+        table = planted_table(cs, rng)
     else:
         table = cs.sketch_vec(jnp.asarray(rng.randn(d).astype(np.float32)))
 

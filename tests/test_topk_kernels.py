@@ -135,28 +135,50 @@ def test_fused_true_topk_ties_and_selected_zero_residuals():
                                       err_msg=nm)
 
 
-def test_unsketch_select_bit_identical_to_estimates_then_topk():
-    """est-mode: the in-kernel per-tile estimate stream + select must
-    equal CountSketch.estimates -> masked top-k bitwise, mask included —
-    the (d,) estimate vector the kernel never materializes."""
-    d, c, r, k = 9_000, 512, 3, 40
+@pytest.mark.parametrize("with_mask", [False, True],
+                         ids=["dense", "with_mask"])
+@pytest.mark.parametrize("d", [5_000, 9_000, 16_384, "planted"],
+                         ids=["one_tile", "9000", "two_whole_tiles",
+                              "planted"])
+def test_unsketch_select_bit_identical_to_estimates_then_topk(
+        d, with_mask, planted_table):
+    """One estimates pass, the counts over what it wrote and the in-place
+    select must equal CountSketch.estimates -> masked top-k bitwise (the
+    mask too, for a caller that asks): with a tail tile whose lanes past
+    d hold estimates of blocks no coordinate has, with d a whole number
+    of tiles, and where the top-k fills up with tied and zero estimates,
+    -0.0 among them."""
+    planted = d == "planted"
+    d, c, r, k = (20_000, 2_048, 5, 200) if planted else (d, 512, 3, 40)
     cs = CountSketch(d=d, c=c, r=r, seed=5, scheme="tiled")
     rng = np.random.RandomState(4)
-    vec = np.zeros(d, np.float32)
-    hot = rng.choice(d, 60, replace=False)
-    vec[hot] = rng.randn(60).astype(np.float32) * 10
-    table = cs.sketch_vec(vec)
+    if planted:
+        table = planted_table(cs, rng, n=40)
+    else:
+        vec = np.zeros(d, np.float32)
+        hot = rng.choice(d, 60, replace=False)
+        vec[hot] = rng.randn(60).astype(np.float32) * 10
+        table = cs.sketch_vec(vec)
     est = cs.estimates(table, use_kernel=False)
     ref_masked, ref_mask = jax.jit(
         lambda e: tk._mask_fallback(e, jnp.int32(k), k, with_mask=True))(est)
+    if planted:
+        picked = np.asarray(ref_masked)[np.asarray(ref_mask) != 0]
+        assert np.signbit(picked[picked == 0]).any()
+        assert np.unique(np.abs(picked[picked != 0])).size < 10
     for mode in ("kernel", "fallback"):
         with tk.force_dispatch(mode):
-            got_masked, got_mask = tk.unsketch_select_pallas(
-                cs, table, k=k, interpret=True)
-        np.testing.assert_array_equal(np.asarray(got_masked),
-                                      np.asarray(ref_masked), err_msg=mode)
-        np.testing.assert_array_equal(np.asarray(got_mask),
-                                      np.asarray(ref_mask), err_msg=mode)
+            got = tk.unsketch_select_pallas(cs, table, k=k,
+                                            with_mask=with_mask,
+                                            interpret=True)
+        got_masked, got_mask = got if with_mask else (got, None)
+        np.testing.assert_array_equal(
+            np.asarray(got_masked).view(np.uint32),
+            np.asarray(ref_masked).view(np.uint32), err_msg=mode)
+        if with_mask:
+            np.testing.assert_array_equal(np.asarray(got_mask),
+                                          np.asarray(ref_mask),
+                                          err_msg=mode)
 
 
 def test_values_indices_from_mask_restores_exact_topk_order():
@@ -307,3 +329,71 @@ def test_topk_row_k_matches_per_row_masking():
     with tk.force_dispatch("kernel"):
         got_k = np.asarray(topk(mat, kmax, row_k=row_k))
     np.testing.assert_array_equal(got_k, ref)
+
+
+def _pallas_programs(fn, *args):
+    """[(name, grid, block shapes, scratch shapes, output shapes, kernel
+    body length)] of every pallas_call in ``fn``'s kernel-arm jaxpr."""
+    from commefficient_tpu.analysis.walker import iter_eqns
+    with tk.force_dispatch("kernel"):
+        jaxpr = jax.make_jaxpr(fn)(*args)
+    out = []
+    for site in iter_eqns(jaxpr):
+        if site.primitive != "pallas_call":
+            continue
+        p, gm = site.eqn.params, site.eqn.params["grid_mapping"]
+        assert not p["input_output_aliases"]
+        out.append((
+            p["name"], tuple(gm.grid),
+            [tuple(b.block_size for b in bm.block_shape)
+             for bm in gm.block_mappings],
+            [a.shape for a in gm.scratch_avals],
+            [o.shape for o in p["out_avals"]], len(p["jaxpr"].eqns)))
+    return out
+
+
+_TILE, _CANDS, _ONE = (64, 128), (1, 16), (1, 1)
+_COUNT = ("radix_count_pallas", (3,), [_TILE, _CANDS, _CANDS], [],
+          [(1, 16)], 127)
+_BCOUNT = ("radix_count_pallas", (3, 3), [(1,) + _TILE, _CANDS, _CANDS], [],
+           [(3, 16)], 127)
+#: read off the parent of PR 36 (which took the in-VMEM estimate stream
+#: out of the kernels these programs share) at d = 20 000, k = 50
+SHARED_PROGRAMS = {
+    "plain": [_COUNT, _COUNT,
+              ("topk_select_pallas", (3,), [_TILE, _ONE, _ONE, _TILE],
+               [_ONE], [(192, 128)], 45)],
+    "plain_with_mask": [_COUNT, _COUNT,
+                        ("topk_select_pallas", (3,),
+                         [_TILE, _ONE, _ONE, _TILE, _TILE], [_ONE],
+                         [(192, 128)] * 2, 47)],
+    "batched": [_BCOUNT, _BCOUNT,
+                ("topk_select_pallas", (3, 3),
+                 [(1,) + _TILE, _ONE, _ONE, (1,) + _TILE], [_ONE],
+                 [(3, 192, 128)], 45)],
+    "true_topk": [_COUNT, _COUNT,
+                  ("fused_true_topk_pallas", (3,),
+                   [_TILE, _TILE, _ONE, _ONE, _TILE, _TILE, _TILE], [_ONE],
+                   [(192, 128)] * 3, 52)],
+}
+
+
+@pytest.mark.parametrize("program", sorted(SHARED_PROGRAMS))
+def test_programs_that_share_the_kernels_are_as_they_were(program):
+    """``topk_select_pallas`` and ``fused_true_topk_pallas`` share
+    ``_count_kernel`` / ``_select_kernel`` / ``_tile_select`` with the
+    sketch server's unsketch: an edit to that path must leave their
+    pallas_calls as they are — grid, blocks, scratch, outputs, name, the
+    kernel body's length, and no aliasing."""
+    d, k = 20_000, 50
+    v = jnp.zeros((d,), jnp.float32)
+    fn, args = {
+        "plain": (lambda x: tk.topk_select_pallas(x, k, k=k), (v,)),
+        "plain_with_mask": (lambda x: tk.topk_select_pallas(
+            x, k, k=k, with_mask=True), (v,)),
+        "batched": (jax.vmap(lambda x, kk: tk.topk_select_pallas(
+            x, kk, k=k)), (jnp.zeros((3, d)), jnp.arange(5, 8))),
+        "true_topk": (lambda g, a, b: tk.fused_true_topk_pallas(
+            g, a, b, k=k, rho=0.9), (v, v, v)),
+    }[program]
+    assert _pallas_programs(fn, *args) == SHARED_PROGRAMS[program]

@@ -175,7 +175,10 @@ def eqn_phases(jaxpr, inherited=""):
             out += eqn_phases(sub, stack)
         if not subs:
             found = re.findall(r"phase:([a-z_]+)", stack)
-            out.append((found[-1] if found else None, eqn.primitive.name))
+            prim = eqn.primitive.name
+            if prim == "pallas_call":
+                prim += ":" + eqn.params["name"]
+            out.append((found[-1] if found else None, prim))
     return out
 
 
@@ -339,8 +342,13 @@ def test_every_pallas_call_of_the_round_is_named(mode):
              if site.primitive == "pallas_call"]
     assert names and set(names) <= KERNEL_NAMES
     if mode == "sketch":
-        assert set(names) == {"sketch_vec_pallas", "radix_count_pallas",
+        assert set(names) == {"sketch_vec_pallas", "estimates_pallas",
+                              "radix_count_pallas",
                               "unsketch_select_pallas"}
+        # the server's estimate pass lies in its phase (the compiled
+        # call, by op_phases: tests/test_chip_compile.py)
+        assert ("server_update", "pallas_call:estimates_pallas") in (
+            eqn_phases(jaxpr.jaxpr))
 
 
 # ------------------------------------------------------ spans on the path
