@@ -176,28 +176,32 @@ def make_gpt2_val_loss(model):
     return apply_loss
 
 
-#: the per-example metric rows of ``make_lm_loss``'s training loss, as the
-#: counters ``training/gpt2.py`` adds their round totals to
-LM_TRAIN_COUNTERS = ("moe.assignments_held", "moe.assignments_fullest",
-                     "moe.dropped")
-
-
 def make_lm_loss(model, train: bool, chunk: int = 8192):
-    """Next-token cross-entropy of a language model with no other head
-    (``models/nemotron_h.py``): ``model.apply`` returns final hidden states,
-    the untied head is ``params['lm_head_embedding']`` (V, C) and is applied
-    vocabulary-chunk by chunk (``ops/fused_ce.py``; the logits are never
-    whole). Batch: ``(tokens (B, T), labels (B, T))``, labels already the
-    next token, -1 ignored. Per-example loss: the mean over a sequence's
-    labelled positions.
+    """Next-token loss of a language model with no other head
+    (``models/nemotron_h.py``, ``models/ouro.py``): the untied head is
+    ``params['lm_head_embedding']`` (V, C) and is applied vocabulary-chunk
+    by chunk (``ops/fused_ce.py``; the logits are never whole). Batch:
+    ``(tokens (B, T), labels (B, T))``, labels already the next token, -1
+    ignored. Per-example loss: the mean over a sequence's labelled positions.
 
-    Metric rows. Training: ``LM_TRAIN_COUNTERS`` — what the expert layers
-    sow a token (``ops/moe.py``), summed by sequence and over the layers, so
-    they ride to the host with the loss. Validation: the rows
-    ``make_gpt2_val_loss`` gives ([0, nll token-sum, labelled tokens])."""
+    ``model.apply`` returns the final hidden states (B, T, C), and a token's
+    loss is its cross-entropy; or, for a model with ``exit_loss`` (more than
+    one exit), ``(hidden states (exits, B, T, C), gates (exits, B, T))``:
+    the head runs once an exit and ``model.exit_loss`` combines the
+    cross-entropies. Validation reads the last exit alone.
+
+    Metric rows. Training: what the model declares, ``model.train_counters``
+    ({counter name: key}), each summed by sequence from what the layers sow
+    a token under that key (``ops/moe.py``) or from what ``exit_loss``
+    returns under it, so they ride to the host with the loss;
+    ``apply_loss.counters`` names them for ``training/gpt2.py``. Validation:
+    the rows ``make_gpt2_val_loss`` gives ([0, nll token-sum, labelled
+    tokens])."""
     from commefficient_tpu.ops.fused_ce import lm_head_nll
     from commefficient_tpu.utils.tracing import layer
     cd = model.config.jnp_dtype
+    counters = dict(getattr(model, "train_counters", {}))
+    exit_loss = getattr(model, "exit_loss", None)
 
     def sown(inter, key, B):
         leaves = [leaf for path, leaf in
@@ -209,27 +213,39 @@ def make_lm_loss(model, train: bool, chunk: int = 8192):
     def apply_loss(params, batch, rng, train_flag):
         tokens, labels = batch
         B = tokens.shape[0]
-        hidden, inter = model.apply({"params": params}, tokens,
-                                    mutable=["intermediates"])
+        out, inter = model.apply({"params": params}, tokens,
+                                 mutable=["intermediates"])
         valid = labels >= 0
-        with layer("lm_head"):
-            wte = params["lm_head_embedding"]
-            nll = lm_head_nll(hidden.reshape(-1, hidden.shape[-1]), wte,
-                              jnp.where(valid, labels, 0).reshape(-1),
-                              min(chunk, wte.shape[0]), cd)
-        nll_sum = jnp.sum(jnp.where(valid, nll.reshape(labels.shape), 0.0),
-                          axis=-1)
+        wte = params["lm_head_embedding"]
+        targets = jnp.where(valid, labels, 0).reshape(-1)
+
+        def head(h):
+            with layer("lm_head"):
+                return lm_head_nll(h.reshape(-1, h.shape[-1]), wte, targets,
+                                   min(chunk, wte.shape[0]),
+                                   cd).reshape(labels.shape)
+
+        rows = {}
+        if exit_loss is None:
+            token_loss = head(out)
+        elif train:
+            hiddens, gates = out
+            token_loss, rows = exit_loss(
+                jnp.stack([head(h) for h in hiddens]), gates, valid)
+        else:
+            token_loss = head(out[0][-1])
+        loss_sum = jnp.sum(jnp.where(valid, token_loss, 0.0), axis=-1)
         count = jnp.sum(valid, axis=-1).astype(jnp.float32)
-        loss = nll_sum / jnp.maximum(count, 1.0)
+        loss = loss_sum / jnp.maximum(count, 1.0)
         if not train:
-            return loss, jnp.stack([jnp.zeros_like(loss), nll_sum, count])
+            return loss, jnp.stack([jnp.zeros_like(loss), loss_sum, count])
         inter = inter.get("intermediates", {})
-        return loss, jnp.stack([sown(inter, "moe_held", B),
-                                sown(inter, "moe_fullest", B),
-                                sown(inter, "moe_dropped", B)])
+        return loss, jnp.stack([rows[key] if key in rows
+                                else sown(inter, key, B)
+                                for key in counters.values()])
 
     if train:
-        apply_loss.counters = LM_TRAIN_COUNTERS
+        apply_loss.counters = tuple(counters)
     return apply_loss
 
 

@@ -17,8 +17,10 @@ from commefficient_tpu.models.resnets import (
     ResNet101LN, ResNet50LN)
 from commefficient_tpu.models.toy import ToyLinear, TinyMLP
 # the language models are built by training/gpt2.py from their own configs
-# (``--model gpt2``, ``nemotron_h``); the registry below is training/cv.py's
+# (``--model gpt2``, ``nemotron_h``, ``ouro``); the registry below is
+# training/cv.py's
 from commefficient_tpu.models.nemotron_h import NemotronH, NemotronHConfig
+from commefficient_tpu.models.ouro import Ouro, OuroConfig
 
 MODEL_REGISTRY = {
     "ResNet9": ResNet9,
@@ -53,4 +55,5 @@ __all__ = ["MODEL_REGISTRY", "get_model", "ResNet9", "FixupResNet9",
            "resnet18", "resnet34", "resnet50", "resnet101", "resnet152",
            "resnext50_32x4d", "resnext101_32x8d", "wide_resnet50_2",
            "wide_resnet101_2", "ResNet101LN", "ResNet50LN",
-           "ToyLinear", "TinyMLP", "NemotronH", "NemotronHConfig"]
+           "ToyLinear", "TinyMLP", "NemotronH", "NemotronHConfig", "Ouro",
+           "OuroConfig"]

@@ -242,6 +242,12 @@ class Block(nn.Module):
 class NemotronH(nn.Module):
     config: NemotronHConfig
 
+    #: the metric rows of the training loss, {counter of training/gpt2.py:
+    #: key the expert layers sow a token under (``ops/moe.py``)}
+    train_counters = {"moe.assignments_held": "moe_held",
+                      "moe.assignments_fullest": "moe_fullest",
+                      "moe.dropped": "moe_dropped"}
+
     @nn.compact
     def __call__(self, ids, train: bool = False):
         cfg = self.config

@@ -272,12 +272,11 @@ def _nemotron_model(args, mesh, cfg, sample):
     preset, cut by ``--layer_pattern``, ``--experts_held``,
     ``--vocab_rows``. Next-token loss only; runs the fused federated
     round on one device or over ``--mesh clients=``."""
-    from commefficient_tpu.federated.losses import make_lm_loss
     from commefficient_tpu.models.nemotron_h import (NemotronH,
                                                      NemotronHConfig)
-    if mesh is not None and set(mesh.axis_names) - {"clients"}:
-        raise ValueError(f"--model {args.model} composes with --mesh "
-                         f"clients= only (got axes {mesh.axis_names})")
+    if getattr(args, "layers_held", None) is not None:
+        raise ValueError(f"--layers_held cuts ouro; --model {args.model} "
+                         "is cut by --layer_pattern")
     kw = {"compute_dtype": getattr(args, "compute_dtype", "float32")}
     if args.layer_pattern:
         kw["pattern"] = args.layer_pattern
@@ -292,18 +291,50 @@ def _nemotron_model(args, mesh, cfg, sample):
                              f"{base.n_routed_experts} routed experts")
         base = dataclasses.replace(
             base, experts_held=tuple(range(args.experts_held)))
-    model = NemotronH(base)
+    return _lm_only_built(NemotronH(base), base, mesh, args, sample)
+
+
+def _ouro_model(args, mesh, cfg, sample):
+    """The looped language model (``models/ouro.py``): published widths, or
+    the tests' tiny preset, cut in depth by ``--layers_held``. Next-token
+    loss at every exit; runs the fused federated round on one device or
+    over ``--mesh clients=``."""
+    from commefficient_tpu.models.ouro import Ouro, OuroConfig
+    for flag in ("layer_pattern", "experts_held", "vocab_rows"):
+        if getattr(args, flag) is not None:
+            raise ValueError(f"--{flag} cuts nemotron_h; --model "
+                             f"{args.model} is cut by --layers_held")
+    kw = {"compute_dtype": getattr(args, "compute_dtype", "float32")}
+    if args.layers_held is not None:
+        if args.layers_held < 1:
+            raise ValueError(f"--layers_held {args.layers_held}")
+        kw["layers_held"] = args.layers_held
+    base = (OuroConfig.tiny if args.model.endswith("-tiny")
+            else OuroConfig)(**kw)
+    return _lm_only_built(Ouro(base), base, mesh, args, sample)
+
+
+def _lm_only_built(model, gcfg, mesh, args, sample):
+    from commefficient_tpu.federated.losses import make_lm_loss
+    if mesh is not None and set(mesh.axis_names) - {"clients"}:
+        raise ValueError(f"--model {args.model} composes with --mesh "
+                         f"clients= only (got axes {mesh.axis_names})")
     return dict(
         init_model=model, loss_tr=make_lm_loss(model, train=True),
         loss_val=make_lm_loss(model, train=False), sample_in=(sample[0],),
-        init_params=None, param_specs=None, gcfg=base,
+        init_params=None, param_specs=None, gcfg=gcfg,
         # jitted: an eager init of the published widths would dispatch
         # every layer's ops one by one
         init=jax.jit(lambda rng, ids: model.init(rng, ids)))
 
 
+#: the language models with no other head than the next token's, on packed
+#: token sequences (``--dataset_name TOKENS``), by ``--model`` less ``-tiny``
+LM_ONLY_MODELS = {"nemotron_h": _nemotron_model, "ouro": _ouro_model}
+
+
 def build_learner(args, cfg, built, sched, mesh=None):
-    """The learner of a model ``_gpt2_model`` / ``_nemotron_model`` built
+    """The learner of a model ``_gpt2_model`` / ``LM_ONLY_MODELS`` built
     (``--server_mode buffered`` swaps in the FedBuff event-loop learner,
     federated/buffer.py; mesh-native — under --mesh clients=N its programs
     shard like the sync round, with the slot buffer partitioned over the
@@ -337,12 +368,13 @@ def train(args, mesh=None, max_rounds=None, log=True):
     from commefficient_tpu.federated.api import set_transfer_guard
     set_transfer_guard(getattr(args, "transfer_guard", "disallow"))
     compile_counters()
-    lm_only = args.model.startswith("nemotron_h")
-    if lm_only != (args.dataset_name == "TOKENS"):
+    lm_only = LM_ONLY_MODELS.get(args.model.removesuffix("-tiny"))
+    if (lm_only is not None) != (args.dataset_name == "TOKENS"):
         raise ValueError(
             "--dataset_name TOKENS (packed next-token sequences) goes with "
-            "--model nemotron_h / nemotron_h-tiny, the PersonaChat sets "
-            f"with the GPT2 double-heads models; got --model {args.model} "
+            f"--model {' / '.join(sorted(LM_ONLY_MODELS))} (or their -tiny "
+            "presets), the PersonaChat sets with the GPT2 double-heads "
+            f"models; got --model {args.model} "
             f"--dataset_name {args.dataset_name}")
     with span("setup.data"):
         if lm_only:
@@ -373,7 +405,7 @@ def train(args, mesh=None, max_rounds=None, log=True):
     cfg = args_to_config(args, num_clients=num_clients,
                          max_seq_len=args.max_seq_len)
     if lm_only:
-        built = _nemotron_model(args, mesh, cfg, sample)
+        built = lm_only(args, mesh, cfg, sample)
     else:
         built = _gpt2_model(args, mesh, cfg, tokenizer, sample, log)
     init_model, gcfg, loss_tr = (built["init_model"], built["gcfg"],
@@ -644,7 +676,12 @@ def build_gpt2_parser():
                              "keeps its width); default: all")
     parser.add_argument("--vocab_rows", type=int, default=None,
                         help="nemotron_h: rows of the vocabulary held "
-                             "(ids, logits and loss are over them)")
+                             "(ids, logits and loss are over them); "
+                             "default: all")
+    parser.add_argument("--layers_held", type=int, default=None,
+                        help="ouro: the layers of the looped stack held "
+                             "here (a pipeline stage; every loop step "
+                             "runs through them); default: all")
     parser.add_argument("--pp_microbatches", type=int, default=0,
                         help="GPipe microbatches per pipeline shard for "
                              "--mesh ...,stage=S (parallel/pp.py); 0 = "
@@ -659,7 +696,8 @@ def build_gpt2_parser():
         if a.dest == "model":
             a.choices = sorted(set(a.choices) |
                                {"gpt2", "gpt2-tiny", "openai-gpt",
-                                "nemotron_h", "nemotron_h-tiny"})
+                                "nemotron_h", "nemotron_h-tiny",
+                                "ouro", "ouro-tiny"})
         if a.dest == "dataset_name":
             a.choices = sorted(set(a.choices) | {"SyntheticPersona",
                                                  "TOKENS"})
