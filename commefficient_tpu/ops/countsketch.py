@@ -237,10 +237,20 @@ class CountSketch:
         signs = 1 - 2 * (_mix(acc) & jnp.uint32(1)).astype(jnp.int32)
         return signs.astype(jnp.float32)
 
-    def _block_hashes(self, row: int, blk: jax.Array):
+    def _block_hashes(self, row, blk: jax.Array):
         """(window base, 7-bit lane mask) per block for the tiled scheme.
-        Two independent avalanche mixes so base and mask are uncorrelated."""
-        _, _, _, _, h5, h6 = (jnp.uint32(h) for h in self.coeffs[row])
+        Two independent avalanche mixes so base and mask are uncorrelated.
+        ``row`` is one row id, or an array of row ids that broadcasts
+        against ``blk`` (sketch_kernels.window_bases hashes every row's
+        blocks in one elementwise pass): the coefficients are then picked
+        by a select a row, not a gather."""
+        if isinstance(row, int):
+            h5, h6 = (jnp.uint32(h) for h in self.coeffs[row][4:])
+        else:
+            h5, h6 = (jnp.uint32(h) for h in self.coeffs[0][4:])
+            for k in range(1, self.r):
+                h5 = jnp.where(row == k, jnp.uint32(self.coeffs[k][4]), h5)
+                h6 = jnp.where(row == k, jnp.uint32(self.coeffs[k][5]), h6)
         mb = _mix(h6 * blk + h5)
         base = mb % jnp.uint32(self.nwindows)
         lanemask = _mix(mb ^ h5) & jnp.uint32(LANES - 1)

@@ -9,9 +9,18 @@ to avoid scalar gathers. This kernel removes that intermediate entirely:
 
 * the whole (r, c_eff) table is VMEM-resident (10 MB at the reference's
   5x500k config — checked against a budget before selecting the kernel);
-* a scalar loop per 256-block tile dynamic-slices each block's 128-float
+* a scalar loop per 64-block tile dynamic-slices each block's 128-float
   window straight out of VMEM (row-granular reads — the design point of
-  the tiled scheme, ops/countsketch.py);
+  the tiled scheme, ops/countsketch.py). The loop computes no hash: the
+  window bases of every (row, block) are hashed once, vectorised, by
+  ``window_bases`` (plain jax.numpy over an iota inside the jitted round;
+  they depend on the sketch's coefficients and the block id only — not on
+  the data, the round, or which pass asks) and reach the loop as a second
+  operand blocked into SMEM. Per window it is read, shift, access, the
+  tile's 64 steps unrolled when the kernel is lowered. Hashing inside
+  the loop (a multiply-add, the murmur finaliser and a 32-bit remainder
+  on a scalar unit with no divider, 320 times a tile) was a third to two
+  fifths of a tile: PERF.md §6, PR 38;
 * the XOR lane permutation runs as the same 7-step butterfly of lane
   rolls the XLA path uses, vectorized over the tile, followed by the
   sign multiply and the r=3/5 min-max median network — all in registers;
@@ -60,6 +69,10 @@ LANES = 128
 # 17.8 MB of scoped VMEM (OOM); 64 keeps the stack under the limit
 TILE_BLOCKS = 64
 VMEM_TABLE_BUDGET = 10 << 20  # leave headroom under ~16 MB VMEM
+# grid steps that share one SMEM block of window bases: 16 x r x 64 = r x
+# 1024 words, a whole number of the 1024-word tiles a flat int32 array is
+# laid out in
+BASE_TILES = 16
 
 _U = jnp.uint32
 
@@ -137,6 +150,64 @@ def _butterfly_xor(x, lanemask):
     return x
 
 
+@partial(jax.jit, static_argnames=("cs", "n_tiles", "block_offset"))
+def window_bases(cs, n_tiles: int, block_offset: int = 0):
+    """Window bases of every block of an ``n_tiles`` grid and every row,
+    as the kernels' SMEM operand: flat int32, ``[pair of tiles][row][the
+    pair's 128 blocks]``, the grid padded to whole SMEM blocks of
+    ``BASE_TILES`` tiles. ``CountSketch._block_hashes`` over an iota — the
+    one copy of the hash — in one lane-dense elementwise pass (104 MB at
+    d = 667 M, 1 MB at 6.57 M). A function of (coefficients, block id)
+    alone: every pass of a round over the same grid asks for the same
+    array, and XLA's CSE keeps one. ``block_offset`` shifts the block ids
+    hashed, as sketch_range's bucket offset does."""
+    rows = -(-n_tiles // BASE_TILES) * (BASE_TILES // 2) * cs.r
+    m = jax.lax.broadcasted_iota(_U, (rows, LANES), 0)
+    blk = (_U(block_offset) + (m // _U(cs.r)) * _U(LANES)
+           + jax.lax.broadcasted_iota(_U, (rows, LANES), 1))
+    base, _ = cs._block_hashes(m % _U(cs.r), blk)
+    return base.astype(jnp.int32).reshape(-1)
+
+
+def _bases_spec(cs, batched):
+    """The bases operand's block: one SMEM block serves ``BASE_TILES``
+    consecutive grid steps (the copy is issued when the block index
+    changes, double-buffered); every batch row hashes alike, so the 2-D
+    grid's index map ignores the batch index."""
+    return pl.BlockSpec(
+        (BASE_TILES * cs.r * TILE_BLOCKS,),
+        (lambda b, i: (i // BASE_TILES,)) if batched
+        else (lambda i: (i // BASE_TILES,)),
+        memory_space=pltpu.SMEM)
+
+
+def _for_each_block(bases_ref, i0, r, batched, unrolled, visit):
+    """The scalar phase both kernels share: ``visit(i, window)`` for each
+    block ``i`` of tile ``i0``, ascending, where ``window(row)`` indexes the
+    block's (1, 128) window in row ``row`` of the table ref (behind the
+    length-1 batch dim where ``batched``), its base read from the tile's
+    part of the SMEM block — no hash, no remainder. On the chip the 64
+    steps are unrolled when the kernel is lowered (``unrolled``): with no
+    loop around the 320 accesses the scheduler overlaps them with each
+    other and with the vector phase (PERF.md §6, PR 38: 3.1 us a tile with
+    8 blocks a loop step, 2.5 with none). The Pallas interpreter keeps the
+    loop: unrolled, XLA:CPU would compile 64 copies of the body."""
+    t = i0 % BASE_TILES
+    off = (t // 2) * (r * LANES) + (t % 2) * TILE_BLOCKS
+
+    def block(i, carry):
+        def window(row):
+            base = bases_ref[off + row * LANES + i]
+            at = (pl.ds(row, 1),
+                  pl.ds(pl.multiple_of(base * LANES, LANES), LANES))
+            return (0, *at) if batched else at
+
+        visit(i, window)
+        return carry
+
+    jax.lax.fori_loop(0, TILE_BLOCKS, block, 0, unroll=unrolled)
+
+
 def _batch_guard(kernel_call, xla_fallback, batched_call=None):
     """Batch-aware dispatch for a single-operand Pallas entry point.
 
@@ -193,25 +264,19 @@ def _batched_params(cs):
         vmem_limit_bytes=2 * cs.r * cs.c_eff * 4 + (16 << 20))
 
 
-def _estimates_kernel(table_ref, out_ref, win, *, coeffs, nwindows, r,
-                      batched):
+def _estimates_kernel(table_ref, bases_ref, out_ref, win, *, coeffs, r,
+                      batched, unrolled):
     # batched: 2-D grid (batch, n_tiles); program_id(0) is the batch row
     # (blocks carry a leading length-1 batch dim), program_id(1) the tile
     i0 = pl.program_id(1) if batched else pl.program_id(0)
 
-    # phase 1 — scalar window gathers: each block's window base is a hash
-    # of its block id; the 128-float window is one VMEM dynamic slice
-    def body(i, carry):
-        blk = (_U(i0) * _U(TILE_BLOCKS) + _U(i))
+    # phase 1 — scalar window gathers: each block's window base comes from
+    # SMEM (window_bases); the 128-float window is one VMEM dynamic slice
+    def gather(i, window):
         for row in range(r):
-            mb, _ = _block_hash(coeffs[row], blk)
-            base = (mb % _U(nwindows)).astype(jnp.int32)
-            sl = pl.ds(base * LANES, LANES)
-            win[row, i, :] = table_ref[0, row, sl] if batched \
-                else table_ref[row, sl]
-        return carry
+            win[row, pl.ds(i, 1), :] = table_ref[window(row)]
 
-    jax.lax.fori_loop(0, TILE_BLOCKS, body, 0)
+    _for_each_block(bases_ref, i0, r, batched, unrolled, gather)
 
     # phase 2 — vectorized permute + sign + median over rows
     blk_vec = (_U(i0) * _U(TILE_BLOCKS)
@@ -239,11 +304,12 @@ def _estimates_tiles(cs, table, interp):
     (its score bits send lanes ``>= d`` to the sentinel)."""
     n_tiles = -(-cs.nblocks // TILE_BLOCKS)
     return pl.pallas_call(
-        partial(_estimates_kernel, coeffs=cs.coeffs,
-                nwindows=cs.nwindows, r=cs.r, batched=False),
+        partial(_estimates_kernel, coeffs=cs.coeffs, r=cs.r, batched=False,
+                unrolled=not interp),
         grid=(n_tiles,),
         in_specs=[pl.BlockSpec((cs.r, cs.c_eff), lambda i: (0, 0),
-                               memory_space=pltpu.VMEM)],
+                               memory_space=pltpu.VMEM),
+                  _bases_spec(cs, batched=False)],
         out_specs=pl.BlockSpec((TILE_BLOCKS, LANES), lambda i: (i, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((n_tiles * TILE_BLOCKS, LANES),
@@ -251,7 +317,7 @@ def _estimates_tiles(cs, table, interp):
         scratch_shapes=[pltpu.VMEM((cs.r, TILE_BLOCKS, LANES),
                                    jnp.float32)],
         interpret=interp, name="estimates_pallas",
-    )(table)
+    )(table, window_bases(cs, n_tiles))
 
 
 @partial(jax.jit, static_argnames=("cs", "interpret"))
@@ -272,12 +338,13 @@ def estimates_pallas(cs, table, interpret: bool = False):
     def batched_call(tabs):
         B = tabs.shape[0]
         out = pl.pallas_call(
-            partial(_estimates_kernel, coeffs=cs.coeffs,
-                    nwindows=cs.nwindows, r=cs.r, batched=True),
+            partial(_estimates_kernel, coeffs=cs.coeffs, r=cs.r,
+                    batched=True, unrolled=not interp),
             grid=(B, n_tiles),
             in_specs=[pl.BlockSpec((1, cs.r, cs.c_eff),
                                    lambda b, i: (b, 0, 0),
-                                   memory_space=pltpu.VMEM)],
+                                   memory_space=pltpu.VMEM),
+                      _bases_spec(cs, batched=True)],
             out_specs=pl.BlockSpec((1, TILE_BLOCKS, LANES),
                                    lambda b, i: (b, i, 0),
                                    memory_space=pltpu.VMEM),
@@ -287,7 +354,7 @@ def estimates_pallas(cs, table, interpret: bool = False):
                                        jnp.float32)],
             compiler_params=_batched_params(cs),
             interpret=interp, name="estimates_pallas",
-        )(tabs)
+        )(tabs, window_bases(cs, n_tiles))
         return out.reshape(B, -1)[:, :cs.d]
 
     return _batch_guard(kernel_call,
@@ -307,8 +374,8 @@ def kernel_supported(cs) -> bool:
             and cs.r * cs.c_eff * 4 <= VMEM_TABLE_BUDGET)
 
 
-def _sketch_kernel(vec_ref, out_ref, win, *, coeffs, nwindows, r,
-                   block_offset, batched):
+def _sketch_kernel(vec_ref, bases_ref, out_ref, win, *, coeffs, r,
+                   block_offset, batched, unrolled):
     """Scatter direction: TPU grid steps run SEQUENTIALLY on a core, and
     the output block's index_map is constant in the tile axis, so
     ``out_ref`` itself is the VMEM-resident accumulator across steps (a
@@ -343,20 +410,19 @@ def _sketch_kernel(vec_ref, out_ref, win, *, coeffs, nwindows, r,
         win[row, :, :] = _butterfly_xor(x * _signs(coeffs[row], idx),
                                         lanemask)
 
-    # scalar: accumulate each block's window at its hashed base
-    def body(i, carry):
-        blk = _U(block_offset) + _U(i0) * _U(TILE_BLOCKS) + _U(i)
+    # scalar: accumulate each block's window at its base, read from SMEM
+    # (window_bases: hashed there with the same block_offset). A block's r
+    # windows lie in r different table rows, so they are loaded together,
+    # added, and stored together: only consecutive BLOCKS wait on each
+    # other's stores (two blocks of a row may share a window)
+    def scatter(i, window):
+        at = [window(row) for row in range(r)]
+        sums = [out_ref[at[row]] + win[row, pl.ds(i, 1), :]
+                for row in range(r)]
         for row in range(r):
-            mb, _ = _block_hash(coeffs[row], blk)
-            base = (mb % _U(nwindows)).astype(jnp.int32)
-            sl = pl.ds(base * LANES, LANES)
-            if batched:
-                out_ref[0, row, sl] = out_ref[0, row, sl] + win[row, i, :]
-            else:
-                out_ref[row, sl] = out_ref[row, sl] + win[row, i, :]
-        return carry
+            out_ref[at[row]] = sums[row]
 
-    jax.lax.fori_loop(0, TILE_BLOCKS, body, 0)
+    _for_each_block(bases_ref, i0, r, batched, unrolled, scatter)
 
 
 @partial(jax.jit, static_argnames=("cs", "interpret", "block_offset"))
@@ -389,11 +455,13 @@ def sketch_vec_pallas(cs, vec, interpret: bool = False,
 
     def kernel_call(v):
         return pl.pallas_call(
-            partial(_sketch_kernel, coeffs=cs.coeffs, nwindows=cs.nwindows,
-                    r=cs.r, block_offset=block_offset, batched=False),
+            partial(_sketch_kernel, coeffs=cs.coeffs, r=cs.r,
+                    block_offset=block_offset, batched=False,
+                    unrolled=not interp),
             grid=(n_tiles,),
             in_specs=[pl.BlockSpec((TILE_BLOCKS, LANES), lambda i: (i, 0),
-                                   memory_space=pltpu.VMEM)],
+                                   memory_space=pltpu.VMEM),
+                      _bases_spec(cs, batched=False)],
             out_specs=pl.BlockSpec((cs.r, cs.c_eff), lambda i: (0, 0),
                                    memory_space=pltpu.VMEM),
             out_shape=jax.ShapeDtypeStruct((cs.r, cs.c_eff), jnp.float32),
@@ -401,18 +469,20 @@ def sketch_vec_pallas(cs, vec, interpret: bool = False,
                 pltpu.VMEM((cs.r, TILE_BLOCKS, LANES), jnp.float32),
             ],
             interpret=interp, name="sketch_vec_pallas",
-        )(_padded(v))
+        )(_padded(v), window_bases(cs, n_tiles, block_offset))
 
     def batched_call(vs):
         B = vs.shape[0]
         vp = jax.vmap(_padded)(vs)
         return pl.pallas_call(
-            partial(_sketch_kernel, coeffs=cs.coeffs, nwindows=cs.nwindows,
-                    r=cs.r, block_offset=block_offset, batched=True),
+            partial(_sketch_kernel, coeffs=cs.coeffs, r=cs.r,
+                    block_offset=block_offset, batched=True,
+                    unrolled=not interp),
             grid=(B, n_tiles),
             in_specs=[pl.BlockSpec((1, TILE_BLOCKS, LANES),
                                    lambda b, i: (b, i, 0),
-                                   memory_space=pltpu.VMEM)],
+                                   memory_space=pltpu.VMEM),
+                      _bases_spec(cs, batched=True)],
             out_specs=pl.BlockSpec((1, cs.r, cs.c_eff),
                                    lambda b, i: (b, 0, 0),
                                    memory_space=pltpu.VMEM),
@@ -423,7 +493,7 @@ def sketch_vec_pallas(cs, vec, interpret: bool = False,
             ],
             compiler_params=_batched_params(cs),
             interpret=interp, name="sketch_vec_pallas",
-        )(vp)
+        )(vp, window_bases(cs, n_tiles, block_offset))
 
     return _batch_guard(
         kernel_call,

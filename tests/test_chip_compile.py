@@ -106,6 +106,36 @@ def test_unsketch_select(one_chip, d):
             if key.startswith("%estimates_pallas")} == {"server_update"}
 
 
+def test_three_hash_passes_share_one_array_of_window_bases(one_chip):
+    """Sketch, estimate, re-sketch in one program, as a sketch round runs
+    them: each custom call takes the window bases as its second operand,
+    and the three equal ``window_bases`` expressions compile to ONE array
+    (XLA's CSE) that all three calls read: hashed once a round."""
+    import re
+    cs = _sketch(D_RESNET9)
+
+    def three_passes(v):
+        table = cs.sketch_vec_batched(v, use_kernel=True)
+        update = topk_kernels.unsketch_select_pallas(cs, table, k=K)
+        return cs.sketch_vec_batched(update, use_kernel=True)
+
+    # the dispatch gate asks the backend, which is the CPU here
+    with sketch_kernels.force_dispatch("kernel"), \
+            pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        text = _compiled_text(three_passes, one_chip,
+                              ((cs.d,), jnp.float32))
+    calls = re.findall(
+        r"%((?:sketch_vec|estimates)_pallas[.\d]*) = [^\n]*? "
+        r"custom-call\(([^)]*)\), custom_call_target=\"tpu_custom_call\"",
+        text)
+    assert sorted(name.split(".")[0] for name, _ in calls) == [
+        "estimates_pallas", "sketch_vec_pallas", "sketch_vec_pallas"]
+    operands = [[o.split()[-1] for o in ops.split(", ")] for _, ops in calls]
+    assert all(len(ops) == 2 for ops in operands), operands
+    assert len({ops[1] for ops in operands}) == 1, operands
+
+
 @pytest.mark.parametrize("d", [D_RESNET9, D_GPT2],
                          ids=["resnet9", "gpt2_small"])
 def test_fused_true_topk(one_chip, d):

@@ -8,13 +8,17 @@ import numpy as np
 import pytest
 
 from commefficient_tpu.ops.countsketch import CountSketch
-from commefficient_tpu.ops.sketch_kernels import (estimates_pallas,
+from commefficient_tpu.ops.sketch_kernels import (BASE_TILES, LANES,
+                                                 TILE_BLOCKS,
+                                                 estimates_pallas,
                                                  kernel_supported,
-                                                 sketch_vec_pallas)
+                                                 sketch_vec_pallas,
+                                                 window_bases)
 
 
+# 300 000 coordinates are 37 tiles: three SMEM blocks of window bases
 @pytest.mark.parametrize("d,c,r", [(40_000, 3_000, 5), (9_999, 1_111, 3),
-                                   (128, 256, 1)])
+                                   (128, 256, 1), (300_000, 3_000, 5)])
 def test_kernel_estimates_bit_identical(d, c, r):
     cs = CountSketch(d=d, c=c, r=r, seed=7, scheme="tiled")
     assert kernel_supported(cs)
@@ -41,7 +45,8 @@ def test_kernel_recovers_heavy_hitters():
     assert len(set(top) & set(hot)) >= k - 1
 
 
-@pytest.mark.parametrize("d,c,r", [(40_000, 3_000, 5), (9_999, 1_111, 3)])
+@pytest.mark.parametrize("d,c,r", [(40_000, 3_000, 5), (9_999, 1_111, 3),
+                                   (300_000, 3_000, 5), (150_000, 900, 1)])
 def test_sketch_kernel_bit_identical(d, c, r):
     cs = CountSketch(d=d, c=c, r=r, seed=5, scheme="tiled")
     rng = np.random.RandomState(2)
@@ -60,23 +65,51 @@ def test_kernel_supported_gate():
     assert not kernel_supported(CountSketch(d=10_000_000, c=2_000_000, r=5))
 
 
-@pytest.mark.parametrize("offset_blocks", [0, 1, 7])
-def test_sketch_kernel_offset_grid_bit_identical(offset_blocks):
+@pytest.mark.parametrize("d,n_max,offset_blocks", [
+    (9_999, 4_000, 0), (9_999, 4_000, 1), (9_999, 4_000, 7),
+    # 19 tiles from block 1 000 on: the offset shifts the bases the helper
+    # hashes over two SMEM blocks, and the chunk ends inside a tile
+    (300_000, 150_001, 1_000)])
+def test_sketch_kernel_offset_grid_bit_identical(d, n_max, offset_blocks):
     """Bucketed dispatch: the kernel sketches a chunk at a non-zero block
     offset (countsketch.sketch_range) and must land every contribution
     in exactly the cell the monolithic XLA path would — the hashes key
     on GLOBAL block/coordinate ids, shifted inside the grid."""
-    d, c, r = 9_999, 1_111, 3
+    c, r = 1_111, 3
     cs = CountSketch(d=d, c=c, r=r, seed=5, scheme="tiled")
     rng = np.random.RandomState(4)
     off = offset_blocks * 128
-    n = min(4_000, d - off)
+    n = min(n_max, d - off)
     chunk = rng.randn(n).astype(np.float32)
     ref = np.asarray(cs.sketch_range(chunk, off))
     ker = np.asarray(sketch_vec_pallas(cs, jax.numpy.asarray(chunk),
                                        interpret=True,
                                        block_offset=offset_blocks))
     np.testing.assert_array_equal(ker, ref)
+
+
+@pytest.mark.parametrize("r", [1, 3, 5])
+@pytest.mark.parametrize("n_tiles,block_offset", [(3, 0), (21, 0),
+                                                  (21, 1_000)])
+def test_window_bases_are_the_block_hashes(r, n_tiles, block_offset):
+    """The kernels' SMEM operand holds, for every row and every block id
+    of the grid (tile edges, SMEM block edges and a bucket's offset
+    included), ``CountSketch._block_hashes(row, blk)[0]`` — laid out
+    [pair of tiles][row][the pair's 128 blocks], the grid padded to whole
+    SMEM blocks."""
+    cs = CountSketch(d=500_000, c=3_000, r=r, seed=13, scheme="tiled")
+    bases = np.asarray(window_bases(cs, n_tiles, block_offset))
+    padded = -(-n_tiles // BASE_TILES) * BASE_TILES
+    assert bases.dtype == np.int32
+    assert bases.shape == (padded * r * TILE_BLOCKS,)
+    bases = bases.reshape(padded // 2, r, 2 * TILE_BLOCKS)
+    assert 2 * TILE_BLOCKS == LANES
+    blk = block_offset + jax.numpy.arange(padded * TILE_BLOCKS,
+                                          dtype=jax.numpy.uint32)
+    for row in range(r):
+        want = np.asarray(cs._block_hashes(row, blk)[0])
+        np.testing.assert_array_equal(bases[:, row, :].reshape(-1), want)
+    assert 0 <= bases.min() and bases.max() < cs.nwindows
 
 
 def _jaxpr_has_pallas(fn, *args) -> bool:
@@ -152,19 +185,24 @@ def test_zero_length_chunk_sketches_to_zero_table():
 
 
 @pytest.mark.parametrize("r", [1, 3, 5])
-@pytest.mark.parametrize("offset_blocks", [0, 7])
-def test_batched_kernel_offsets_all_r_bit_identical(r, offset_blocks):
+@pytest.mark.parametrize("d,batch,n_max,offset_blocks", [
+    (9_999, 4, 4_000, 0), (9_999, 4, 4_000, 7),
+    # 18 tiles a row: the bases' SMEM block changes inside a row and is
+    # fetched again as the next batch row starts
+    (200_000, 2, 140_001, 7)])
+def test_batched_kernel_offsets_all_r_bit_identical(r, d, batch, n_max,
+                                                    offset_blocks):
     """Acceptance sweep: the batched 2-D grid kernel, at offset 0 and a
     bucketed offset, for every supported median width — bit-identical to
     the vmapped XLA formulation in both directions. d is chosen so the
     chunk ends on a TAIL tile (n_blocks not a multiple of TILE_BLOCKS)
     and a partial last block, exercising the zero-pad path per row."""
-    d, c = 9_999, 1_111
+    c = 1_111
     cs = CountSketch(d=d, c=c, r=r, seed=5, scheme="tiled")
     rng = np.random.RandomState(40 + r)
     off = offset_blocks * 128
-    n = min(4_000, d - off)
-    chunks = jax.numpy.asarray(rng.randn(4, n).astype(np.float32))
+    n = min(n_max, d - off)
+    chunks = jax.numpy.asarray(rng.randn(batch, n).astype(np.float32))
     out = jax.vmap(lambda v: sketch_vec_pallas(
         cs, v, interpret=True, block_offset=offset_blocks))(chunks)
     ref = jax.vmap(lambda v: cs.sketch_range(v, off))(chunks)

@@ -69,3 +69,46 @@ def test_cv_learner_traces_at_the_example_flags(fetchsgd_learner,
         assert all(leaf.shape[0] == K for leaf in jax.tree.leaves(metrics))
     assert state.weights.shape == learner.state.weights.shape
     assert jax.tree.structure(state) == jax.tree.structure(learner.state)
+
+
+def test_sketch_round_kernels_read_one_expression_of_window_bases(
+        fetchsgd_learner):
+    """The three hash passes of a sketch round at the small cell's sketch
+    (d = 6.57 M, 5 x 500 000: the aggregate-side sketch, the server's
+    estimate pass, the re-sketch of the update) each take two operands,
+    the second a block in SMEM: the window bases. Each call's bases are
+    the output of ``sketch_kernels.window_bases`` — a jitted function of
+    static arguments only, traced once a call site — and the three
+    expressions are EQUAL (same sketch, same grid, offset 0), not one
+    shared variable: nothing threads the array through round.py and
+    server.py, and XLA's CSE makes them one array in the compiled round
+    (tests/test_chip_compile.py holds that on the compiled program)."""
+    from commefficient_tpu.analysis.walker import iter_eqns
+    from commefficient_tpu.ops import sketch_kernels
+    _, learner, (ids, batch, mask) = fetchsgd_learner
+    args = (jax.numpy.asarray(ids, jax.numpy.int32),
+            tuple(jax.numpy.asarray(t) for t in batch),
+            jax.numpy.asarray(mask), jax.numpy.float32(0.1),
+            jax.random.PRNGKey(0))
+    with sketch_kernels.force_dispatch("kernel"):
+        jaxpr = jax.make_jaxpr(learner._round)(learner.state, *args)
+    sites = list(iter_eqns(jaxpr))
+    made = {id(site.eqn.outvars[0]): site.eqn for site in sites
+            if site.primitive in ("pjit", "jit")
+            and site.eqn.params["name"] == "window_bases"}
+    passes = [site.eqn for site in sites if site.primitive == "pallas_call"
+              and site.eqn.params["name"] in ("sketch_vec_pallas",
+                                              "estimates_pallas")]
+    assert sorted(e.params["name"] for e in passes) == [
+        "estimates_pallas", "sketch_vec_pallas", "sketch_vec_pallas"]
+    expressions = set()
+    for eqn in passes:
+        data, bases = eqn.invars
+        assert bases.aval.dtype == jax.numpy.int32 and bases.aval.ndim == 1
+        spaces = [str(bm.transformed_block_aval.memory_space)
+                  for bm in eqn.params["grid_mapping"].block_mappings]
+        assert spaces == ["vmem", "smem", "vmem"], spaces
+        maker = made[id(bases)]
+        assert not maker.invars       # a function of the sketch alone
+        expressions.add(str(maker.params["jaxpr"]))
+    assert len(expressions) == 1
