@@ -35,7 +35,8 @@ from commefficient_tpu.federated.state import (CLIENT_STATE_FIELDS,
 from commefficient_tpu.ops.countsketch import LANES
 from commefficient_tpu.utils.params import flatten_params
 from commefficient_tpu.utils.schedules import PiecewiseLinear
-from commefficient_tpu.utils.tracing import count, round_mark, span
+from commefficient_tpu.utils.tracing import (round_enqueued, round_mark,
+                                             span)
 
 # --------------------------------------------------------------------------
 # Transfer guard around the round dispatch.
@@ -337,7 +338,6 @@ class FedLearner:
         host<->device row traffic overlaps compute instead of serializing
         the round."""
         round_mark(self.rounds_done)
-        count("rounds")
         with span("round.dispatch"):
             lr = self.lr_at(self.rounds_done if epoch_frac is None
                             else epoch_frac)
@@ -366,6 +366,7 @@ class FedLearner:
                 with _dispatch_guard():
                     self.state, out_rows, metrics = self._round(
                         self.state, rows, ids, cols, m, lr_in, round_rng, *ks)
+                round_enqueued(metrics["loss_sum"])
                 self._offload_pipe.push(ids_np, valid, out_rows)
                 if next_client_ids is not None:
                     self._offload_pipe.prefetch(
@@ -374,6 +375,7 @@ class FedLearner:
                 with _dispatch_guard():
                     self.state, metrics = self._round(self.state, ids, cols, m,
                                                       lr_in, round_rng, *ks)
+                round_enqueued(metrics["loss_sum"])
             self.rounds_done += 1
             metrics["lr"] = lr
             return metrics

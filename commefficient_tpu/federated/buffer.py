@@ -87,8 +87,8 @@ from commefficient_tpu.federated.faults import FaultModel
 from commefficient_tpu.federated.round import FedState, download_counts
 from commefficient_tpu.federated.server import make_sketch, server_update
 from commefficient_tpu.federated.state import BufferState, ClientState
-from commefficient_tpu.utils.tracing import (count, phase, round_mark,
-                                             span)
+from commefficient_tpu.utils.tracing import (phase, round_enqueued,
+                                             round_mark, span)
 
 
 def build_buffer_programs(apply_loss: Callable, unflatten: Callable,
@@ -789,7 +789,6 @@ class BufferedFedLearner(FedLearner):
     def train_round_async(self, client_ids, batch, mask, epoch_frac=None,
                           next_client_ids=None):
         round_mark(self.rounds_done)
-        count("rounds")
         with span("round.dispatch"):
             return self._dispatch_cohort(client_ids, batch, mask,
                                          epoch_frac, next_client_ids)
@@ -848,6 +847,7 @@ class BufferedFedLearner(FedLearner):
             with _dispatch_guard():
                 out = self._lockstep(self.state, *rows_arg, ids, cols, m,
                                      lr_in, cohort_rng, *ks)
+            round_enqueued(out[-1]["loss_sum"])
             if self._offload:
                 self.state, wb, raw = out
                 self._push_writeback(wb)
@@ -871,6 +871,7 @@ class BufferedFedLearner(FedLearner):
                 contrib, cmetrics = self._cohort(
                     self.state.replace(buffer=None), *rows_arg, ids,
                     cols, m, lr_in, cohort_rng, *ks)
+            round_enqueued(cmetrics["loss_sum"])
             self._ensure_buffer(contrib)
             valid_np = np.asarray(mask).any(axis=1)
             started, arrives, latency = fm.cohort_fates(

@@ -103,17 +103,24 @@ class Timer:
 def profile_ctx(trace_dir):
     """jax.profiler trace context, or a no-op when ``trace_dir`` is falsy
     (the TPU analog of the reference's cProfile hooks, SURVEY.md §5). On
-    exit the program's own spans and counters (utils/tracing.py) are left
-    in ``trace_dir/spans.json``, beside the device trace that holds the
-    same spans as ``fed:*`` annotations."""
+    exit the program's own spans, counters and round stamps
+    (utils/tracing.py) are left in ``trace_dir/spans.json``, beside the
+    device trace that holds the same spans as ``fed:*`` annotations. The
+    whole session is one ``fed:profile`` annotation, and ``spans.json``'s
+    ``anchors["fed:profile"]`` is the ``perf_counter_ns`` at which it
+    opened: its start on the trace minus that anchor maps every stamp of
+    ``spans.json`` onto the trace's clock."""
     if not trace_dir:
         yield
         return
     import jax
 
     from commefficient_tpu.utils import tracing
+    name = tracing.SPAN_PREFIX + "profile"
     try:
-        with jax.profiler.trace(trace_dir):
+        with jax.profiler.trace(trace_dir), \
+                jax.profiler.TraceAnnotation(name):
+            tracing.anchor(name)
             yield
     finally:
         tracing.write(trace_dir)

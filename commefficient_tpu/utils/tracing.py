@@ -15,15 +15,33 @@ Always on, bounded, in memory; no flag, no environment variable.
   ``layer(name)`` is a second level beneath it, for the parts of a model
   (``layer:ssm_scan`` inside ``phase:client_grad``); ``op_layers`` reads it.
 * ``compile_counters()`` feeds ``compile.*`` from JAX's own monitoring events.
+* The round's timeline, on the same clock: ``round_mark`` stamps the
+  dispatch (``t_ns``); ``round_enqueued(out)``, called by the dispatch site
+  as soon as the jitted call returns, stamps ``t_enq_ns`` (the round is in
+  the device's queue) and hands ``out``, one small output of the round, to
+  the *waiter*: one daemon thread, started by the first ``round_mark``,
+  that blocks on the outputs in dispatch order (the device's order; the
+  wait releases the GIL), stamps ``t_done_ns`` when the device has
+  finished the round, and drops the reference. The dispatching thread
+  never waits on it. Each round also keeps ``intervals``, ``[name, t0_ns,
+  t1_ns]`` of the main thread's top-level spans (opened with no span open:
+  ``data.*``, ``round.dispatch``, ``round.sync``, ``eval``, ``offload.*``),
+  at most ``MAX_INTERVALS`` a round; ``intervals_dropped`` counts the rest.
+* ``anchors``: ``perf_counter_ns`` at which an annotation the program opens
+  on the profiler's trace began (``utils.logging.profile_ctx``'s
+  ``fed:profile``): the trace's start of that annotation minus the anchor
+  puts every stamp above onto the trace's clock, as the benchmark does
+  with ``bench:traced_window`` (``benchmarks/benchlib/timeline.py``).
 * ``snapshot()`` is all of it as plain data; ``write(dir)`` leaves it as
   ``spans.json`` (``utils.logging.profile_ctx`` does, beside the device
-  trace). PERF.md section 3 names every span, counter and scope.
+  trace). PERF.md section 3 names every span, counter, stamp and scope.
 """
 
 from __future__ import annotations
 
 import json
 import os
+import queue
 import re
 import threading
 import time
@@ -32,6 +50,9 @@ from collections import deque
 import jax
 
 RING_ROUNDS = 4096
+#: top-level host spans kept as intervals a round; the rest are counted
+MAX_INTERVALS = 32
+WAITER = "tracing.waiter"
 SPAN_PREFIX = "fed:"
 PHASE_PREFIX = "phase:"
 LAYER_PREFIX = "layer:"
@@ -54,6 +75,7 @@ _COMPILE_EVENTS = {
 class _OpenSpans(threading.local):
     def __init__(self):                    # once a thread
         self.stack = []
+        self.main = threading.current_thread() is threading.main_thread()
 
 
 class _Recorder:
@@ -61,33 +83,60 @@ class _Recorder:
         self.lock = threading.Lock()
         self.open_spans = _OpenSpans()
         self.listening = False
+        self.waiting = None                # the waiter's queue, once started
         self.reset()
 
     def reset(self):
         with self.lock:
             self.ring = deque(maxlen=RING_ROUNDS)
             self.counters = {}             # name -> [value, t_ns last change]
+            self.anchors = {}
+            waiting, self.waiting = self.waiting, None
             self._open(None)
+        if waiting is not None:            # it ends after what it was handed
+            waiting.put(None)
 
     def _open(self, index):
         # before the first mark the open round is set-up: index None
         self.round = {"round": index, "t_ns": time.perf_counter_ns(),
-                      "spans": {}, "counts": {}}
+                      "t_enq_ns": None, "t_done_ns": None,
+                      "spans": {}, "counts": {}, "intervals": [],
+                      "intervals_dropped": 0}
 
     def mark(self, index):
         with self.lock:
             self.ring.append(self.round)
             self._open(index)
+            if self.waiting is None:
+                self.waiting = queue.SimpleQueue()
+                threading.Thread(target=_wait, args=(self.waiting,),
+                                 name=WAITER, daemon=True).start()
 
-    def add_span(self, name, ns, self_ns):
+    def enqueued(self, out):
+        t = time.perf_counter_ns()
         with self.lock:
-            tot = self.round["spans"].get(name)
+            rnd = self.round
+            rnd["t_enq_ns"] = t
+            waiting = self.waiting
+        if waiting is not None:
+            waiting.put((rnd, out))
+
+    def add_span(self, name, ns, self_ns, t0_top):
+        with self.lock:
+            rnd = self.round
+            tot = rnd["spans"].get(name)
             if tot is None:
-                self.round["spans"][name] = [ns, 1, self_ns]
+                rnd["spans"][name] = [ns, 1, self_ns]
             else:
                 tot[0] += ns
                 tot[1] += 1
                 tot[2] += self_ns
+            if t0_top is None:
+                return
+            if len(rnd["intervals"]) < MAX_INTERVALS:
+                rnd["intervals"].append((name, t0_top, t0_top + ns))
+            else:
+                rnd["intervals_dropped"] += 1
 
     def add_count(self, name, n):
         with self.lock:
@@ -98,6 +147,26 @@ class _Recorder:
             counts[name] = counts.get(name, 0) + n
 
 
+def _wait(waiting):
+    """The waiter: stamp each handed round's ``t_done_ns`` once its output
+    is ready, in the order handed (one thread, so dispatch order)."""
+    while True:
+        item = waiting.get()
+        if item is None:
+            return
+        rnd, out = item
+        del item
+        try:
+            out.block_until_ready()        # releases the GIL while it waits
+        except Exception:  # failed or deleted: no stamp; the sync raises it
+            pass
+        else:
+            t = time.perf_counter_ns()
+            with _REC.lock:
+                rnd["t_done_ns"] = t
+        del rnd, out
+
+
 _REC = _Recorder()
 
 
@@ -106,14 +175,17 @@ class span:
     open on this thread when it begins is its parent: a span's own time is
     its duration less its children's (third number of its totals)."""
 
-    __slots__ = ("name", "_ann", "_t0", "_children_ns", "_parent", "_stack")
+    __slots__ = ("name", "_ann", "_t0", "_children_ns", "_parent", "_stack",
+                 "_top")
 
     def __init__(self, name: str):
         self.name = name
 
     def __enter__(self):
-        stack = self._stack = _REC.open_spans.stack
+        local = _REC.open_spans
+        stack = self._stack = local.stack
         self._parent = stack[-1] if stack else None
+        self._top = local.main and not stack
         self._children_ns = 0
         stack.append(self)
         self._ann = jax.profiler.TraceAnnotation(SPAN_PREFIX + self.name)
@@ -127,7 +199,8 @@ class span:
         self._stack.pop()
         if self._parent is not None:
             self._parent._children_ns += ns
-        _REC.add_span(self.name, ns, ns - self._children_ns)
+        _REC.add_span(self.name, ns, ns - self._children_ns,
+                      self._t0 if self._top else None)
         return False
 
 
@@ -149,8 +222,25 @@ def spanned(iterable, name: str):
 def round_mark(index: int) -> None:
     """Close the current round's totals into the ring (the last
     ``RING_ROUNDS`` rounds) and open round ``index``: called once a
-    dispatch, before the dispatch's own span."""
+    dispatch, before the dispatch's own span. The first call starts the
+    waiter."""
     _REC.mark(int(index))
+
+
+def round_enqueued(out) -> None:
+    """Stamp the open round's ``t_enq_ns`` and hand ``out`` (one small
+    output of the round's program, never a buffer a later call donates) to
+    the waiter, which stamps ``t_done_ns`` once the device has made it.
+    Called by the dispatch site right after the jitted call returns."""
+    _REC.enqueued(out)
+
+
+def anchor(name: str) -> None:
+    """Record ``perf_counter_ns`` now as the start of the trace annotation
+    ``name`` the caller has just opened (module docstring)."""
+    t = time.perf_counter_ns()
+    with _REC.lock:
+        _REC.anchors[name] = t
 
 
 def count(name: str, n=1) -> None:
@@ -210,19 +300,26 @@ def compile_counters() -> None:
 
 
 def snapshot() -> dict:
-    """The ring, the open round and the counters, as plain data (copies):
-    ``{"rounds": [{"round", "t_ns", "spans": {name: [ns, count, self_ns]},
-    "counts": {name: growth}}], "open": {...}, "counters": {name: [value,
-    t_ns of the last change]}}`` — times on ``time.perf_counter_ns``."""
+    """The ring, the open round, the counters and the anchors, as plain
+    data (copies): ``{"rounds": [{"round", "t_ns", "t_enq_ns", "t_done_ns",
+    "spans": {name: [ns, count, self_ns]}, "counts": {name: growth},
+    "intervals": [[name, t0_ns, t1_ns]], "intervals_dropped"}], "open":
+    {...}, "counters": {name: [value, t_ns of the last change]}, "anchors":
+    {annotation: t_ns}}`` — times on ``time.perf_counter_ns``; a stamp not
+    (yet) made is ``None``."""
     def plain(r):
         return {"round": r["round"], "t_ns": r["t_ns"],
+                "t_enq_ns": r["t_enq_ns"], "t_done_ns": r["t_done_ns"],
                 "spans": {k: list(v) for k, v in r["spans"].items()},
-                "counts": dict(r["counts"])}
+                "counts": dict(r["counts"]),
+                "intervals": [list(i) for i in r["intervals"]],
+                "intervals_dropped": r["intervals_dropped"]}
 
     with _REC.lock:
         return {"rounds": [plain(r) for r in _REC.ring],
                 "open": plain(_REC.round),
-                "counters": {k: list(v) for k, v in _REC.counters.items()}}
+                "counters": {k: list(v) for k, v in _REC.counters.items()},
+                "anchors": dict(_REC.anchors)}
 
 
 def write(directory: str) -> str:
@@ -234,8 +331,9 @@ def write(directory: str) -> str:
 
 
 def reset() -> None:
-    """Forget every round and counter (tests; a second ``train`` in one
-    process that wants its own numbers)."""
+    """Forget every round, counter and anchor (tests; a second ``train`` in
+    one process that wants its own numbers). The waiter ends once the
+    rounds it holds are done; the next ``round_mark`` starts another."""
     _REC.reset()
 
 

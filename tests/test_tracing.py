@@ -5,6 +5,8 @@ round program."""
 import contextlib
 import json
 import re
+import sys
+import threading
 import time
 
 import jax
@@ -131,6 +133,12 @@ def test_write_and_profile_ctx_leave_spans_json(tmp_path, monkeypatch):
     with open(tmp_path / "spans.json") as f:
         snap = json.load(f)
     assert snap["open"]["spans"]["round.dispatch"][1] == 1
+    # the stamps and intervals travel too, and the session's anchor lies
+    # before everything stamped inside it
+    assert snap["open"]["intervals"][0][0] == "round.dispatch"
+    assert snap["open"]["intervals_dropped"] == 0
+    assert {"t_enq_ns", "t_done_ns"} <= set(snap["open"])
+    assert snap["anchors"]["fed:profile"] <= snap["open"]["t_ns"]
     with profile_ctx(None):         # falsy: no trace, nothing written
         pass
 
@@ -366,7 +374,8 @@ def test_rounds_leave_dispatch_and_sync_spans_under_their_index():
     assert all(r["spans"]["round.dispatch"][1] == 1 for r in rounds)
     assert [r["spans"].get("round.sync", [0, 0])[1] for r in rounds] == [
         0, 1, 2]                                     # one round behind
-    assert snap["counters"]["rounds"][0] == 3
+    assert snap["open"]["round"] + 1 == learner.rounds_done == 3
+    assert "rounds" not in snap["counters"]          # the ring's index is it
     assert "eval" in snap["open"]["spans"]
 
 
@@ -457,3 +466,223 @@ def test_batcher_counts_its_paths_and_its_arrays(uint8_pool, one_pass):
     counters = {k: v[0] for k, v in tracing.snapshot()["counters"].items()}
     assert counters[ran] == 12 + 9
     assert counters["data.arrays_new"] == 2
+
+
+# ------------------------------------------------------ the round's stamps
+
+def stamped_rounds(timeout=30.0):
+    """The marked rounds, once the waiter has stamped every one it was
+    handed (it stamps on its own thread, so give it a moment)."""
+    deadline = time.monotonic() + timeout
+    while True:
+        snap = tracing.snapshot()
+        rounds = [r for r in snap["rounds"] + [snap["open"]]
+                  if r["round"] is not None]
+        if all(r["t_done_ns"] for r in rounds if r["t_enq_ns"]) or (
+                time.monotonic() > deadline):
+            return rounds
+        time.sleep(0.01)
+
+
+def check_stamps(rounds):
+    assert rounds
+    for r in rounds:
+        assert r["t_done_ns"] >= r["t_enq_ns"] >= r["t_ns"], r["round"]
+    done = [r["t_done_ns"] for r in rounds]
+    assert done == sorted(done)                      # the device's order
+    marks = [r["t_ns"] for r in rounds]
+    assert marks == sorted(marks)
+
+
+def waiters():
+    return [t for t in threading.enumerate() if t.name == tracing.WAITER]
+
+
+def quiet():
+    """A recorder with no waiter alive (a former test's ends on reset)."""
+    tracing.reset()
+    for t in waiters():
+        t.join(10)
+    assert not waiters()
+
+
+def buffered_learner(fault_model):
+    from commefficient_tpu.federated.buffer import BufferedFedLearner
+    from commefficient_tpu.federated.faults import FaultModel
+    model = TinyMLP(num_classes=2, hidden=4)
+    cfg = FedConfig(weight_decay=0, num_workers=W, num_clients=6,
+                    lr_scale=0.05, server_mode="buffered", **MODES[
+                        "local_topk"])
+    fm = FaultModel(0, 6, dropout_prob=0.0, latency_sigma=0.1) if (
+        fault_model) else None
+    return BufferedFedLearner(model, cfg, make_cv_loss(model), None,
+                              jax.random.PRNGKey(1),
+                              np.zeros((1, 8), np.float32), fault_model=fm)
+
+
+@pytest.mark.parametrize("site", ["sync", "buffered_lockstep",
+                                  "buffered_faults"])
+def test_every_dispatch_site_stamps_enqueue_and_completion(site):
+    learner = (make_learner(**MODES["sketch"]) if site == "sync"
+               else buffered_learner(site == "buffered_faults"))
+    pipe = learner.pipeline()
+    for r in range(3):
+        pipe.push(learner.train_round_async(*host_batch(r)))
+    pipe.flush()
+    rounds = stamped_rounds()
+    assert [r["round"] for r in rounds] == [0, 1, 2]
+    check_stamps(rounds)
+    for r in rounds:                 # the enqueue lies inside the dispatch
+        (t0, t1), = [(a, b) for name, a, b in r["intervals"]
+                     if name == "round.dispatch"]
+        assert t0 <= r["t_enq_ns"] <= t1
+
+
+def test_the_waiter_stamps_every_round_of_a_short_train(monkeypatch):
+    from commefficient_tpu.training import cv
+    quiet()
+    seen = []                        # is a waiter alive at each mark?
+    mark = tracing._REC.mark
+
+    def watched(index):
+        seen.append(bool(waiters()))
+        mark(index)
+
+    monkeypatch.setattr(tracing._REC, "mark", watched)
+    monkeypatch.setattr(cv, "get_transforms",
+                        lambda name, train: (lambda cols, rng: cols))
+    args = cv.build_parser(default_lr=0.4).parse_args(
+        "--dataset_name Synthetic --model TinyMLP --mode sketch "
+        "--error_type virtual --k 10 --num_rows 3 --num_cols 100 "
+        "--num_workers 4 --local_batch_size 8 --eval_before_start "
+        "--valid_batch_size 64".split())
+    np.random.seed(0)
+    cv.train(args, max_rounds=4, log=False)
+    # set-up (data, learner, compile, the validation pass) started no
+    # thread: the first mark found none and left one behind
+    assert seen[0] is False and all(seen[1:]) and len(waiters()) == 1
+    rounds = stamped_rounds()
+    assert [r["round"] for r in rounds] == [0, 1, 2, 3]
+    check_stamps(rounds)
+    setup = tracing.snapshot()["rounds"][0]
+    assert setup["t_enq_ns"] is None and setup["t_done_ns"] is None
+    names = [i[0] for i in setup["intervals"]]
+    assert {"setup.data", "setup.learner", "setup.eval"} <= set(names)
+
+
+class Gate:
+    """A round output the test makes ready by hand (or never)."""
+
+    def __init__(self, fails=False):
+        self.open = threading.Event()
+        self.fails = fails
+
+    def block_until_ready(self):
+        self.open.wait()
+        if self.fails:
+            raise RuntimeError("the round failed")
+        return self
+
+
+def test_a_round_not_ready_is_unstamped_and_blocks_no_dispatch():
+    tracing.round_mark(0)
+    first, failing, last = Gate(), Gate(fails=True), Gate()
+    t0 = time.perf_counter()
+    tracing.round_enqueued(first)
+    tracing.round_mark(1)
+    tracing.round_enqueued(failing)
+    tracing.round_mark(2)
+    tracing.round_enqueued(last)
+    assert time.perf_counter() - t0 < 0.5           # nothing waited here
+    time.sleep(0.05)
+    snap = tracing.snapshot()
+    rounds = snap["rounds"][1:] + [snap["open"]]
+    assert all(r["t_enq_ns"] and r["t_done_ns"] is None for r in rounds)
+    # in order: the later rounds wait behind the first; a failed output
+    # leaves its round unstamped and the waiter goes on
+    last.open.set()
+    failing.open.set()
+    time.sleep(0.05)
+    assert tracing.snapshot()["open"]["t_done_ns"] is None
+    first.open.set()
+    deadline = time.monotonic() + 10
+    while tracing.snapshot()["open"]["t_done_ns"] is None:
+        assert time.monotonic() < deadline
+        time.sleep(0.005)
+    snap = tracing.snapshot()
+    done = [r["t_done_ns"] for r in snap["rounds"][1:] + [snap["open"]]]
+    assert done[0] and done[1] is None and done[2] >= done[0]
+
+
+def test_intervals_are_the_main_threads_top_level_spans_and_capped():
+    tracing.round_mark(0)
+    with tracing.span("outer"):
+        with tracing.span("inner"):
+            time.sleep(0.001)
+    worker = threading.Thread(target=lambda: tracing.span("elsewhere")
+                              .__enter__().__exit__(None, None, None))
+    worker.start()
+    worker.join()
+    for _ in range(tracing.MAX_INTERVALS + 5):
+        with tracing.span("leaf"):
+            pass
+    rnd = tracing.snapshot()["open"]
+    names = [name for name, _, _ in rnd["intervals"]]
+    assert names[0] == "outer" and "inner" not in names
+    assert "elsewhere" not in names and "elsewhere" in rnd["spans"]
+    assert len(names) == tracing.MAX_INTERVALS
+    assert rnd["intervals_dropped"] == 1 + 5          # 32 kept of 1 + 37
+    (_, t0, t1) = rnd["intervals"][0]
+    assert t1 - t0 == rnd["spans"]["outer"][0] >= rnd["spans"]["inner"][0]
+    edges = [t for _, a, b in rnd["intervals"] for t in (a, b)]
+    assert edges == sorted(edges)                    # in order, disjoint
+    tracing.round_mark(1)
+    assert tracing.snapshot()["open"]["intervals"] == []
+
+
+def monitoring_listeners():
+    from jax._src import monitoring
+    return [len(get()) for get in (monitoring.get_event_listeners,
+                                   monitoring.get_event_duration_listeners,
+                                   monitoring.get_event_time_span_listeners,
+                                   monitoring.get_scalar_listeners)]
+
+
+def test_the_hand_off_adds_nothing_to_the_round():
+    learner = make_learner(**MODES["sketch"])
+    before = learner._round.lower(learner.state, *round_args()).as_text()
+    listening = monitoring_listeners()
+    pipe = learner.pipeline()
+    for r in range(3):
+        pipe.push(learner.train_round_async(*host_batch(r)))
+    pipe.flush()
+    check_stamps(stamped_rounds())
+    after = learner._round.lower(learner.state, *round_args()).as_text()
+    assert after == before
+    assert learner._round._cache_size() == 1         # nothing recompiled
+    assert monitoring_listeners() == listening       # and nothing listens
+
+
+def test_the_ring_and_the_waiter_stay_bounded():
+    """Rounds as fast as the host can mark them, the interpreter switching
+    threads every microsecond: the waiter's stamps and the main thread's
+    spans and intervals land in the same records, none lost."""
+    outs = [jnp.float32(i) for i in range(tracing.RING_ROUNDS + 10)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        for i, out in enumerate(outs):
+            tracing.round_mark(i)
+            with tracing.span("round.dispatch"):
+                tracing.round_enqueued(out)
+        del outs
+        rounds = stamped_rounds()
+    finally:
+        sys.setswitchinterval(interval)
+    assert all(r["spans"]["round.dispatch"][1] == 1
+               and len(r["intervals"]) == 1 for r in rounds)
+    assert len(tracing.snapshot()["rounds"]) == tracing.RING_ROUNDS
+    assert len(rounds) == tracing.RING_ROUNDS + 1     # and the open one
+    check_stamps(rounds)
+    assert tracing._REC.waiting.empty()              # it holds no output
+    assert len(waiters()) == 1
