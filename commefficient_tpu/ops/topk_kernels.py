@@ -20,7 +20,10 @@ with two streaming passes over 8,192-element tiles:
   yields ``n_gt`` (strictly-greater survivors), so ``n_take = k - n_gt``
   ties must be accepted. Total work: 9 streaming passes of pure
   compare+sum — O(d) each, no sort, no d-sized intermediate (the only
-  HBM traffic is re-reading the operand stream; counts live in SMEM).
+  HBM traffic is re-reading the operand stream). A counting call walks
+  the stream in its own blocks of ``COUNT_ROWS`` rows, not in tiles:
+  its counts stay lane-dense in VMEM, one ``int32[8, 128]`` a
+  candidate, and fold to 16 SMEM scalars once, at the last step.
 
 * **Pass 2 — fused select/epilogue.** A second sequential-grid kernel
   recomputes each tile's scores, selects ``bits > t`` plus the first
@@ -108,6 +111,13 @@ __all__ = ["topk_kernel_ok", "topk_select_pallas", "fused_true_topk_pallas",
            "force_dispatch", "forced_dispatch"]
 
 TILE_N = TILE_BLOCKS * LANES          # elements per grid step (8,192)
+#: rows of a count pass's block (512 KB of float32). A pass alone at d =
+#: 666 962 944 on a v5e, medians of 10: 29.9 ms in 64-row steps with the
+#: counts reduced to SMEM scalars every step; 22.4 ms with lane-dense
+#: accumulators at 64 rows; 8.6 ms at 512-4 096 rows; 7.3 ms at 1 024 or
+#: 2 048 rows with only the blocks that hold a lane >= n masked (PERF.md
+#: §6). The count is bound by the vector unit there, not by the step.
+COUNT_ROWS = 1024
 _NIBBLES = 16                          # candidates per radix round
 _SENTINEL = np.int32(-(2 ** 31))      # below every valid score's bits
 _I32_MAX = np.int32(2 ** 31 - 1)
@@ -140,16 +150,20 @@ def topk_kernel_ok(approx_recall=None) -> bool:
 # in-kernel tile helpers
 # --------------------------------------------------------------------------
 
+def _bits(x):
+    """Score bits: ``x*x`` bitcast to int32 (non-negative f32 orders
+    identically as signed int32)."""
+    return jax.lax.bitcast_convert_type(x * x, jnp.int32)
+
+
 def _masked_bits(x, i0, n):
-    """Score bits for one (TILE_BLOCKS, LANES) tile: ``x*x`` bitcast to
-    int32 (non-negative f32 orders identically as signed int32), with
-    padding lanes (flat index >= n) forced to the sentinel so they never
-    count toward a threshold and never select."""
+    """Score bits for block ``i0`` of ``x.shape[0]`` rows, with padding
+    lanes (flat index >= n) forced to the sentinel so they never count
+    toward a threshold and never select."""
     rows = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
     lanes = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
-    idx = (i0 * TILE_BLOCKS + rows) * LANES + lanes
-    bits = jax.lax.bitcast_convert_type(x * x, jnp.int32)
-    return jnp.where(idx < n, bits, _SENTINEL)
+    idx = (i0 * x.shape[0] + rows) * LANES + lanes
+    return jnp.where(idx < n, _bits(x), _SENTINEL)
 
 
 def _source_tile(refs, *, src, batched):
@@ -177,51 +191,77 @@ def _source_tile(refs, *, src, batched):
 # pass 1 — counting kernel (one call per radix round)
 # --------------------------------------------------------------------------
 
-def _count_kernel(vec_ref, cand_ref, out_ref, *, n, batched):
-    i0 = pl.program_id(1) if batched else pl.program_id(0)
+def _count_kernel(vec_ref, cand_ref, out_ref, acc_ref, *, n, batched):
+    axis = 1 if batched else 0
+    i = pl.program_id(axis)
+    rows = vec_ref.shape[-2]
 
-    bits = _masked_bits(vec_ref[0] if batched else vec_ref[...], i0, n)
+    # lane-dense counts in VMEM across the sequential grid: zeroed as each
+    # (batch row's) first block comes in, folded to the 16 scalars of the
+    # SMEM output at its last. A step is elementwise adds of whole vregs,
+    # no cross-lane reduction and no SMEM access; a lane's count stays
+    # under the buffer's rows / 8 (651 k at d = 667 M)
+    @pl.when(i == 0)
+    def _():
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.int32)
 
-    # counts accumulate in SMEM across the sequential grid; zero them as
-    # each (batch row's) first tile comes in
-    @pl.when(i0 == 0)
+    def add(bits):
+        b3 = bits.reshape(rows // 8, 8, LANES)
+        for j in range(_NIBBLES):
+            acc_ref[j] = acc_ref[j] + jnp.sum(
+                (b3 >= cand_ref[0, j]).astype(jnp.int32), axis=0)
+
+    # only a block that holds a lane >= n (the padding, or past the buffer
+    # where the last block overhangs it) pays for the mask
+    whole = (i + 1) * (rows * LANES) <= n
+
+    @pl.when(whole)
+    def _():
+        add(_bits(vec_ref[0] if batched else vec_ref[...]))
+
+    @pl.when(jnp.logical_not(whole))
+    def _():
+        add(_masked_bits(vec_ref[0] if batched else vec_ref[...], i, n))
+
+    @pl.when(i == pl.num_programs(axis) - 1)
     def _():
         for j in range(_NIBBLES):
-            out_ref[0, j] = jnp.int32(0)
-
-    for j in range(_NIBBLES):
-        c = cand_ref[0, j]
-        out_ref[0, j] = out_ref[0, j] + jnp.sum((bits >= c)
-                                                .astype(jnp.int32))
+            out_ref[0, j] = jnp.sum(acc_ref[j])
 
 
-def _count_call(vec, cands, *, n, n_tiles, interp, batched=False):
+def _count_call(vec, cands, *, n, interp, batched=False):
     """Counts of ``bits >= cand`` for 16 candidates over the tiled ``vec``
     (the vector itself, the true_topk error, the sketch server's
-    estimates)."""
+    estimates), in blocks of ``COUNT_ROWS`` rows, capped at ``vec``'s; the
+    last block may overhang ``vec`` and is masked by flat index."""
+    rows = vec.shape[-2]
+    blk = min(COUNT_ROWS, rows)
+    n_steps = -(-rows // blk)
     kern = partial(_count_kernel, n=n, batched=batched)
     cand_smem = dict(memory_space=pltpu.SMEM)
+    acc = [pltpu.VMEM((_NIBBLES, 8, LANES), jnp.int32)]
     if batched:
         B = cands.shape[0]
         return pl.pallas_call(
-            kern, grid=(B, n_tiles),
-            in_specs=[pl.BlockSpec((1, TILE_BLOCKS, LANES),
-                                   lambda b, i: (b, i, 0),
+            kern, grid=(B, n_steps),
+            in_specs=[pl.BlockSpec((1, blk, LANES), lambda b, i: (b, i, 0),
                                    memory_space=pltpu.VMEM),
                       pl.BlockSpec((1, _NIBBLES), lambda b, i: (b, 0),
                                    **cand_smem)],
             out_specs=pl.BlockSpec((1, _NIBBLES), lambda b, i: (b, 0),
                                    **cand_smem),
             out_shape=jax.ShapeDtypeStruct((B, _NIBBLES), jnp.int32),
+            scratch_shapes=acc,
             interpret=interp, name=COUNT_KERNEL_NAME)(vec, cands)
     out = pl.pallas_call(
-        kern, grid=(n_tiles,),
-        in_specs=[pl.BlockSpec((TILE_BLOCKS, LANES), lambda i: (i, 0),
+        kern, grid=(n_steps,),
+        in_specs=[pl.BlockSpec((blk, LANES), lambda i: (i, 0),
                                memory_space=pltpu.VMEM),
                   pl.BlockSpec((1, _NIBBLES), lambda i: (0, 0),
                                **cand_smem)],
         out_specs=pl.BlockSpec((1, _NIBBLES), lambda i: (0, 0), **cand_smem),
         out_shape=jax.ShapeDtypeStruct((1, _NIBBLES), jnp.int32),
+        scratch_shapes=acc,
         interpret=interp,
         name=COUNT_KERNEL_NAME)(vec, cands.reshape(1, _NIBBLES))
     return out.reshape(_NIBBLES)
@@ -509,8 +549,7 @@ def topk_select_pallas(vec, kk, *, k, with_mask=False, interpret=False):
         vp = jnp.pad(v, (0, n_tiles * TILE_N - n)).reshape(
             n_tiles * TILE_BLOCKS, LANES)
         t, ntake = _radix_threshold(
-            lambda cands: _count_call(vp, cands, n=n, n_tiles=n_tiles,
-                                      interp=interp), kk_)
+            lambda cands: _count_call(vp, cands, n=n, interp=interp), kk_)
         outs = _select_call((vp,), t, ntake, n=n, n_tiles=n_tiles,
                             interp=interp, src="plain", with_mask=with_mask,
                             name="topk_select_pallas")
@@ -525,8 +564,8 @@ def topk_select_pallas(vec, kk, *, k, with_mask=False, interpret=False):
         vp = jnp.pad(vs, ((0, 0), (0, n_tiles * TILE_N - n))).reshape(
             B, n_tiles * TILE_BLOCKS, LANES)
         t, ntake = _radix_threshold_batched(
-            lambda cands: _count_call(vp, cands, n=n, n_tiles=n_tiles,
-                                      interp=interp, batched=True), kks)
+            lambda cands: _count_call(vp, cands, n=n, interp=interp,
+                                      batched=True), kks)
         outs = _select_call((vp,), t, ntake, n=n, n_tiles=n_tiles,
                             interp=interp, src="plain", batched=True,
                             with_mask=with_mask, name="topk_select_pallas")
@@ -567,8 +606,7 @@ def fused_true_topk_pallas(gradient, vvelocity, verror, *, k, rho,
 
         errp, vp = pad(err), pad(v)
         t, ntake = _radix_threshold(
-            lambda cands: _count_call(errp, cands, n=n, n_tiles=n_tiles,
-                                      interp=interp),
+            lambda cands: _count_call(errp, cands, n=n, interp=interp),
             jnp.int32(k))
         return _select_call((errp, vp), t, ntake, n=n, n_tiles=n_tiles,
                             interp=interp, src="resid",
@@ -601,8 +639,8 @@ def unsketch_select_pallas(cs, table, *, k, with_mask=False,
         # the passes (_masked_bits sends lanes >= d to the sentinel)
         est = _estimates_tiles(cs, tab, interp)
         t, ntake = _radix_threshold(
-            lambda cands: _count_call(est, cands, n=n, n_tiles=n_tiles,
-                                      interp=interp), jnp.int32(k))
+            lambda cands: _count_call(est, cands, n=n, interp=interp),
+            jnp.int32(k))
         outs = _select_call((est,), t, ntake, n=n, n_tiles=n_tiles,
                             interp=interp, src="plain", with_mask=with_mask,
                             in_place=True, name="unsketch_select_pallas")

@@ -84,8 +84,8 @@ def test_estimates_resnet9(one_chip):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("d", [D_RESNET9, D_GPT2],
-                         ids=["resnet9", "gpt2_small"])
+@pytest.mark.parametrize("d", [D_RESNET9, D_GPT2, D_NEMOTRON_CUT],
+                         ids=["resnet9", "gpt2_small", "nemotron_cut"])
 def test_unsketch_select(one_chip, d):
     from commefficient_tpu.utils import tracing
     cs = _sketch(d)
@@ -102,6 +102,11 @@ def test_unsketch_select(one_chip, d):
                  "unsketch_select_pallas"):
         assert f"%{name}" in text, name
     assert text.count('custom_call_target="tpu_custom_call"') == 4
+    # the count walks its own blocks of COUNT_ROWS rows, and at all three
+    # d (81 417 tiles of 64 rows at the hybrid's cut) the last one
+    # overhangs the buffer
+    rows = -(-cs.d // topk_kernels.TILE_N) * topk_kernels.TILE_BLOCKS
+    assert rows % topk_kernels.COUNT_ROWS != 0
     assert {phase for key, phase in tracing.op_phases(compiled).items()
             if key.startswith("%estimates_pallas")} == {"server_update"}
 

@@ -331,6 +331,80 @@ def test_topk_row_k_matches_per_row_masking():
     np.testing.assert_array_equal(got_k, ref)
 
 
+_BLOCK_N = tk.COUNT_ROWS * 128        # elements of one count block
+
+
+def _padded(vec, poison=3e30):
+    """``vec`` in the tiled layout the kernels stream, its padding lanes
+    holding a score above every real one: they count only if the mask
+    fails to send them to the sentinel."""
+    n = vec.shape[-1]
+    width = -(-n // tk.TILE_N) * tk.TILE_N
+    out = np.full(vec.shape[:-1] + (width,), poison, np.float32)
+    out[..., :n] = vec
+    return out.reshape(vec.shape[:-1] + (width // 128, 128))
+
+
+@pytest.mark.parametrize("case", ["whole_blocks", "overhang", "one_block",
+                                  "batched", "all_tied", "padding"])
+def test_count_kernel_counts_equal_numpy(case):
+    """The count pass's 16 counts of ``bits >= cand`` equal a numpy count:
+    over whole count blocks, where the last block overhangs the buffer (the
+    interpreter fills what lies past it with NaN, whose bits would count),
+    in one block smaller than ``COUNT_ROWS``, per row on the batched grid
+    with a different k a row, with every score tied, and with the padding
+    lanes at the sentinel (candidates at the sentinel + 1 and at 0 count
+    exactly the d real lanes)."""
+    rng = np.random.RandomState(43)
+    d = {"whole_blocks": 2 * _BLOCK_N, "one_block": 20_000,
+         "all_tied": _BLOCK_N + 5_000}.get(case, _BLOCK_N + 18_928)
+    B = 3 if case == "batched" else 1
+    vec = rng.randn(B, d).astype(np.float32)
+    if case == "all_tied":
+        vec[:] = -1.5
+    bits = (vec * vec).view(np.int32)
+    js = np.arange(16, dtype=np.int32)
+    if case == "padding":
+        cands = np.array([[tk._SENTINEL + 1, 0, 1] + [0x7F000000] * 13],
+                         np.int32)
+    else:
+        # around each row's k-th largest score, for a different k a row
+        kth = [np.sort(bits[b])[::-1][k - 1]
+               for b, k in zip(range(B), (1, 700, 20_000))]
+        cands = np.stack([t - 8 + js for t in kth]).astype(np.int32)
+    ref = (bits[:, :, None] >= cands[:, None, :]).sum(axis=1)
+    vp = jnp.asarray(_padded(vec))
+    if case == "batched":
+        got = tk._count_call(vp, jnp.asarray(cands), n=d, interp=True,
+                             batched=True)
+    else:
+        got = tk._count_call(vp[0], jnp.asarray(cands[0]), n=d,
+                             interp=True)[None]
+    np.testing.assert_array_equal(np.asarray(got), ref)
+    if case == "all_tied":
+        assert set(ref[0].tolist()) == {0, d}
+    if case == "padding":
+        assert ref[0, 0] == ref[0, 1] == d
+    if case == "overhang":
+        assert vp.shape[-2] % tk.COUNT_ROWS != 0
+
+
+@pytest.mark.parametrize("kk", [700, 640])
+def test_threshold_parity_where_the_last_count_block_overhangs(kk):
+    """The radix threshold and the select over a stream whose last count
+    block overhangs the buffer, with contested ties across tiles and
+    count blocks: equal to the incumbent masked top-k, bit for bit."""
+    d, k = _BLOCK_N + 18_928, 700
+    vec = jnp.asarray(_vec_with_ties(d, 900, seed=47, mag=2.0))
+    assert (-(-d // tk.TILE_N) * 64) % tk.COUNT_ROWS != 0
+    ref = jax.jit(lambda v: tk._mask_fallback(v, jnp.int32(kk), k))(vec)
+    with tk.force_dispatch("kernel"):
+        got = tk.topk_select_pallas(vec, kk, k=k, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got).view(np.uint32),
+                                  np.asarray(ref).view(np.uint32))
+    assert (np.abs(np.asarray(vec)) == 2.0).sum() > kk
+
+
 def _pallas_programs(fn, *args):
     """[(name, grid, block shapes, scratch shapes, output shapes, kernel
     body length)] of every pallas_call in ``fn``'s kernel-arm jaxpr."""
@@ -353,12 +427,14 @@ def _pallas_programs(fn, *args):
 
 
 _TILE, _CANDS, _ONE = (64, 128), (1, 16), (1, 1)
-_COUNT = ("radix_count_pallas", (3,), [_TILE, _CANDS, _CANDS], [],
-          [(1, 16)], 127)
-_BCOUNT = ("radix_count_pallas", (3, 3), [(1,) + _TILE, _CANDS, _CANDS], [],
-           [(3, 16)], 127)
-#: read off the parent of PR 36 (which took the in-VMEM estimate stream
-#: out of the kernels these programs share) at d = 20 000, k = 50
+#: the count walks the 192 rows of d = 20 000 in one block of its own, with
+#: its 16 lane-dense accumulators in VMEM scratch
+_COUNT = ("radix_count_pallas", (1,), [(192, 128), _CANDS, _CANDS],
+          [(16, 8, 128)], [(1, 16)], 15)
+_BCOUNT = ("radix_count_pallas", (3, 1), [(1, 192, 128), _CANDS, _CANDS],
+           [(16, 8, 128)], [(3, 16)], 15)
+#: the select entries read off the tree before the estimate stream left
+#: the kernels these programs share, at d = 20 000, k = 50
 SHARED_PROGRAMS = {
     "plain": [_COUNT, _COUNT,
               ("topk_select_pallas", (3,), [_TILE, _ONE, _ONE, _TILE],
