@@ -21,9 +21,10 @@ to avoid scalar gathers. This kernel removes that intermediate entirely:
   the loop (a multiply-add, the murmur finaliser and a 32-bit remainder
   on a scalar unit with no divider, 320 times a tile) was a third to two
   fifths of a tile: PERF.md §6, PR 38;
-* the XOR lane permutation runs as the same 7-step butterfly of lane
-  rolls the XLA path uses, vectorized over the tile, followed by the
-  sign multiply and the r=3/5 min-max median network — all in registers;
+* the XOR lane permutation is one lane gather a vreg and row
+  (``_lane_xor``: ``tpu.dynamic_gather`` on the chip), where the XLA path
+  runs a 7-step butterfly of lane rolls; with the sign multiply and the
+  r=3/5 min-max median network it stays in registers;
 * the only HBM traffic is the (d,) output write.
 
 Round 8 made both kernels BATCH-NATIVE: under ``vmap`` the custom_vmap
@@ -135,19 +136,11 @@ def _signs(coeffs_row, idx):
             ).astype(jnp.float32)
 
 
-def _butterfly_xor(x, lanemask):
-    """y[b, l] = x[b, l ^ lanemask[b]] — countsketch._permute_xor's
-    7-step butterfly, usable inside the kernel (static rolls + selects)."""
-    lanes = jax.lax.broadcasted_iota(_U, x.shape, 1)
-    for b in range(7):
-        w = 1 << b
-        plus = jnp.roll(x, w, axis=1)
-        minus = jnp.roll(x, -w, axis=1)
-        swapped = jnp.where(((lanes >> _U(b)) & _U(1)).astype(bool),
-                            plus, minus)
-        bit = ((lanemask >> _U(b)) & _U(1)).astype(bool)
-        x = jnp.where(bit, swapped, x)
-    return x
+def _lane_xor(x, lane, lanemask):
+    """y[b, l] = x[b, l ^ lanemask[b, l]] — countsketch._permute_xor's
+    permutation as one lane gather (every index lies in [0, 128))."""
+    return jnp.take_along_axis(x, (lane ^ lanemask).astype(jnp.int32),
+                               axis=1, mode="promise_in_bounds")
 
 
 @partial(jax.jit, static_argnames=("cs", "n_tiles", "block_offset"))
@@ -287,7 +280,7 @@ def _estimates_kernel(table_ref, bases_ref, out_ref, win, *, coeffs, r,
     for row in range(r):
         _, lanemask = _block_hash(coeffs[row], blk_vec)
         signs = _signs(coeffs[row], idx)
-        per_row.append(_butterfly_xor(win[row], lanemask) * signs)
+        per_row.append(_lane_xor(win[row], lane, lanemask) * signs)
     if batched:
         out_ref[0, :, :] = _median(per_row)
     else:
@@ -398,8 +391,8 @@ def _sketch_kernel(vec_ref, bases_ref, out_ref, win, *, coeffs, r,
     def _():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    # vectorized: sign-multiply + XOR-permute the tile (the butterfly is an
-    # involution: the same permute serves scatter and gather)
+    # vectorized: sign-multiply + XOR-permute the tile (the XOR permutation
+    # is an involution: the same permute serves scatter and gather)
     blk_vec = (_U(block_offset) + _U(i0) * _U(TILE_BLOCKS)
                + jax.lax.broadcasted_iota(_U, (TILE_BLOCKS, LANES), 0))
     lane = jax.lax.broadcasted_iota(_U, (TILE_BLOCKS, LANES), 1)
@@ -407,8 +400,8 @@ def _sketch_kernel(vec_ref, bases_ref, out_ref, win, *, coeffs, r,
     x = vec_ref[0, :, :] if batched else vec_ref[:, :]
     for row in range(r):
         _, lanemask = _block_hash(coeffs[row], blk_vec)
-        win[row, :, :] = _butterfly_xor(x * _signs(coeffs[row], idx),
-                                        lanemask)
+        win[row, :, :] = _lane_xor(x * _signs(coeffs[row], idx), lane,
+                                   lanemask)
 
     # scalar: accumulate each block's window at its base, read from SMEM
     # (window_bases: hashed there with the same block_offset). A block's r

@@ -49,8 +49,9 @@ with two streaming passes over 8,192-element tiles:
 server's update) is the ``plain`` program over ONE d-long buffer: a
 single pass of ops/sketch_kernels' estimates kernel writes every
 coordinate's estimate in the tiled layout (five hashes, five window
-gathers, a butterfly and a median a coordinate: 4.6 ms at d = 6.57 M on a
-v5e, sixteen times a plain count pass), the nine counts stream that
+gathers, an XOR lane permutation and a median a coordinate: 4.6 ms at
+d = 6.57 M on a v5e with the permutation as a butterfly, sixteen times a
+plain count pass), the nine counts stream that
 buffer, and the select pass overwrites it in place with the masked
 update (``input_output_aliases``: it reads tile i and writes tile i).
 Recomputing the estimates per tile inside each pass instead costs ten

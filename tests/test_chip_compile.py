@@ -84,6 +84,23 @@ def test_estimates_resnet9(one_chip):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("r", [1, 3])
+@pytest.mark.parametrize("kernel", ["sketch_vec", "estimates"])
+def test_hash_kernels_at_narrower_medians(one_chip, kernel, r):
+    """The r = 1 and r = 3 bodies beside the r = 5 ones above: r lane
+    gathers a tile and a narrower median network."""
+    cs = CountSketch(d=D_RESNET9, c=COLS, r=r)
+    if kernel == "sketch_vec":
+        text = _compiled_text(
+            lambda v: sketch_kernels.sketch_vec_pallas(cs, v), one_chip,
+            ((cs.d,), jnp.float32))
+    else:
+        text = _compiled_text(
+            lambda t: sketch_kernels.estimates_pallas(cs, t), one_chip,
+            _table(cs))
+    assert "tpu_custom_call" in text
+
+
 @pytest.mark.parametrize("d", [D_RESNET9, D_GPT2, D_NEMOTRON_CUT],
                          ids=["resnet9", "gpt2_small", "nemotron_cut"])
 def test_unsketch_select(one_chip, d):
@@ -246,4 +263,21 @@ def test_batched_per_worker(one_chip, monkeypatch, op):
     monkeypatch.setattr(sketch_kernels, "_interpret", lambda flag: False)
     with sketch_kernels.force_dispatch("kernel"):
         text = _compiled_text(jax.vmap(fn), one_chip, (shape, jnp.float32))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("op", ["sketch_vec", "estimates"])
+def test_batched_grid_at_the_hybrid_models_cut(one_chip, monkeypatch, op):
+    """The 2-D grid (batch, n_tiles) kernels at the hybrid's d, as its round
+    runs them: the server's and the aggregate's calls are a singleton vmap
+    (``sketch_vec_batched`` / ``estimates_batched``), 81 417 tiles a row."""
+    cs = _sketch(D_NEMOTRON_CUT)
+    fn, shape = {
+        "sketch_vec": (lambda v: cs.sketch_vec_batched(v, True), (cs.d,)),
+        "estimates": (lambda t: cs.estimates_batched(t, True),
+                      _table(cs)[0]),
+    }[op]
+    monkeypatch.setattr(sketch_kernels, "_interpret", lambda flag: False)
+    with sketch_kernels.force_dispatch("kernel"):
+        text = _compiled_text(fn, one_chip, (shape, jnp.float32))
     assert "tpu_custom_call" in text

@@ -7,9 +7,10 @@ import jax
 import numpy as np
 import pytest
 
-from commefficient_tpu.ops.countsketch import CountSketch
+from commefficient_tpu.analysis.walker import iter_eqns
+from commefficient_tpu.ops.countsketch import CountSketch, _permute_xor
 from commefficient_tpu.ops.sketch_kernels import (BASE_TILES, LANES,
-                                                 TILE_BLOCKS,
+                                                 TILE_BLOCKS, _lane_xor,
                                                  estimates_pallas,
                                                  kernel_supported,
                                                  sketch_vec_pallas,
@@ -110,6 +111,50 @@ def test_window_bases_are_the_block_hashes(r, n_tiles, block_offset):
         want = np.asarray(cs._block_hashes(row, blk)[0])
         np.testing.assert_array_equal(bases[:, row, :].reshape(-1), want)
     assert 0 <= bases.min() and bases.max() < cs.nwindows
+
+
+def test_lane_gather_is_the_xor_permutation_for_every_mask():
+    """The kernels' lane gather against the XLA path's butterfly: row b of
+    a (128, 128) tile of distinct floats permuted by lane mask b, so all
+    128 masks are checked, bit for bit."""
+    x = jax.numpy.asarray(
+        np.random.RandomState(3).permutation(LANES * LANES)
+        .astype(np.float32).reshape(LANES, LANES) - 8_000.5)
+    masks = jax.numpy.arange(LANES, dtype=jax.numpy.uint32)
+    lane = jax.lax.broadcasted_iota(jax.numpy.uint32, x.shape, 1)
+    got = jax.jit(_lane_xor)(x, lane, jax.numpy.broadcast_to(
+        masks[:, None], x.shape))
+    want = jax.jit(_permute_xor)(x, masks)
+    np.testing.assert_array_equal(np.asarray(got).view(np.uint32),
+                                  np.asarray(want).view(np.uint32))
+    np.testing.assert_array_equal(
+        np.asarray(got)[5], np.asarray(x)[5][np.arange(LANES) ^ 5])
+
+
+@pytest.mark.parametrize("r", [1, 3, 5])
+@pytest.mark.parametrize("kernel", ["estimates", "sketch_vec"])
+@pytest.mark.parametrize("batched", [False, True],
+                         ids=["grid_1d", "grid_2d"])
+def test_hash_kernel_body_permutes_by_one_gather_a_row(r, kernel, batched):
+    """The kernel arm's body, as traced: r lane gathers (one a table row)
+    and no lane concatenate — a butterfly of lane rolls (slices joined by
+    concatenate) would be back in the vector phase."""
+    cs = CountSketch(d=20_000, c=1_111, r=r, seed=5, scheme="tiled")
+    fn, arg = {
+        "estimates": (lambda t: estimates_pallas(cs, t, interpret=True),
+                      np.zeros((r, cs.c_eff), np.float32)),
+        "sketch_vec": (lambda v: sketch_vec_pallas(cs, v, interpret=True),
+                       np.zeros(cs.d, np.float32)),
+    }[kernel]
+    if batched:
+        fn, arg = jax.vmap(fn), np.stack([arg, arg])
+    bodies = [site.eqn.params["jaxpr"]
+              for site in iter_eqns(jax.make_jaxpr(fn)(arg))
+              if site.primitive == "pallas_call"]
+    assert len(bodies) == 1
+    prims = [site.primitive for site in iter_eqns(bodies[0])]
+    assert prims.count("gather") == r
+    assert "concatenate" not in prims
 
 
 def _jaxpr_has_pallas(fn, *args) -> bool:
